@@ -7,13 +7,9 @@ PY ?= python
 # pass anywhere (tests/conftest.py pins this too; exporting here covers the
 # non-pytest entry points).
 CPU_ENV = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
-# Persistent XLA compilation cache (madsim_tpu/parallel/compile_cache.py),
-# honored by every entry point at package import and inherited by spawned
-# fleet workers: each distinct program compiles ONCE across all of
-# `make check`'s legs, and CI re-runs start warm. The tracelint budget
-# leg is exempt by construction (analysis/budgets.py compiles fresh —
-# the cache strips cost/alias stats).
-export MADSIM_COMPILE_CACHE ?= $(CURDIR)/.jax_cache
+# The persistent XLA compilation cache needs nothing here: every entry
+# point follows madsim_tpu/parallel/compile_cache.py at package import
+# ($JAX_COMPILATION_CACHE_DIR if set, else <checkout>/.jax_cache).
 
 .PHONY: check lint detlint tracelint speclint speclint-demo test smoke \
         dryrun determinism dualmode native clean replay-demo bench-diff \

@@ -15,6 +15,23 @@ step function vmapped over thousands of seeds and sharded across a TPU mesh
 (``ops.threefry``), so randomness is a pure function of (seed, stream, index)
 on every backend.
 """
+# The one compile-cache rule (parallel/compile_cache.py), applied at
+# package import, before anything compiles (jax latches its cache at the
+# first compile), so every entry point — bench, chip_smoke, tools/, fleet
+# worker processes — follows it. Loaded by file path, NOT `from
+# .parallel import ...`: the parallel package init imports jax and the
+# engine, and the host-only import path stays jax-free.
+import os as _os
+from importlib import util as _ilu
+
+_spec = _ilu.spec_from_file_location(
+    "madsim_tpu._compile_cache_boot",
+    _os.path.join(_os.path.dirname(_os.path.abspath(__file__)),
+                  "parallel", "compile_cache.py"))
+_cc = _ilu.module_from_spec(_spec)
+_spec.loader.exec_module(_cc)
+_cc.apply()
+
 from .core.config import Config, FsConfig, NetConfig, TcpConfig
 from .core.context import NoRuntimeError
 from .core.futures import Cancelled, ChannelClosed
@@ -26,27 +43,6 @@ from .core.plugin import Simulator, simulator
 from .testing import Builder, main, run, test
 
 from . import fs, net, rand, sync, task, time
-
-# Persistent XLA compilation cache opt-in (parallel/compile_cache.py):
-# honored at package import so every entry point — bench, tools/, fleet
-# worker processes, `make check` — gets it from one env var. Gated so
-# the host-only import path stays jax-free when the var is unset. Loaded
-# by file path, NOT `from .parallel import ...`: the parallel package
-# init pulls in engine.core, which compiles programs at import time —
-# jax initializes its cache at the first compile, so the dir must be
-# configured before that chain ever starts.
-import os as _os
-
-if _os.environ.get("MADSIM_COMPILE_CACHE"):
-    from importlib import util as _ilu
-
-    _spec = _ilu.spec_from_file_location(
-        "madsim_tpu._compile_cache_boot",
-        _os.path.join(_os.path.dirname(_os.path.abspath(__file__)),
-                      "parallel", "compile_cache.py"))
-    _mod = _ilu.module_from_spec(_spec)
-    _spec.loader.exec_module(_mod)
-    _mod.enable_from_env()
 
 __version__ = "0.1.0"
 

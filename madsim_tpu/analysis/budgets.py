@@ -55,11 +55,8 @@ def compile_fresh(lowered):
     after, so later compiles re-attach to the directory cache)."""
     import jax
 
-    try:
-        from jax._src import compilation_cache as _cc
-        reset = _cc.reset_cache
-    except (ImportError, AttributeError):  # pragma: no cover — jax drift
-        reset = lambda: None  # noqa: E731
+    from jax.experimental.compilation_cache.compilation_cache import \
+        reset_cache as reset
 
     prev = jax.config.jax_compilation_cache_dir
     reset()
@@ -80,11 +77,7 @@ def measure_compiled(comp, unit_div: Optional[int] = None) -> Dict[str, Any]:
     flops into a per-world figure for programs with a world axis.
     """
     ca = comp.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax: one dict per device
-        ca = ca[0] if ca else {}
     ma = comp.memory_analysis()
-    if isinstance(ma, (list, tuple)):  # pragma: no cover — jax drift
-        ma = ma[0]
     arg = int(ma.argument_size_in_bytes)
     out_b = int(ma.output_size_in_bytes)
     temp = int(ma.temp_size_in_bytes)

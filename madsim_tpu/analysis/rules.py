@@ -72,8 +72,9 @@ RULES: Dict[str, Rule] = {r.code: r for r in [
          "delete the detlint-allow.txt line — the tree it excused is clean "
          "now (or was renamed out from under it)"),
     Rule("TRC001", "host callback primitive inside a jitted sim program",
-         "pure_callback/io_callback/debug_callback re-enter the host mid-"
-         "program: remove it (debug prints belong in obs/, not the step)"),
+         "pure_callback/io_callback/debug_callback/debug_print re-enter the "
+         "host mid-program: remove it (debug prints belong in obs/, not the "
+         "step)"),
     Rule("TRC002", "backend-variant or nondeterministic primitive",
          "unstable sorts, float scatter-accumulation onto duplicate "
          "indices, approximate/stateful kernels vary across backends — "

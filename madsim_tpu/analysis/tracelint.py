@@ -10,7 +10,7 @@ sanitizer passes in a training stack (DrJAX's MapReduce-primitive
 discipline, SCALE-Sim's cost-model validation — PAPERS.md):
 
 - **TRC001** — no host callbacks (``pure_callback``/``io_callback``/
-  ``debug_callback``) inside jitted sim programs: a callback re-enters
+  ``debug_callback``/``debug_print``) inside jitted sim programs: a callback re-enters
   the host mid-program, breaking both determinism (host state) and the
   dispatch-ahead pipeline (implicit sync).
 - **TRC002** — no backend-variant or nondeterministic primitives:
@@ -52,8 +52,8 @@ from .rules import RULES
 # TRC001: primitives that re-enter the host from inside a program.
 CALLBACK_PRIMS = frozenset({
     "pure_callback", "io_callback", "debug_callback",
-    # legacy host_callback spellings, in case a dependency resurrects them
-    "outside_call", "host_callback",
+    # jax.debug.print traces to its own primitive, not debug_callback
+    "debug_print",
 })
 
 # TRC002: outright-forbidden primitives (stateful/approximate kernels whose
@@ -765,10 +765,7 @@ def registry() -> Dict[str, TraceProgram]:
 def _x64_ctx():
     import jax
 
-    ctx = getattr(jax, "enable_x64", None)
-    if ctx is None:  # pragma: no cover — newer jax
-        from jax.experimental import enable_x64 as ctx
-    return ctx
+    return jax.enable_x64
 
 
 def _finding(program: str, rule: str, msg: str) -> Finding:

@@ -299,8 +299,6 @@ class BridgeKernel:
 
     def __init__(self, seeds, *, cap: int = 128, k_events: int = 4,
                  device: str = None, metrics: bool = False):
-        import os
-
         import jax
         import jax.numpy as jnp
 
@@ -308,25 +306,16 @@ class BridgeKernel:
         from ..ops.threefry import derive_stream_np
 
         self._jax = jax
-        # jax.enable_x64 moved to the top level after 0.4.x; reach the
-        # experimental home on older installs so the bridge runs on both.
-        self._enable_x64 = getattr(jax, "enable_x64", None)
-        if self._enable_x64 is None:
-            from jax.experimental import enable_x64 as _x64
-
-            self._enable_x64 = _x64
+        self._enable_x64 = jax.enable_x64
         self.W = len(seeds)
         self.cap = cap
         self.k_events = k_events
         self.metrics_enabled = bool(metrics)
-        # The lockstep protocol is dispatch-latency bound (one step per
-        # event cluster), so the kernel defaults to the LOCAL XLA backend:
-        # a co-located accelerator amortizes at large W, but a tunneled
-        # remote TPU (hundreds of ms per dispatch) never can. Override
-        # with device= or MADSIM_BRIDGE_DEVICE to place the kernel on an
-        # accelerator whose dispatch latency you have measured.
-        name = device or os.environ.get("MADSIM_BRIDGE_DEVICE", "cpu")
-        self.device = jax.local_devices(backend=name)[0]
+        # JAX's default device unless ``device`` names a backend
+        # ("cpu", "tpu"): the lockstep protocol pays one dispatch per
+        # event cluster, so a caller may pin the kernel to the host.
+        self.device = (jax.local_devices(backend=device)[0] if device
+                       else jax.local_devices()[0])
         seeds = np.asarray(seeds, dtype=np.uint64)
         k0 = (seeds & np.uint64(0xFFFFFFFF)).astype(np.uint32)
         k1 = (seeds >> np.uint64(32)).astype(np.uint32)

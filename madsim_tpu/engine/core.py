@@ -45,6 +45,7 @@ from .lanes import (
     PACKED,
     WIDE,
     Lanes,
+    deinterleave,
     join_wide,
     narrow,
     narrow_wrap,
@@ -167,15 +168,18 @@ class EngineConfig:
     # one VMEM residency on TPU instead of round-tripping HBM between
     # XLA fusions. Off by default: CPU tier-1 compiles the existing lax
     # programs unchanged. Bitwise identical to the lax step (the kernel
-    # body IS the step function, gated in tests and `make smoke`).
+    # body IS the step function, gated in tests and `make smoke`). Runs
+    # interpreted only: it does not lower on a TPU yet.
     pallas: bool = False
     # World-axis block per Pallas grid step (None = whole batch in one
-    # kernel invocation). Must divide the batch width when set;
-    # otherwise the call falls back to the single-block form.
+    # kernel invocation). Must divide the batch width: the kernel's
+    # first trace raises on one that does not.
     pallas_block: Optional[int] = None
     # Force/disable interpreter-mode Pallas (None = auto: interpret
     # everywhere except on real TPU backends). Interpret mode keeps the
-    # kernel runnable — and the bitwise-identity gate green — on CPU.
+    # kernel runnable — and the bitwise-identity gate green — on CPU;
+    # Mosaic lowering is refused with NotImplementedError until the
+    # kernel lowers (pallas_step.MOSAIC_LOWERING_GAPS).
     pallas_interpret: Optional[bool] = None
 
     def __post_init__(self):
@@ -725,8 +729,9 @@ class DeviceEngine:
             # backend-independent. Counters (and therefore values) are
             # bit-identical to the per-slot sequential draws.
             xs, rng = next_u32_vec(ws.rng, 2 * m)
-            lat = _u32_to_range(xs[0::2], ws.lat_min, ws.lat_max)  # (M,)
-            u = _u32_to_unit_f32(xs[1::2])                         # (M,)
+            even, odd = deinterleave(xs)
+            lat = _u32_to_range(even, ws.lat_min, ws.lat_max)      # (M,)
+            u = _u32_to_unit_f32(odd)                              # (M,)
             dst = jnp.clip(ob.dst, 0, cfg.n_nodes - 1)             # (M,)
             clogged = take_small(ws.clog_node, src) \
                 | take_small(ws.clog_node, dst) \

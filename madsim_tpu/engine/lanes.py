@@ -7,15 +7,30 @@ The doctrine, refined by measurement over two perf rounds
   a lone ``x.at[i].set(v)`` with a traced index lowers to a scatter XLA
   cannot fuse, while the mask write fuses into the surrounding kernel
   and vectorizes over the world axis for free.
-- **Reads** use real gathers (``take_small``) when the source axis is
-  tiny (nodes N ≤ 8, log rows L ≤ 64, outbox M ≤ 8): the one-hot
-  contraction costs k·m·width ops per read — measured as one of the
-  step's dominant flop consumers — while the gather is priced at ~zero
-  and its operand is a state buffer that is materialized anyway.
+- **Reads** (``take_small``) from a tiny source axis (nodes N ≤ 8, log
+  rows L ≤ 64, outbox M ≤ 8) depend on the backend. On the CPU a real
+  gather: the one-hot select costs k·m·width ops per read — measured as
+  one of the step's dominant flop consumers there — while the gather is
+  priced at ~zero. On a TPU the opposite: XLA's TPU cost model prices
+  each vmapped gather at ~0.5 MB per world (a step held ~50 of them,
+  ~5.5 MB/world/step, and the 524,288-world headline did not finish a
+  sweep in 30 minutes on the chip), against ~160 B for the select
+  chain. Both forms clamp out-of-range indices, so values are equal.
+  Re-measured on today's code (CPU, 4 cores, PR 21): the select forms
+  make the 8,192-seed headline sweep 4-6x slower warm (8.0-10.7 s vs
+  1.6-2.1 s) and the 2,048-world chaos sweep 13-20x (23.8-32.1 s vs
+  1.5-1.9 s), so the CPU keeps the gathers.
 - **The queue insert** (``queue.push_many``) is the one deliberate
-  scatter: M rows, computed slots, in-place under buffer donation — see
-  its docstring for why it beats both the unrolled one-hot chain and a
-  (Q,)-gather-driven rewrite.
+  scatter on the CPU: M rows, computed slots, in-place under buffer
+  donation — see its docstring for why it beats both the unrolled
+  one-hot chain and a (Q,)-gather-driven rewrite. On a TPU it writes the
+  same rows with one select per row.
+
+The form follows the platform the program is traced for
+(:func:`gathers_are_cheap`): the default device's when one is set —
+``jax.default_device(cpu)`` on a TPU host traces the CPU's program, as
+the chip-vs-CPU crosscheck and a CPU-placed bridge kernel do — else the
+default backend's.
 
 Anything not covered above goes through these helpers rather than raw
 ``x[i]`` / ``.at[i]`` so the layout decisions keep exactly one home.
@@ -46,6 +61,7 @@ from __future__ import annotations
 
 from typing import Any, NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -236,8 +252,36 @@ def prefix_count(mask: jnp.ndarray) -> jnp.ndarray:
                                      np.uint32(0xFFFFFFFF)), jnp.uint32)
         counts = counts + lax.population_count(word & below) \
             .astype(jnp.int32)
+    if not gathers_are_cheap():
+        return counts
     # Identity gather = an explicit materialization point (see docstring).
     return jnp.take(counts, jnp.arange(n), axis=0)
+
+
+def gathers_are_cheap() -> bool:
+    """Whether the programs being traced may use real gathers: True on
+    every platform but the TPU (see the module docstring). Read at trace
+    time from the default device when one is set (``jax.default_device``
+    is part of jit's cache key, so each placement traces its own form),
+    else from the default backend; a test that compiles for a described
+    TPU without one steers it."""
+    dev = jax.config.jax_default_device
+    platform = dev if isinstance(dev, str) else getattr(dev, "platform",
+                                                        None)
+    return (platform or jax.default_backend()) != "tpu"
+
+
+def deinterleave(x: jnp.ndarray):
+    """``(x[0::2], x[1::2])`` of a 1-D ``x`` of even length. Under vmap
+    the strided index is a gather; on the TPU a strided static slice
+    instead (the gather made phase A's step 24,781 bytes per world in
+    the v5e cost model vs 15,312). On the CPU the gather stays: the slice
+    raised the engine programs' cost-model flops 35-54% and the 2,048-
+    world chaos sweep 8-33% warm (my CPU runs, PR 21)."""
+    if gathers_are_cheap():
+        return x[0::2], x[1::2]
+    n = x.shape[0]
+    return lax.slice(x, (0,), (n,), (2,)), lax.slice(x, (1,), (n,), (2,))
 
 
 def take_small(x: jnp.ndarray, idxs: jnp.ndarray) -> jnp.ndarray:
@@ -254,9 +298,18 @@ def take_small(x: jnp.ndarray, idxs: jnp.ndarray) -> jnp.ndarray:
     Out-of-range indices clamp to the edge ("clip" mode — measured
     cheaper post-fusion than both ``promise_in_bounds``'s at-get lowering
     and "wrap"); callers with possibly-wild indices get edge values and
-    must mask the result.
+    must mask the result. Where gathers are not cheap (the TPU) the same
+    values come from a select chain over the m source rows.
     """
-    return jnp.take(x, jnp.asarray(idxs, jnp.int32), axis=0, mode="clip")
+    idxs = jnp.asarray(idxs, jnp.int32)
+    if gathers_are_cheap():
+        return jnp.take(x, idxs, axis=0, mode="clip")
+    idxs = jnp.clip(idxs, 0, x.shape[0] - 1)
+    pick = idxs.reshape(idxs.shape + (1,) * (x.ndim - 1))
+    out = jnp.broadcast_to(x[0], idxs.shape + x.shape[1:])
+    for i in range(1, x.shape[0]):
+        out = jnp.where(pick == i, x[i], out)
+    return out
 
 
 def upd(x: jnp.ndarray, i, v) -> jnp.ndarray:

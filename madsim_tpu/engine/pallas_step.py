@@ -25,11 +25,14 @@ Deployment shape:
   the kernel through the Pallas interpreter — same primitive sequence,
   bit-identical results, no Mosaic lowering required. This is what
   keeps the gate green in CI.
-- **TPU**: real lowering, whole batch in one kernel invocation by
-  default (state blocks resident in VMEM), or gridded over the world
-  axis via ``EngineConfig(pallas_block=B)`` when W worlds exceed VMEM —
-  each grid step owns a ``(B, ...)`` block of every state leaf
-  (worlds are independent, so the block split is semantics-free).
+- **TPU**: not yet. Mosaic refuses the kernel (see
+  ``MOSAIC_LOWERING_GAPS``), so a non-interpreted kernel raises
+  ``NotImplementedError`` at engine construction instead of a Mosaic
+  assertion at first trace. The intended shape: the whole batch in one
+  kernel invocation, or gridded over the world axis via
+  ``EngineConfig(pallas_block=B)`` — each grid step owns a
+  ``(B, ...)`` block of every state leaf (worlds are independent, so
+  the block split is semantics-free).
 - ``input_output_aliases`` maps every state leaf onto its output slot,
   the in-kernel analog of the run loop's buffer donation: the state is
   updated in place, not double-buffered.
@@ -46,6 +49,14 @@ import jax
 import jax.numpy as jnp
 
 
+# What Mosaic (jax 0.9.0, libtpu 0.0.34) refuses when the kernel is
+# compiled for a v5e, in the order the compiler hits them.
+MOSAIC_LOWERING_GAPS = (
+    "rank-0 hoisted constants: Mosaic 'supports only blocks of rank >= 1'",
+    "the step's queue gathers: no Mosaic lowering in _gather_lowering_rule",
+)
+
+
 def _interpret_default() -> bool:
     """Interpret everywhere but on a real TPU backend: the interpreter
     is the portable (and CPU tier-1) execution mode; Mosaic lowering is
@@ -60,22 +71,32 @@ def make_pallas_step(step_one: Callable, cfg) -> Callable:
     ``pallas_block`` / ``pallas_interpret`` knobs."""
     from jax.experimental import pallas as pl
 
+    interpret = cfg.pallas_interpret
+    if interpret is None:
+        interpret = _interpret_default()
+    if not interpret:
+        raise NotImplementedError(
+            "EngineConfig(pallas=True) does not lower on TPU yet; Mosaic "
+            "refuses the kernel for: " + "; ".join(MOSAIC_LOWERING_GAPS)
+            + ". Use the lax step (pallas=False) on a TPU, or "
+            "pallas_interpret=True.")
     batched_step = jax.vmap(step_one)
 
     def pallas_batched_step(state):
         leaves, treedef = jax.tree_util.tree_flatten(state)
         n = len(leaves)
         w = leaves[0].shape[0]
-        interpret = cfg.pallas_interpret
-        if interpret is None:
-            interpret = _interpret_default()
 
         def flat_step(*ls):
             s = jax.tree_util.tree_unflatten(treedef, ls)
             return jax.tree_util.tree_leaves(batched_step(s))
 
         block = cfg.pallas_block
-        gridded = block is not None and block < w and w % block == 0
+        if block is not None and w % block:
+            raise ValueError(
+                f"pallas_block={block} does not divide the batch of {w} "
+                f"worlds")
+        gridded = block is not None and block < w
         bw = block if gridded else w
 
         # The step closure carries constant tables (the popcount
@@ -106,7 +127,7 @@ def make_pallas_step(step_one: Callable, cfg) -> Callable:
             # Every state leaf aliases its output slot: in-place update
             # inside the kernel, the donation story of the lax path.
             input_output_aliases={i: i for i in range(n)},
-            interpret=bool(interpret),
+            interpret=True,
         )
         if gridded:
             # Grid over the world axis: grid step i owns worlds

@@ -41,9 +41,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from . import lanes as _lanes
 from .lanes import narrow, onehot, prefix_count, take_small, widen
 
-INF_TIME = jnp.int32(2**31 - 1)
+INF_TIME = np.int32(2**31 - 1)
 
 # Event flag bits.
 FLAG_TIMER = 1  # gen-checked against the destination node's generation
@@ -216,11 +217,16 @@ def push_many(q: EventQueue, evs: Event, enable=None,
     rank = prefix_count(en)
     base_time = q.time
     free = base_time == INF_TIME
+    scatter = _lanes.gathers_are_cheap()
     if clear is not None:
         cslot, cfound = clear
-        free = free | (onehot(cslot, qcap) & cfound)
-        base_time = base_time.at[jnp.where(cfound, cslot, qcap)].set(
-            INF_TIME, mode="drop")
+        cleared = onehot(cslot, qcap) & cfound
+        free = free | cleared
+        if scatter:
+            base_time = base_time.at[jnp.where(cfound, cslot, qcap)].set(
+                INF_TIME, mode="drop")
+        else:
+            base_time = jnp.where(cleared, INF_TIME, base_time)
     # Pack the free mask into uint32 words: bit s of word w ⇔ slot
     # 32w + s is free. Everything below runs on these scalars.
     words = []
@@ -231,7 +237,9 @@ def push_many(q: EventQueue, evs: Event, enable=None,
         words.append(jnp.sum(jnp.where(free[32 * w:32 * w + lanes], pow2,
                                        jnp.uint32(0))))
     n_free = sum(lax.population_count(w).astype(jnp.int32) for w in words)
-    n_en = rank[-1] + en[-1].astype(jnp.int32)
+    # Static slices, not rank[-1]: under vmap that index is a gather.
+    n_en = (lax.slice(rank, (m - 1,), (m,))
+            + lax.slice(en, (m - 1,), (m,)).astype(jnp.int32))[0]
     ok = ~en | (rank < n_free)
     # Order-preserving compaction of the enabled events to the front:
     # row r of the compacted table is the event with rank r. The (M, M)
@@ -245,6 +253,46 @@ def push_many(q: EventQueue, evs: Event, enable=None,
     cpay = take_small(evs.payload, ev_idx)
     # Target slot of rank r = lowest set bit still standing; clear it and
     # move on. Ranks past n_en aim at slot Q and are dropped.
+    if m * len(words) > _UNROLL_MAX:
+        slots = _rank_slots_sorted(free, m, n_en)
+    else:
+        slots = _rank_slots_unrolled(words, m, n_en, qcap)
+    # Saturating narrow at the write boundary (packed payload lane);
+    # engine-split wide params (lanes.split_wide) are in range by
+    # construction, so the clip never bites them.
+    cpay = narrow(cpay, q.payload.dtype)
+    if scatter:
+        # Slots are distinct (dropped ranks all aim at the same
+        # out-of-range Q, which "drop" discards), so the scatters are
+        # order-independent; XLA chains the clear scatter and this one
+        # through a single buffer.
+        q = EventQueue(time=base_time.at[slots].set(ct, mode="drop"),
+                       meta=q.meta.at[slots].set(cmeta, mode="drop"),
+                       payload=q.payload.at[slots].set(cpay, mode="drop"))
+    else:
+        # Where gathers are not cheap neither are scatters (the TPU):
+        # one select per rank over the Q lanes, the same writes.
+        time, meta, pay = base_time, q.meta, q.payload
+        for r in range(m):
+            hit = slots[r] == jnp.arange(qcap, dtype=jnp.int32)
+            time = jnp.where(hit, ct[r], time)
+            meta = jnp.where(hit, cmeta[r], meta)
+            pay = jnp.where(hit[:, None], cpay[r], pay)
+        q = EventQueue(time=time, meta=meta, payload=pay)
+    return q, ok, jnp.minimum(n_en, n_free)
+
+
+# Above this many (rank x word) steps the unrolled lowest-set-bit chain is
+# replaced by one sort: the chain is a serial dependency M*Q/32 selects
+# long, and at init's whole-fault-schedule batches (M in the hundreds)
+# XLA CPU did not compile it in 30 minutes. The step's outbox batches
+# (M <= 8, Q <= 64) stay unrolled: sorting them made phase A's warm
+# sweep 19% slower on a v5e and the CPU sweeps 5-6x slower (PR 21). The
+# threshold between those two ends is not tuned.
+_UNROLL_MAX = 256
+
+
+def _rank_slots_unrolled(words, m, n_en, qcap):
     slots = []
     for r in range(m):
         pos = jnp.int32(qcap)
@@ -260,20 +308,20 @@ def push_many(q: EventQueue, evs: Event, enable=None,
             placed = placed | use
         words = nxt
         slots.append(jnp.where(r < n_en, pos, qcap))
-    slots = jnp.stack(slots)
-    # Slots are distinct (dropped ranks all aim at the same out-of-range
-    # Q, which "drop" discards), so the scatters are order-independent;
-    # XLA chains the clear scatter and this one through a single buffer.
-    q = EventQueue(
-        time=base_time.at[slots].set(ct, mode="drop"),
-        meta=q.meta.at[slots].set(cmeta, mode="drop"),
-        # Saturating narrow at the scatter boundary (packed payload
-        # lane); engine-split wide params (lanes.split_wide) are in
-        # range by construction, so the clip never bites them.
-        payload=q.payload.at[slots].set(narrow(cpay, q.payload.dtype),
-                                        mode="drop"),
-    )
-    return q, ok, jnp.minimum(n_en, n_free)
+    return jnp.stack(slots)
+
+
+def _rank_slots_sorted(free, m, n_en):
+    """The same assignment in closed form: rank r takes the r-th free
+    slot in lowest-first order (a stable sort puts the free slots first,
+    in slot order); ranks past the free count or ``n_en`` aim at Q."""
+    qcap = free.shape[0]
+    order = jnp.argsort((~free).astype(jnp.int32), stable=True)
+    pick = jnp.concatenate([order[:m].astype(jnp.int32),
+                            jnp.full((max(m - qcap, 0),), qcap, jnp.int32)])
+    n_free = jnp.sum(free, dtype=jnp.int32)
+    r = jnp.arange(m, dtype=jnp.int32)
+    return jnp.where((r < n_en) & (r < n_free), pick, qcap)
 
 
 def insert_metrics(times, enable, n_inserted):

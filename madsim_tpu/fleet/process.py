@@ -46,6 +46,53 @@ def _wire_safe(kw: Dict[str, Any]) -> Dict[str, Any]:
     return out
 
 
+def _spawned_backend():
+    """``(platform, held)``: the JAX platform a spawned worker will get,
+    and whether THIS process already holds it.
+
+    Learned without taking the chip: from this process's backends when
+    it has initialized them, else from ``JAX_PLATFORMS``, else from a
+    short-lived probe process (which releases the chip as it exits)."""
+    if "jax" in sys.modules:
+        from jax._src import xla_bridge
+
+        if xla_bridge.backends_are_initialized():
+            import jax
+
+            return jax.default_backend(), True
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return platforms.split(",")[0], False
+    import subprocess
+
+    probe = subprocess.run(
+        [sys.executable, "-c", "import jax; print(jax.default_backend())"],
+        capture_output=True, text=True, timeout=300)
+    lines = probe.stdout.split()
+    return (lines[-1] if probe.returncode == 0 and lines else "unknown",
+            False)
+
+
+def _check_one_process_per_chip(n_workers: int) -> None:
+    """A TPU belongs to one process, and a spawned worker's JAX claims
+    every chip of its host. Refuse at once what would otherwise fail or
+    hang in the second worker's backend start."""
+    platform, held = _spawned_backend()
+    if platform != "tpu":
+        return
+    if held:
+        raise RuntimeError(
+            "process_fleet_sweep: this process has initialized JAX on the "
+            "TPU and holds the chip, so no spawned worker can get it. Call "
+            "it from a process that has not touched JAX's devices.")
+    if n_workers > 1:
+        raise RuntimeError(
+            f"process_fleet_sweep: n_workers={n_workers} on a TPU host, "
+            f"but each spawned worker claims every chip of the host, so "
+            f"only one worker can hold them. Use n_workers=1, or spread "
+            f"worlds over the chips in one process with sweep(mesh=...).")
+
+
 class PipeTransport:
     """Worker-side transport: one request/response per call over the
     process's pipe to the coordinator."""
@@ -71,15 +118,12 @@ def _worker_main(conn, worker_id: str, actor, cfg, seeds, faults,
                  sweep_kwargs: Dict[str, Any]) -> None:
     """Entry point of a spawned worker process."""
     # Spawned fresh: the parent's test/CI environment (JAX_PLATFORMS,
-    # XLA device-count flags) rides the inherited env vars; the engine
-    # and all jit caches are rebuilt here, as on any real fleet host.
-    # The persistent compilation cache (MADSIM_COMPILE_CACHE, set by the
-    # parent when a checkpoint dir exists) turns that rebuild into a
-    # disk load after the first worker compiles — without it, N workers
-    # compile the identical sweep program N times.
-    from ..parallel.compile_cache import enable_from_env
-
-    enable_from_env()
+    # XLA device-count flags, JAX_COMPILATION_CACHE_DIR) rides the
+    # inherited env vars; the engine and all jit caches are rebuilt
+    # here, as on any real fleet host. The persistent compilation cache
+    # turns that rebuild into a disk load after the first worker
+    # compiles — without it, N workers compile the identical sweep
+    # program N times.
     from ..engine.core import DeviceEngine
     from .worker import Worker
 
@@ -138,6 +182,7 @@ def process_fleet_sweep(actor, cfg, seeds, *, n_workers: int,
     import multiprocessing as mp
     import signal
 
+    _check_one_process_per_chip(n_workers)
     from ..obs import observatory as _obsy
     from .coordinator import Coordinator
 
@@ -149,14 +194,12 @@ def process_fleet_sweep(actor, cfg, seeds, *, n_workers: int,
                               n_devices=1)
     if checkpoint_dir is not None:
         os.makedirs(checkpoint_dir, exist_ok=True)
-        # Workers inherit env on spawn: point their persistent XLA
-        # cache at the durable workdir so respawns (and workers 2..N)
-        # load executables instead of recompiling them. An explicit
-        # MADSIM_COMPILE_CACHE in the environment wins.
-        from ..parallel.compile_cache import ENV_VAR
+    # Workers inherit env on spawn: hand them the parent's persistent
+    # cache directory (the one compile-cache rule) so respawns and
+    # workers 2..N load executables instead of recompiling them.
+    from ..parallel import compile_cache
 
-        os.environ.setdefault(
-            ENV_VAR, os.path.join(checkpoint_dir, "xla_cache"))
+    os.environ[compile_cache.ENV_VAR] = compile_cache.cache_dir()
     del retry  # worker-side policy is fixed in _worker_main
 
     ctx = mp.get_context("spawn")
@@ -238,7 +281,12 @@ def process_fleet_sweep(actor, cfg, seeds, *, n_workers: int,
         for p in procs.values():
             if p.exitcode is None:
                 p.terminate()
+        # No worker outlives the call: one still shutting down holds the
+        # TPU, and the caller's next JAX start would fail on the chip.
         for p in procs.values():
             p.join(timeout=5.0)
+            if p.exitcode is None:
+                p.kill()
+                p.join()
         if close is not None:
             close()

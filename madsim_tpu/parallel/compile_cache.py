@@ -1,64 +1,55 @@
-"""Persistent XLA compilation cache for cold processes.
+"""The one rule for JAX's persistent compilation cache.
 
-Every spawned fleet worker (``fleet/process.py``) and every CI
-invocation pays the full program-set compile from scratch: jit caches
-are per-process, and a fleet of N workers compiles the SAME sweep
-runner N times. JAX's persistent compilation cache
-(``jax_compilation_cache_dir``) is the fix — executables are stored on
-disk keyed by HLO + compile flags, so the first process populates and
-every later cold process (worker respawn after SIGKILL, the next CI
-shard, the next ``make check``) loads instead of compiling.
+If ``JAX_COMPILATION_CACHE_DIR`` is set, the cache lives there and no
+code of this repo sets another path. Otherwise it lives at the fixed
+``<checkout>/.jax_cache`` (gitignored): the path is part of what makes a
+later process find the entries, so it must not move between runs.
 
-Correctness-neutral by construction: the cache key covers the program
-and the backend configuration, and result determinism is separately
-pinned by the crosscheck/determinism suites — ``tests/
-test_compile_cache.py`` additionally asserts cached-vs-fresh bitwise
-equality end to end.
+:func:`apply` runs at package import (``madsim_tpu/__init__.py``, loaded
+by file path so it fires before any program compiles: jax latches the
+cache at its first compile). When jax is not imported yet it only sets
+environment variables, which jax reads at its own import, so the
+host-only import path stays jax-free and every child process — bench
+children, spawned fleet workers — inherits the same directory.
+Thresholds are zeroed so every program is cached: this codebase's
+programs are few, large, and identical across processes.
 
-Opt-in surfaces:
-
-- ``enable_compilation_cache(path)`` — call before tracing; idempotent.
-- ``MADSIM_COMPILE_CACHE`` env var — honored by spawned fleet workers
-  (set automatically by ``process_fleet_sweep`` when the fleet has a
-  checkpoint dir: the cache lives beside the checkpoints, the one
-  durable workdir a deployment already has) and by ``make check``.
+Correctness-neutral: the cache key covers the program and the backend
+configuration (``tests/test_compile_cache.py`` asserts cached-vs-fresh
+bitwise equality end to end). ``analysis/budgets.py`` turns the cache
+off around its own fresh compiles.
 """
 from __future__ import annotations
 
 import os
-from typing import Optional
+import sys
 
-ENV_VAR = "MADSIM_COMPILE_CACHE"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
-_enabled_dir: Optional[str] = None
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-
-def enable_compilation_cache(cache_dir: str) -> str:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
-
-    Safe to call more than once (last path wins, matching
-    ``jax.config`` semantics) and before OR after jax is first
-    imported — but must run before the programs you want cached are
-    compiled. Thresholds are zeroed so every program is eligible: this
-    codebase's programs are few, large, and identical across processes,
-    the exact shape the cache exists for.
-    """
-    global _enabled_dir
-    import jax
-
-    cache_dir = os.path.abspath(cache_dir)
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    _enabled_dir = cache_dir
-    return cache_dir
+_THRESHOLDS = {"jax_persistent_cache_min_compile_time_secs": 0.0,
+               "jax_persistent_cache_min_entry_size_bytes": -1}
 
 
-def enable_from_env() -> Optional[str]:
-    """Enable the cache iff ``MADSIM_COMPILE_CACHE`` is set (worker-
-    process entry hook). Returns the cache dir, or None if unset."""
-    path = os.environ.get(ENV_VAR)
-    if not path:
-        return None
-    return enable_compilation_cache(path)
+def cache_dir() -> str:
+    """The directory the rule picks."""
+    return os.environ.get(ENV_VAR) or DEFAULT_DIR
+
+
+def apply() -> str:
+    """Point JAX's persistent cache at :func:`cache_dir`; returns it."""
+    path = cache_dir()
+    if "jax" in sys.modules:
+        import jax
+
+        jax.config.update("jax_compilation_cache_dir", path)
+        for name, value in _THRESHOLDS.items():
+            jax.config.update(name, value)
+    else:
+        os.environ[ENV_VAR] = path
+        for name, value in _THRESHOLDS.items():
+            os.environ.setdefault(name.upper(), str(value))
+    return path

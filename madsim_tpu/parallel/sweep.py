@@ -38,10 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 
-try:  # jax >= 0.8 promotes shard_map out of experimental
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..engine.core import DeviceEngine, EngineConfig, WorldState
 from .mesh import (
@@ -139,12 +136,8 @@ def sharded_engine(eng: DeviceEngine, mesh: Mesh, chunk_steps: int = 512,
         in_specs = (spec, sp, sp, spec, sp)
         out_specs = (spec, sp, sp, sp, sp, sp)
 
-    try:  # jax >= 0.8 renamed check_rep -> check_vma
-        mapped = shard_map(chunk, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
-    except TypeError:  # pragma: no cover — older jax
-        mapped = shard_map(chunk, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+    mapped = shard_map(chunk, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     runner = jax.jit(mapped, donate_argnums=(0,) if donate else ())
     cache[key] = runner
     return runner
@@ -230,12 +223,8 @@ def sharded_superstep(eng: DeviceEngine, mesh: Mesh, chunk_steps: int,
         in_specs = (spec, sp, sp, spec, sp, sp, sp, sp)
         out_specs = (spec, sp, sp, sp, sp, sp, sp, sp)
 
-    try:  # jax >= 0.8 renamed check_rep -> check_vma
-        mapped = shard_map(sstep, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
-    except TypeError:  # pragma: no cover — older jax
-        mapped = shard_map(sstep, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+    mapped = shard_map(sstep, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     runner = jax.jit(mapped, donate_argnums=(0,) if donate else ())
     cache[key] = runner
     return runner
@@ -271,12 +260,8 @@ def _cov_endfolder(eng: DeviceEngine, mesh: Mesh):
 
     in_specs = (spec, sp, sp, spec, sp, sp)
     out_specs = (sp, sp)
-    try:  # jax >= 0.8 renamed check_rep -> check_vma
-        mapped = shard_map(fold_end, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
-    except TypeError:  # pragma: no cover — older jax
-        mapped = shard_map(fold_end, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+    mapped = shard_map(fold_end, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     fn = jax.jit(mapped)
     cache[mesh] = fn
     return fn

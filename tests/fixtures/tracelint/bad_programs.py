@@ -11,7 +11,7 @@ import numpy as np
 
 
 def leaky_callback(x):
-    """TRC001 x2: a pure_callback and a debug.print (debug_callback)."""
+    """TRC001 x2: a pure_callback and a debug.print (debug_print)."""
     y = jax.pure_callback(lambda v: np.asarray(v) + 1,
                           jax.ShapeDtypeStruct((), jnp.int32), x)
     jax.debug.print("x={x}", x=x)

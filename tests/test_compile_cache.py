@@ -3,10 +3,12 @@
 A fleet of spawned workers (fleet/process.py) builds N identical
 engines in N fresh JAX runtimes; without the on-disk cache each pays
 the full XLA compile of the same sweep program. The contract under
-test: with ``MADSIM_COMPILE_CACHE`` set, the FIRST cold process
+test: with ``JAX_COMPILATION_CACHE_DIR`` set, the FIRST cold process
 populates the cache, a SECOND cold process loads instead of compiling
 (counted via the persistent-cache hit log line), and the cached run's
-results are bitwise identical to the fresh run's.
+results are bitwise identical to the fresh run's. Unset, the cache
+goes to the fixed ``<checkout>/.jax_cache`` (parallel/compile_cache.py,
+the one rule).
 """
 import json
 import os
@@ -14,6 +16,9 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _CHILD = r"""
 import json, logging, os, sys
@@ -25,9 +30,11 @@ class _Cap(logging.Handler):
     def emit(self, record):
         records.append(record.getMessage())
 
-from madsim_tpu.parallel.compile_cache import enable_from_env
+import madsim_tpu
+import jax
 
-assert enable_from_env() == os.environ["MADSIM_COMPILE_CACHE"]
+assert jax.config.jax_compilation_cache_dir == \
+    os.environ["JAX_COMPILATION_CACHE_DIR"]
 
 # The persistent-cache layer logs hits/misses under jax's logger tree.
 h = _Cap(level=logging.DEBUG)
@@ -60,13 +67,12 @@ json.dump({"hits": hits,
 
 def _run_child(cache_dir):
     env = dict(os.environ,
-               MADSIM_COMPILE_CACHE=str(cache_dir),
+               JAX_COMPILATION_CACHE_DIR=str(cache_dir),
                JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     out = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                          capture_output=True, text=True, timeout=600,
-                         cwd=os.path.dirname(os.path.dirname(
-                             os.path.abspath(__file__))))
+                         cwd=REPO)
     assert out.returncode == 0, out.stderr[-4000:]
     return json.loads(out.stdout)
 
@@ -89,23 +95,44 @@ def test_second_cold_process_reuses_cache(tmp_path):
                                       cached["steps"][k], err_msg=k)
 
 
-def test_env_hook_points_jax_at_the_dir(tmp_path, monkeypatch):
-    """The worker-entry hook (fleet/process.py calls this before
-    building the engine): no-op when the var is unset, creates + wires
-    the directory when set."""
+def test_rule_points_jax_at_the_dir(tmp_path, monkeypatch):
+    """With jax already imported, the rule updates jax's config: the
+    env var's directory when set, else the fixed checkout path."""
     import jax
 
     from madsim_tpu.parallel import compile_cache as cc
 
     prev = jax.config.jax_compilation_cache_dir
-    monkeypatch.delenv(cc.ENV_VAR, raising=False)
     try:
-        assert cc.enable_from_env() is None
-        assert jax.config.jax_compilation_cache_dir == prev
-        target = tmp_path / "xla_cache"
-        monkeypatch.setenv(cc.ENV_VAR, str(target))
-        assert cc.enable_from_env() == str(target)
-        assert jax.config.jax_compilation_cache_dir == str(target)
-        assert target.is_dir()
+        monkeypatch.delenv(cc.ENV_VAR, raising=False)
+        assert cc.apply() == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == cc.DEFAULT_DIR
+        target = str(tmp_path / "xla_cache")
+        monkeypatch.setenv(cc.ENV_VAR, target)
+        assert cc.apply() == target
+        assert jax.config.jax_compilation_cache_dir == target
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
+
+
+@pytest.mark.parametrize("env_dir", [None, "custom"])
+def test_package_import_applies_the_rule_before_jax(tmp_path, env_dir):
+    """A fresh process that imports madsim_tpu before jax stays jax-free
+    on import, and the jax it imports next caches where the rule says —
+    and only there."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    want = os.path.join(REPO, ".jax_cache")
+    if env_dir:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    code = ("import sys, madsim_tpu\n"
+            "assert 'jax' not in sys.modules\n"
+            "import jax, jax.numpy as jnp\n"
+            "jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+    out = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split()[-1] == want
+    if env_dir:
+        assert any(os.scandir(want)), "no cache entry written to the dir"

@@ -535,3 +535,33 @@ def test_process_fleet_smoke(tmp_path):
     for k in single.observations:
         np.testing.assert_array_equal(single.observations[k],
                                       fleet.observations[k], err_msg=k)
+
+
+@pytest.mark.parametrize("held,n_workers,match", [
+    (True, 1, "holds the chip"),
+    (False, 2, "n_workers=2 on a TPU host"),
+])
+def test_process_fleet_refuses_a_second_process_on_the_chip(
+        monkeypatch, held, n_workers, match):
+    """On a TPU host (steered here: the suite runs on the CPU) a worker
+    that cannot get the chip is refused before anything spawns, instead
+    of failing or hanging in the worker's backend start."""
+    from madsim_tpu.fleet import process as fp
+
+    monkeypatch.setattr(fp, "_spawned_backend", lambda: ("tpu", held))
+    with pytest.raises(RuntimeError, match=match):
+        fp.process_fleet_sweep(RaftActor(RCFG), ECFG, np.arange(8),
+                               n_workers=n_workers, range_size=4,
+                               **SWEEP_KW)
+
+
+def test_spawned_backend_reads_this_process_without_a_probe():
+    """This process has initialized JAX on the CPU: the check answers
+    from it (no probe process) and lets CPU fleets through."""
+    import jax
+
+    from madsim_tpu.fleet import process as fp
+
+    jax.devices()
+    assert fp._spawned_backend() == ("cpu", True)
+    fp._check_one_process_per_chip(4)

@@ -95,21 +95,47 @@ def test_pallas_overflow_mid_batch_bitwise_identical():
 
 def test_pallas_world_block_grid_bitwise_identical(raft_pair):
     """pallas_block grids the kernel over the world axis (the VMEM-fit
-    knob on TPU); a non-dividing block falls back to one block. Both
-    must stay bitwise identical to the monolithic kernel."""
+    knob on TPU); it must stay bitwise identical to the monolithic
+    kernel."""
     lax_eng, _, mk, cfg = raft_pair
     sl = lax_eng.run(lax_eng.init(SEEDS), 1_000)
-    for block in (4, 5):  # 5 does not divide 16: fallback path
-        eng = DeviceEngine(mk(), dataclasses.replace(
-            cfg, pallas=True, pallas_block=block))
-        sb = eng.run(eng.init(SEEDS), 1_000)
-        mism = _leaves_equal(sl, sb)
-        assert not mism, f"pallas_block={block} diverged on: {mism}"
+    eng = DeviceEngine(mk(), dataclasses.replace(
+        cfg, pallas=True, pallas_block=4))
+    sb = eng.run(eng.init(SEEDS), 1_000)
+    mism = _leaves_equal(sl, sb)
+    assert not mism, f"pallas_block=4 diverged on: {mism}"
 
 
 def test_pallas_block_validation():
     with pytest.raises(ValueError, match="pallas_block"):
         EngineConfig(n_nodes=3, pallas=True, pallas_block=0)
+
+
+def test_pallas_block_must_divide_the_batch(raft_pair):
+    """A block that does not divide W raises; it used to fall back to
+    one block in silence."""
+    _, _, mk, cfg = raft_pair
+    eng = DeviceEngine(mk(), dataclasses.replace(
+        cfg, pallas=True, pallas_block=5))
+    with pytest.raises(ValueError, match="pallas_block=5 does not divide"):
+        eng.run(eng.init(SEEDS), 10)
+
+
+@pytest.mark.parametrize("interpret", [None, False])
+def test_pallas_refuses_mosaic_lowering_on_tpu(raft_pair, monkeypatch,
+                                               interpret):
+    """On a TPU backend (steered here: the suite runs on the CPU) the
+    kernel is refused at construction, naming both Mosaic gaps, instead
+    of an assertion deep in Mosaic at first trace."""
+    from madsim_tpu.engine import pallas_step
+
+    monkeypatch.setattr(pallas_step.jax, "default_backend", lambda: "tpu")
+    _, _, mk, cfg = raft_pair
+    with pytest.raises(NotImplementedError) as ei:
+        DeviceEngine(mk(), dataclasses.replace(
+            cfg, pallas=True, pallas_interpret=interpret))
+    for gap in ("rank >= 1", "_gather_lowering_rule"):
+        assert gap in str(ei.value)
 
 
 def test_pallas_state_is_donated_through_the_kernel():

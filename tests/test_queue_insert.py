@@ -60,15 +60,21 @@ def _queues_equal(a, b):
 # Queue-level equivalence: push_many == the sequential push chain
 # ---------------------------------------------------------------------------
 
-def test_push_many_matches_sequential_chain_randomized():
+@pytest.mark.parametrize("trials,caps,ms", [
+    (60, (2, 70), (1, 9)),
+    # Wide batches (init's whole fault schedule) take the sorted slot
+    # assignment instead of the unrolled chain: same contract.
+    (3, (200, 260), (100, 240)),
+], ids=["unrolled", "sorted"])
+def test_push_many_matches_sequential_chain_randomized(trials, caps, ms):
     """Randomized queues (pre-filled, holey after pops) x event batches
     (INF times, disabled slots, more events than capacity): the fused
     insert must reproduce the chain's slot assignment, ok flags, and
     inserted count exactly."""
     rng = np.random.default_rng(0)
-    for trial in range(60):
-        cap = int(rng.integers(2, 70))
-        m = int(rng.integers(1, 9))
+    for trial in range(trials):
+        cap = int(rng.integers(*caps))
+        m = int(rng.integers(*ms))
         p = int(rng.integers(1, 5))
         q = empty_queue(cap, p)
         for _ in range(int(rng.integers(0, cap + 1))):
@@ -315,8 +321,6 @@ def test_step_op_budget_regression():
     state = eng.init(np.arange(w))
     comp = _compile_fresh(eng._run.lower(state, 4_000))
     ca = comp.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-        ca = ca[0]
     per_world = float(ca["flops"]) / w
     assert per_world <= FLOPS_PER_WORLD_STEP_BUDGET, (
         f"step costs {per_world:.0f} cost-model flops/world-step, over the "
@@ -357,3 +361,71 @@ def test_run_donates_its_input_state():
     jax.block_until_ready(out)
     with pytest.raises(RuntimeError, match="deleted|donated"):
         _ = np.asarray(state.now)
+
+
+# ---------------------------------------------------------------------------
+# The TPU's select forms (lanes.gathers_are_cheap() False) == the gather
+# and scatter forms the CPU compiles, whole trajectories
+# ---------------------------------------------------------------------------
+
+_FAULTS = np.array([[400_000, FAULT_KILL, 0, 0],
+                    [900_000, FAULT_RESTART, 0, 0]], np.int32)
+
+
+@pytest.mark.parametrize("family", ["raft_faults", "raft_overflow",
+                                    "raft5_wide_faults", "pb", "tpc"])
+def test_select_forms_bitwise_equal_gather_forms(family, monkeypatch):
+    from madsim_tpu.engine import lanes
+
+    faults = None
+    if family == "raft_faults":
+        actor = RaftActor(RaftDeviceConfig(n=3, n_proposals=2,
+                                           buggy_double_vote=True))
+        cfg = EngineConfig(n_nodes=3, outbox_cap=4, queue_cap=64,
+                           t_limit_us=2_500_000, stop_on_bug=False)
+        faults = _FAULTS
+    elif family == "raft_overflow":
+        actor = RaftActor(RaftDeviceConfig(n=3, n_proposals=2))
+        cfg = EngineConfig(n_nodes=3, outbox_cap=4, queue_cap=8,
+                           t_limit_us=2_000_000, stop_on_bug=False)
+    elif family == "raft5_wide_faults":
+        # 80 fault rows: init's wide push takes the sorted slot path.
+        actor = RaftActor(RaftDeviceConfig(n=5, n_proposals=2, log_cap=8))
+        cfg = EngineConfig(n_nodes=5, outbox_cap=6, queue_cap=128,
+                           t_limit_us=1_500_000, stop_on_bug=False)
+        t = 100_000 + 10_000 * np.arange(40)
+        faults = np.concatenate([
+            np.stack([t, np.full(40, FAULT_KILL), t % 5, 0 * t], 1),
+            np.stack([t + 5_000, np.full(40, FAULT_RESTART), t % 5, 0 * t],
+                     1)]).astype(np.int32)
+    elif family == "pb":
+        actor = PBActor(PBDeviceConfig(n=3, n_writes=4))
+        cfg = EngineConfig(n_nodes=3, outbox_cap=4, queue_cap=64,
+                           t_limit_us=1_500_000, loss_rate=0.05)
+    else:
+        actor = TPCActor(TPCDeviceConfig(n=4, n_txns=4,
+                                         buggy_presumed_commit=True))
+        cfg = EngineConfig(n_nodes=4, outbox_cap=5, queue_cap=64,
+                           t_limit_us=1_500_000, loss_rate=0.1)
+    seeds = np.arange(24)
+    gather = DeviceEngine(actor, cfg)
+    sg = gather.run(gather.init(seeds, faults=faults), 4_000)
+    monkeypatch.setattr(lanes, "gathers_are_cheap", lambda: False)
+    select = DeviceEngine(actor, cfg)
+    ss = select.run(select.init(seeds, faults=faults), 4_000)
+    mism = _leaves_bitwise_equal(sg, ss)
+    assert not mism, f"select vs gather forms diverged on: {mism}"
+
+
+@pytest.mark.parametrize("default, cheap", [
+    (None, True), ("cpu", True), ("tpu", False), ("cpu_device", True)])
+def test_form_follows_the_default_device(default, cheap):
+    """The form follows the platform a program is traced for: a set
+    default device wins over the default backend, so the CPU leg of a
+    chip-vs-CPU crosscheck compiles the CPU's own program."""
+    from madsim_tpu.engine import lanes
+
+    if default == "cpu_device":
+        default = jax.devices("cpu")[0]
+    with jax.default_device(default):
+        assert lanes.gathers_are_cheap() is cheap

@@ -60,7 +60,7 @@ def test_trc001_host_callbacks_fire(bad):
     fs = _trace_rules(bad.leaky_callback, jnp.int32(1))
     assert _rules(fs) == ["TRC001", "TRC001"], fs
     assert any("pure_callback" in f.message for f in fs)
-    assert any("debug_callback" in f.message for f in fs)
+    assert any("debug_print" in f.message for f in fs)
 
 
 def test_trc001_recurses_into_scan_bodies(bad):
