@@ -813,27 +813,35 @@ class DeviceEngine:
             # frozen world pops nothing, so every queue lane, counter and
             # actor field below is left untouched through its own masked
             # dataflow — no end-of-step whole-state restore select.
-            q, ev, found, slot = pop_indexed(
-                ws.queue,
-                eligible_mask(ws.queue, ws.paused, cfg.n_nodes) & ws.active)
-            now = jnp.where(found, jnp.maximum(ws.now, ev.time), ws.now)
-            in_time = now < jnp.int32(cfg.t_limit_us)
-            ws1 = ws._replace(queue=q, now=now, steps=ws.steps + 1,
-                              qdepth=ws.qdepth
-                              - found.astype(ws.qdepth.dtype))
+            # The named scopes (madsim/pop, fault, handle, push) tag the
+            # ops' metadata for the device trace; they change no op.
+            with jax.named_scope("madsim/pop"):
+                q, ev, found, slot = pop_indexed(
+                    ws.queue,
+                    eligible_mask(ws.queue, ws.paused, cfg.n_nodes)
+                    & ws.active)
+                now = jnp.where(found, jnp.maximum(ws.now, ev.time), ws.now)
+                in_time = now < jnp.int32(cfg.t_limit_us)
+                ws1 = ws._replace(queue=q, now=now, steps=ws.steps + 1,
+                                  qdepth=ws.qdepth
+                                  - found.astype(ws.qdepth.dtype))
 
-            dst = jnp.clip(ev.dst, 0, cfg.n_nodes - 1)
-            is_fault = (ev.flags & FLAG_FAULT) != 0
-            is_timer = (ev.flags & FLAG_TIMER) != 0
-            # Generations compare modulo the packed width (queue.GEN_MASK).
-            stale = is_timer & (ev.gen != (widen(take_small(ws1.gen, dst))
-                                           & GEN_MASK))
-            dead = ~take_small(ws1.alive, dst)
-            deliver = found & in_time & ~is_fault & ~stale & ~dead
-            do_fault = found & in_time & is_fault
+                dst = jnp.clip(ev.dst, 0, cfg.n_nodes - 1)
+                is_fault = (ev.flags & FLAG_FAULT) != 0
+                is_timer = (ev.flags & FLAG_TIMER) != 0
+                # Generations compare modulo the packed width
+                # (queue.GEN_MASK).
+                stale = is_timer & (ev.gen != (widen(take_small(ws1.gen, dst))
+                                               & GEN_MASK))
+                dead = ~take_small(ws1.alive, dst)
+                deliver = found & in_time & ~is_fault & ~stale & ~dead
+                do_fault = found & in_time & is_fault
 
-            fault_ws, fault_ob = apply_fault(ws1, ev)
-            astate2, act_ob, rng2, hbug = actor.handle(cfg, ws1.astate, ev, now, ws1.rng)
+            with jax.named_scope("madsim/fault"):
+                fault_ws, fault_ob = apply_fault(ws1, ev)
+            with jax.named_scope("madsim/handle"):
+                astate2, act_ob, rng2, hbug = actor.handle(
+                    cfg, ws1.astate, ev, now, ws1.rng)
             act_ws = ws1._replace(astate=astate2, rng=rng2)
 
             ws2 = tree_select(do_fault, fault_ws,
@@ -841,9 +849,11 @@ class DeviceEngine:
             ob = tree_select(do_fault, fault_ob,
                              tree_select(deliver, act_ob, Outbox.empty(cfg)))
             src = jnp.where(do_fault, jnp.clip(ev.src, 0, cfg.n_nodes - 1), dst)
-            ws3 = push_outbox(ws2, src, ob, ws.queue, (slot, found))
+            with jax.named_scope("madsim/push"):
+                ws3 = push_outbox(ws2, src, ob, ws.queue, (slot, found))
 
-            bug_now = (deliver & hbug) | actor.invariant(cfg, ws3.astate)
+            with jax.named_scope("madsim/handle"):
+                bug_now = (deliver & hbug) | actor.invariant(cfg, ws3.astate)
             bug = ws3.bug | bug_now
             bug_time = jnp.where(bug & ~ws3.bug, now, ws3.bug_time)
             active = found & in_time & ~(cfg.stop_on_bug & bug)

@@ -1,5 +1,5 @@
-"""The sweep observatory: live telemetry, Prometheus snapshots, profiler
-hooks, and the ``watch`` CLI.
+"""The sweep observatory: live telemetry, Prometheus snapshots, the
+loop's host spans and profiler capture, and the ``watch`` CLI.
 
 The sweep loop (parallel/sweep.py) learns a handful of scalars per
 superstep anyway — occupancy, bug flag, chunk count, the coverage
@@ -29,14 +29,18 @@ still 0), and — when the engine runs metrics — ``coverage_distinct`` /
 """
 from __future__ import annotations
 
+import collections
+import contextlib
+import functools
 import json
 import os
 import sys
 import tempfile
+import time
 from typing import Any, Callable, List, Optional, Tuple
 
-# Every duration in the telemetry schema is MONOTONIC seconds (the
-# sweep's ``_clk`` = time.perf_counter, docs/perf.md "Telemetry units"),
+# Every duration in the telemetry schema is MONOTONIC seconds
+# (``LoopTracer.clock`` = time.perf_counter, docs/perf.md "Telemetry units"),
 # never a wall-clock date: two runs of one seed must render identical
 # *virtual* timelines, and host clocks must never leak into them.
 _SCHEMA = "madsim.sweep.telemetry/1"
@@ -212,8 +216,66 @@ def write_prometheus_snapshot(records: List[dict], path: str) -> None:
 
 
 # ---------------------------------------------------------------------------
-# Profiler capture window
+# Loop spans and the profiler capture window
 # ---------------------------------------------------------------------------
+
+def _annotation(name: str):
+    import jax
+
+    # detlint: allow[DET007] reason=the one span site: a TraceMe host event, recorded only while some capture runs
+    return jax.profiler.TraceAnnotation(name)
+
+
+def spanned(name: str):
+    """Decorator: run every call of the function inside the host span
+    ``name``, the root that a :class:`LoopTracer`'s spans nest in."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with _annotation(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
+
+
+class LoopTracer:
+    """Named host spans of one orchestration call, on the profiler's clock.
+
+    ``span(name, stat)`` always enters a ``jax.profiler.TraceAnnotation``:
+    a TraceMe event, nearly free while no capture runs, and a host span
+    on the device trace's clock while one does, whoever started it
+    (``sweep(profile_dir=)``, a benchmark, a user's ``jax.profiler``
+    capture). ``entered[name]`` counts the spans entered (the loop's
+    dispatches and reads), and the block's duration adds to
+    ``stats[stat]``, the ``loop_stats`` seconds key the span feeds.
+    Spans nest on the calling thread, so each span of one call lies
+    inside that call's :func:`spanned` root. Host-side observation only:
+    nothing here feeds a simulation decision.
+    """
+
+    def __init__(self, stats: Tuple[str, ...] = ()):
+        self.stats = {k: 0.0 for k in stats}
+        self.entered: collections.Counter = collections.Counter()
+
+    @staticmethod
+    def clock() -> float:
+        """Monotonic host seconds (``time.perf_counter``)."""
+        return time.perf_counter()  # detlint: allow[DET001]
+
+    @contextlib.contextmanager
+    def span(self, name: str, stat: str):
+        self.entered[name] += 1
+        with _annotation(name):
+            t0 = self.clock()
+            try:
+                yield
+            finally:
+                self.stats[stat] += self.clock() - t0
+
+    def seconds(self) -> dict:
+        """The accumulated ``stat`` seconds, rounded for ``loop_stats``."""
+        return {k: round(v, 6) for k, v in self.stats.items()}
+
 
 class ProfilerWindow:
     """Wrap a window of sweep dispatches in ``jax.profiler`` capture.
@@ -224,9 +286,10 @@ class ProfilerWindow:
     every in-window dispatch has completed inside the capture), or at
     loop end. The device timeline lands under ``trace_dir`` — beside the
     *virtual-time* timelines of obs/timeline.py, this is the sanctioned
-    wall-clock view of the same sweep. With ``trace_dir=None`` every
-    method is a no-op. Capture failures (profiler backends vary) are
-    recorded on ``self.error`` and never propagate into the sweep.
+    wall-clock view of the same sweep; the loop's :class:`LoopTracer`
+    spans name its phases there. With ``trace_dir=None`` every method is
+    a no-op. Capture failures (profiler backends vary) are recorded on
+    ``self.error`` and never propagate into the sweep.
     """
 
     def __init__(self, trace_dir: Optional[str],
@@ -258,21 +321,6 @@ class ProfilerWindow:
                 self.error = f"{type(exc).__name__}: {exc}"
                 self._done = True
         self._dispatches += 1
-
-    def annotate(self, label: str):
-        """Context manager naming the enclosed dispatch on the captured
-        timeline; a null context while no capture is active."""
-        if self._active:
-            try:
-                import jax
-
-                # detlint: allow[DET007] reason=names the dispatch on the sanctioned capture timeline
-                return jax.profiler.TraceAnnotation(label)
-            except Exception:  # pragma: no cover — backend-specific
-                pass
-        import contextlib
-
-        return contextlib.nullcontext()
 
     def after_read(self) -> None:
         """One blocking scalar read happened: device work up to the read

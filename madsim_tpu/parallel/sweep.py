@@ -41,6 +41,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax import shard_map
 
 from ..engine.core import DeviceEngine, EngineConfig, WorldState
+from ..obs import observatory as _obsy
 from .mesh import (
     scalar_spec,
     seed_mesh,
@@ -56,6 +57,12 @@ from .mesh import (
 # blocking read in this module as a finding. Semantics: jax.device_get of
 # an arbitrary pytree.
 _fetch = jax.device_get  # detlint: allow[DET008] reason=the ONE sanctioned pull hook; runtime tests count calls through this exact name
+
+# The ``loop_stats`` seconds keys, each fed by one phase's host spans
+# (docs/observability.md "Loop spans and profiler capture").
+_LOOP_SECONDS = ("prepare_s", "init_s", "upload_s", "dispatch_s",
+                 "device_wait_s", "host_decision_s", "retire_wait_s",
+                 "assemble_s")
 
 
 def _cov_reducers(mesh: Mesh):
@@ -643,6 +650,7 @@ class SweepResult:
         return banner
 
 
+@_obsy.spanned("madsim:sweep")
 def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = None,
           mesh: Optional[Mesh] = None, chunk_steps: int = 512,
           max_steps: int = 1_000_000, stop_on_first_bug: bool = False,
@@ -800,7 +808,8 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
     ``jax.profiler`` trace capture, so a device timeline lands in
     ``profile_dir`` next to the virtual-time timelines of
     obs/timeline.py. Purely host-side observation: trajectories and the
-    dispatch schedule are unchanged.
+    dispatch schedule are unchanged. The loop's host spans
+    (``madsim:*``) show in any ``jax.profiler`` capture.
 
     ``coverage_buckets``: bucket count of the behavior-coverage ledger
     (obs/coverage.py; default ``DEFAULT_BUCKETS`` when the engine runs
@@ -864,8 +873,12 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
     accounting: it shifts ids only, never a corpus decision or a child
     byte.
     """
+    import hashlib
+    import os
+
     from ..engine import checkpoint as ckpt
 
+    tr = _obsy.LoopTracer(_LOOP_SECONDS)
     eng = engine if engine is not None else DeviceEngine(actor, cfg)
     mesh = mesh if mesh is not None else seed_mesh()
     n_dev = mesh.devices.size
@@ -944,119 +957,112 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
         raise ValueError("search_lin_base must be >= 0")
     lineage_on = bool(search_on and getattr(search, "lineage", False))
 
-    # Batch width: a multiple of the mesh. Plain sweeps hold every seed at
-    # once; recycled sweeps hold batch_worlds slots and stream the rest.
-    full_w = n + ((-n) % n_dev)
-    if recycle and batch_worlds is not None:
-        w0 = min(max(1, int(batch_worlds)), max(n, 1))
-        w0 += (-w0) % n_dev
-        w0 = min(w0, full_w)
-    else:
-        w0 = full_w
-    # Pad the seed-id space to the batch width (padded worlds are real
-    # simulations of dummy seeds; their results are sliced off below).
-    n_ids = max(n, w0)
-    seeds_p = (np.concatenate([seeds, seeds[:1].repeat(n_ids - n)])
-               if n_ids > n else seeds)
-
-    faults_p = faults
-    per_world_faults = False
-    if faults is not None:
-        faults_p = np.asarray(faults, np.int32)
-        if faults_p.ndim == 2:
-            if faults_p.shape[-1] != 4:
-                raise ValueError(
-                    f"shared fault schedule must be (F, 4) rows of "
-                    f"[time_us, op, a, b]; got shape {faults_p.shape}")
-        elif faults_p.ndim == 3:
-            # Validate the leading dim EXPLICITLY against len(seeds):
-            # without this, a mismatched (m, F, 4) would silently gather
-            # via ``faults_p[ids]`` below — wrong-world schedules (m > n)
-            # or an IndexError deep in a refill (m < n) instead of a
-            # boundary error naming both dims.
-            if faults_p.shape[-1] != 4:
-                raise ValueError(
-                    f"per-world fault schedules must be (n_seeds, F, 4) "
-                    f"rows of [time_us, op, a, b]; got shape "
-                    f"{faults_p.shape}")
-            if faults_p.shape[0] != n:
-                raise ValueError(
-                    f"per-world fault schedules carry one (F, 4) block "
-                    f"per seed: got leading dim {faults_p.shape[0]} but "
-                    f"len(seeds)={n}")
-            per_world_faults = True
-            if n_ids > n:
-                faults_p = np.concatenate(
-                    [faults_p, faults_p[:1].repeat(n_ids - n, axis=0)],
-                    axis=0)
+    with tr.span("madsim:prepare", "prepare_s"):
+        # Batch width: a multiple of the mesh. Plain sweeps hold every seed at
+        # once; recycled sweeps hold batch_worlds slots and stream the rest.
+        full_w = n + ((-n) % n_dev)
+        if recycle and batch_worlds is not None:
+            w0 = min(max(1, int(batch_worlds)), max(n, 1))
+            w0 += (-w0) % n_dev
+            w0 = min(w0, full_w)
         else:
-            raise ValueError(
-                f"faults must be (F, 4) or (n_seeds, F, 4); got "
-                f"{faults_p.ndim}-D shape {faults_p.shape}")
+            w0 = full_w
+        # Pad the seed-id space to the batch width (padded worlds are real
+        # simulations of dummy seeds; their results are sliced off below).
+        n_ids = max(n, w0)
+        seeds_p = (np.concatenate([seeds, seeds[:1].repeat(n_ids - n)])
+                   if n_ids > n else seeds)
 
-    def batch_faults(ids: np.ndarray):
-        """Fault rows for the worlds holding the given seed ids."""
-        if faults_p is None:
-            return None
-        return faults_p[ids] if per_world_faults else faults_p
+        faults_p = faults
+        per_world_faults = False
+        if faults is not None:
+            faults_p = np.asarray(faults, np.int32)
+            if faults_p.ndim == 2:
+                if faults_p.shape[-1] != 4:
+                    raise ValueError(
+                        f"shared fault schedule must be (F, 4) rows of "
+                        f"[time_us, op, a, b]; got shape {faults_p.shape}")
+            elif faults_p.ndim == 3:
+                # Validate the leading dim EXPLICITLY against len(seeds):
+                # without this, a mismatched (m, F, 4) would silently gather
+                # via ``faults_p[ids]`` below — wrong-world schedules (m > n)
+                # or an IndexError deep in a refill (m < n) instead of a
+                # boundary error naming both dims.
+                if faults_p.shape[-1] != 4:
+                    raise ValueError(
+                        f"per-world fault schedules must be (n_seeds, F, 4) "
+                        f"rows of [time_us, op, a, b]; got shape "
+                        f"{faults_p.shape}")
+                if faults_p.shape[0] != n:
+                    raise ValueError(
+                        f"per-world fault schedules carry one (F, 4) block "
+                        f"per seed: got leading dim {faults_p.shape[0]} but "
+                        f"len(seeds)={n}")
+                per_world_faults = True
+                if n_ids > n:
+                    faults_p = np.concatenate(
+                        [faults_p, faults_p[:1].repeat(n_ids - n, axis=0)],
+                        axis=0)
+            else:
+                raise ValueError(
+                    f"faults must be (F, 4) or (n_seeds, F, 4); got "
+                    f"{faults_p.ndim}-D shape {faults_p.shape}")
 
-    import hashlib
-    import os
-    from time import perf_counter
+        def batch_faults(ids: np.ndarray):
+            """Fault rows for the worlds holding the given seed ids."""
+            if faults_p is None:
+                return None
+            return faults_p[ids] if per_world_faults else faults_p
 
-    def _clk() -> float:
-        # Wall-clock telemetry of the orchestration loop itself (host
-        # side); never feeds a simulation decision.
-        return perf_counter()  # detlint: allow[DET001]
-
-    # World identity travels with the checkpoint: resuming under different
-    # seeds OR fault schedules would silently attribute results (repro
-    # banners!) to inputs that never produced them.
-    faults_key = (np.ascontiguousarray(faults_p).tobytes()
-                  if faults_p is not None else b"none")
-    seeds_meta = {
-        "seeds_sha256": hashlib.sha256(seeds_p.tobytes()).hexdigest(),
-        "faults_sha256": hashlib.sha256(faults_key).hexdigest(),
-    }
+        # World identity travels with the checkpoint: resuming under different
+        # seeds OR fault schedules would silently attribute results (repro
+        # banners!) to inputs that never produced them.
+        faults_key = (np.ascontiguousarray(faults_p).tobytes()
+                      if faults_p is not None else b"none")
+        seeds_meta = {
+            "seeds_sha256": hashlib.sha256(seeds_p.tobytes()).hexdigest(),
+            "faults_sha256": hashlib.sha256(faults_key).hexdigest(),
+        }
 
     resumed = False
     resume_aux: Dict[str, np.ndarray] = {}
-    if resume and checkpoint_path and os.path.exists(checkpoint_path):
-        state, resume_aux = ckpt.load(eng, checkpoint_path,
-                                      expect_extra=seeds_meta, with_aux=True)
-        w_file = int(np.asarray(state.now).shape[0])
-        if recycle:
-            # Recycled checkpoints carry the sweep-level aux (cursor,
-            # slot→seed index, retired observations) — without it the
-            # file is a plain full-batch snapshot this mode cannot
-            # re-attribute.
-            if "cursor" not in resume_aux:
+    with tr.span("madsim:init", "init_s"):
+        if resume and checkpoint_path and os.path.exists(checkpoint_path):
+            state, resume_aux = ckpt.load(
+                eng, checkpoint_path, expect_extra=seeds_meta, with_aux=True)
+            w_file = int(np.asarray(state.now).shape[0])
+            if recycle:
+                # Recycled checkpoints carry the sweep-level aux (cursor,
+                # slot→seed index, retired observations) — without it the
+                # file is a plain full-batch snapshot this mode cannot
+                # re-attribute.
+                if "cursor" not in resume_aux:
+                    raise ckpt.CheckpointError(
+                        f"checkpoint {checkpoint_path!r} was written by a "
+                        "non-recycled sweep (no slot->seed aux): resume it "
+                        "with recycle=False, or delete it to start the "
+                        "recycled hunt fresh")
+                if w_file != w0:
+                    raise ValueError(
+                        f"cannot resume recycled sweep: checkpoint holds "
+                        f"{w_file} world slots but batch_worlds implies {w0} "
+                        "— a shrunk-compacted or differently-batched state "
+                        "cannot resume into the full-shape contract; rerun "
+                        "with the original batch_worlds")
+            elif "cursor" in resume_aux:
                 raise ckpt.CheckpointError(
                     f"checkpoint {checkpoint_path!r} was written by a "
-                    "non-recycled sweep (no slot->seed aux): resume it "
-                    "with recycle=False, or delete it to start the "
-                    "recycled hunt fresh")
-            if w_file != w0:
-                raise ValueError(
-                    f"cannot resume recycled sweep: checkpoint holds "
-                    f"{w_file} world slots but batch_worlds implies {w0} "
-                    "— a shrunk-compacted or differently-batched state "
-                    "cannot resume into the full-shape contract; rerun "
-                    "with the original batch_worlds")
-        elif "cursor" in resume_aux:
-            raise ckpt.CheckpointError(
-                f"checkpoint {checkpoint_path!r} was written by a "
-                "recycled sweep: pass recycle=True (and the original "
-                "batch_worlds) to resume it")
-        elif w_file != seeds_p.shape[0]:
-            raise ckpt.CheckpointError(
-                f"checkpoint holds {w_file} worlds, "
-                f"sweep expects {seeds_p.shape[0]} (seeds + mesh padding)")
-        state = shard_worlds(state, mesh)
-        resumed = True
-    else:
-        state = shard_worlds(
-            eng.init(seeds_p[:w0], faults=batch_faults(np.arange(w0))), mesh)
+                    "recycled sweep: pass recycle=True (and the original "
+                    "batch_worlds) to resume it")
+            elif w_file != seeds_p.shape[0]:
+                raise ckpt.CheckpointError(
+                    f"checkpoint holds {w_file} worlds, "
+                    f"sweep expects {seeds_p.shape[0]} (seeds + mesh padding)")
+            state = shard_worlds(state, mesh)
+            resumed = True
+        else:
+            state = shard_worlds(eng.init(
+                seeds_p[:w0], faults=batch_faults(np.arange(w0))), mesh)
 
     writer = (_AsyncCheckpointer(eng, checkpoint_path, seeds_meta)
               if checkpoint_path else None)
@@ -1077,233 +1083,231 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
     # re-derives deterministically on resume — so chunk-count identity
     # remains a sound skip condition for the final submit.
     submitted_chunks = -1
-    w_cur = w0                         # current batch width (slot count)
-    cursor = w0                        # next seed id the stream admits
-    # Slot→seed-id map, DEVICE-resident: compaction permutes it with the
-    # state in the same on-device program, so the host never needs the
-    # permutation (or state.active) to keep attribution straight. -1
-    # marks a dead slot (retired world still riding in the batch).
-    idx = shard_worlds(jnp.arange(w_cur, dtype=jnp.int32), mesh)
-    reordered = False                  # batch rows still == seed order?
-    retired: Dict[str, list] = {}      # field → retired observation batches
-    retired_rows: List[np.ndarray] = []
-    # -- guided-search state (search/, docs/search.md) --------------------
-    # slot_sched: the (W, F, 4) schedule each slot is CURRENTLY running,
-    # device-resident and permuted/refilled in lockstep with the state —
-    # the attribution that makes generated children replayable. corpus:
-    # the mesh-replicated parent pool (search/corpus.py).
-    slot_sched = corpus = None
-    retired_sched: List[np.ndarray] = []
-    # -- lineage lanes + operator outcome table (obs/lineage.py) ----------
-    # slot_lin: per-slot provenance (parent entry ids, applied-operator
-    # bitmask, ancestry depth), permuted/split/refilled in lockstep with
-    # slot_sched; op_tab: the per-operator produced/novel/survived/bug
-    # counters, accumulated inside the searcher program.
-    slot_lin = op_tab = None
-    retired_lin: List[tuple] = []
-    search_host = {"corpus_size": 1, "inserted": 0, "gen": 0,
-                   "refill_novel": 0, "refill_inserted": 0}
-    if search_on:
-        from ..search.corpus import CorpusState, corpus_init
-        from ..search.generate import searcher as _searcher
-        from ..triage.shrink import normalize as _normalize_sched
-
-        f_rows = int(faults_p.shape[-2])
-        base0 = (faults_p[:w0] if per_world_faults
-                 else np.broadcast_to(faults_p, (w0,) + faults_p.shape))
-        slot_sched = shard_worlds(
-            jnp.asarray(np.ascontiguousarray(base0), jnp.int32), mesh)
-        if lineage_on:
-            from ..obs.lineage import lanes_origin, table_zeros
-
-            # The initial batch runs the template itself: generation-0
-            # lanes (no parents, no operators, depth 0).
-            slot_lin = shard_worlds(lanes_origin(w0), mesh)
-            op_tab = jax.device_put(table_zeros(),
-                                    NamedSharding(mesh, scalar_spec()))
-        if search_corpus is not None:
-            # Exchange seeding (fleet/exchange.py): start from a merged
-            # host corpus instead of the template-only init. The per-
-            # sweep gen/inserted counters still start at zero — they
-            # count THIS sweep's refills/inserts.
-            sc_sched = np.asarray(search_corpus.sched, np.int32)
-            k = int(search.corpus)
-            if sc_sched.shape != (k, f_rows, 4):
-                raise ValueError(
-                    f"search_corpus.sched must be (K, F, 4) = "
-                    f"({k}, {f_rows}, 4) for SearchConfig.corpus={k} and "
-                    f"the {f_rows}-row template; got {sc_sched.shape}")
-            for name in ("sig", "score", "filled", "entry", "depth"):
-                shp = np.asarray(getattr(search_corpus, name)).shape
-                if shp != (k,):
-                    raise ValueError(
-                        f"search_corpus.{name} must be ({k},) for "
-                        f"SearchConfig.corpus={k}; got {shp}")
-            # gen starts at the epoch stream offset (fleet/exchange.py):
-            # generation is the third key of the mutation lanes, so the
-            # shift moves this sweep onto a fresh splitmix64 stream
-            # family instead of redrawing the seed corpus's parents'.
-            corpus = jax.device_put(CorpusState(
-                sched=jnp.asarray(sc_sched),
-                sig=jnp.asarray(np.asarray(search_corpus.sig, np.uint32)),
-                score=jnp.asarray(np.asarray(search_corpus.score,
-                                             np.int32)),
-                filled=jnp.asarray(np.asarray(search_corpus.filled, bool)),
-                gen=jnp.int32(search_gen0), inserted=jnp.int32(0),
-                entry=jnp.asarray(np.asarray(search_corpus.entry,
-                                             np.int32)),
-                depth=jnp.asarray(np.asarray(search_corpus.depth,
-                                             np.int32)),
-            ), NamedSharding(mesh, scalar_spec()))
-        else:
-            # Corpus seeded with the (normalized) template: parents
-            # always exist, so generation-1 children mutate the original
-            # schedule.
-            template = _normalize_sched(
-                faults_p[0] if per_world_faults else faults_p)
-            c0 = corpus_init(int(search.corpus), template)
-            if search_gen0:
-                c0 = c0._replace(gen=jnp.int32(search_gen0))
-            corpus = jax.device_put(
-                c0, NamedSharding(mesh, scalar_spec()))
-    if resumed and recycle:
-        # Rehydrate the sweep-level bookkeeping the checkpoint carried:
-        # the slot→seed index (device-resident again), the refill
-        # cursor, and the observations of every world retired before the
-        # snapshot. With these restored, the continuation re-attributes
-        # recycled slots exactly as the unbroken run would have.
-        cursor = int(np.asarray(resume_aux["cursor"]))
-        idx = shard_worlds(
-            jnp.asarray(np.asarray(resume_aux["idx"], np.int32)), mesh)
-        reordered = True
-        if "ret_rows" in resume_aux:
-            retired_rows.append(np.asarray(resume_aux["ret_rows"]))
-            for key in resume_aux:
-                if key.startswith("ret_") and key != "ret_rows":
-                    retired[key[4:]] = [np.asarray(resume_aux[key])]
-        if search_on != ("srch_sched" in resume_aux):
-            raise ckpt.CheckpointError(
-                f"checkpoint {checkpoint_path!r} was written by a "
-                f"{'guided' if 'srch_sched' in resume_aux else 'plain'} "
-                f"sweep but this resume is "
-                f"{'guided (search=...)' if search_on else 'plain'}: "
-                "the per-slot schedules and search corpus cannot be "
-                "reconciled — resume with the original search setting")
+    with tr.span("madsim:upload", "upload_s"):
+        w_cur = w0                         # current batch width (slot count)
+        cursor = w0                        # next seed id the stream admits
+        # Slot→seed-id map, DEVICE-resident: compaction permutes it with the
+        # state in the same on-device program, so the host never needs the
+        # permutation (or state.active) to keep attribution straight. -1
+        # marks a dead slot (retired world still riding in the batch).
+        idx = shard_worlds(jnp.arange(w_cur, dtype=jnp.int32), mesh)
+        reordered = False                  # batch rows still == seed order?
+        retired: Dict[str, list] = {}      # field → retired obs batches
+        retired_rows: List[np.ndarray] = []
+        # -- guided-search state (search/, docs/search.md) --------------------
+        # slot_sched: the (W, F, 4) schedule each slot is CURRENTLY running,
+        # device-resident and permuted/refilled in lockstep with the state —
+        # the attribution that makes generated children replayable. corpus:
+        # the mesh-replicated parent pool (search/corpus.py).
+        slot_sched = corpus = None
+        retired_sched: List[np.ndarray] = []
+        # -- lineage lanes + operator outcome table (obs/lineage.py) ----------
+        # slot_lin: per-slot provenance (parent entry ids, applied-operator
+        # bitmask, ancestry depth), permuted/split/refilled in lockstep with
+        # slot_sched; op_tab: the per-operator produced/novel/survived/bug
+        # counters, accumulated inside the searcher program.
+        slot_lin = op_tab = None
+        retired_lin: List[tuple] = []
+        search_host = {"corpus_size": 1, "inserted": 0, "gen": 0,
+                       "refill_novel": 0, "refill_inserted": 0}
         if search_on:
-            # Restore the search state bit-exactly: the per-slot
-            # schedules, the parent corpus (incl. its generation and
-            # insert counters), and the retired-schedule attribution.
-            from ..search.corpus import CorpusState
+            from ..search.corpus import CorpusState, corpus_init
+            from ..search.generate import searcher as _searcher
+            from ..triage.shrink import normalize as _normalize_sched
 
-            if lineage_on != ("srch_lin_p1" in resume_aux):
-                raise ckpt.CheckpointError(
-                    f"checkpoint {checkpoint_path!r} was written with "
-                    f"lineage "
-                    f"{'on' if 'srch_lin_p1' in resume_aux else 'off'} "
-                    f"but this resume runs SearchConfig(lineage="
-                    f"{lineage_on}): the provenance lanes cannot be "
-                    "reconciled — resume with the original lineage "
-                    "setting")
-            slot_sched = shard_worlds(jnp.asarray(
-                np.asarray(resume_aux["srch_sched"], np.int32)), mesh)
-            corpus = jax.device_put(CorpusState(
-                sched=jnp.asarray(np.asarray(resume_aux["srch_c_sched"],
-                                             np.int32)),
-                sig=jnp.asarray(np.asarray(resume_aux["srch_c_sig"],
-                                           np.uint32)),
-                score=jnp.asarray(np.asarray(resume_aux["srch_c_score"],
-                                             np.int32)),
-                filled=jnp.asarray(np.asarray(resume_aux["srch_c_filled"],
-                                              bool)),
-                gen=jnp.asarray(np.asarray(resume_aux["srch_c_gen"],
-                                           np.int32).reshape(())),
-                inserted=jnp.asarray(np.asarray(
-                    resume_aux["srch_c_inserted"], np.int32).reshape(())),
-                entry=jnp.asarray(np.asarray(resume_aux["srch_c_entry"],
-                                             np.int32)),
-                depth=jnp.asarray(np.asarray(resume_aux["srch_c_depth"],
-                                             np.int32)),
-            ), NamedSharding(mesh, scalar_spec()))
-            if "srch_ret" in resume_aux:
-                retired_sched.append(
-                    np.asarray(resume_aux["srch_ret"], np.int32))
+            f_rows = int(faults_p.shape[-2])
+            base0 = (faults_p[:w0] if per_world_faults
+                     else np.broadcast_to(faults_p, (w0,) + faults_p.shape))
+            slot_sched = shard_worlds(
+                jnp.asarray(np.ascontiguousarray(base0), jnp.int32), mesh)
             if lineage_on:
-                # Lineage lanes + operator table ride the same aux
-                # channel — a resumed hunt's ancestry and outcome
-                # accounting equal an unbroken run's bit for bit.
-                from ..obs.lineage import LineageLanes, OperatorTable
+                from ..obs.lineage import lanes_origin, table_zeros
 
-                slot_lin = shard_worlds(LineageLanes(
-                    p1=jnp.asarray(np.asarray(resume_aux["srch_lin_p1"],
-                                              np.int32)),
-                    p2=jnp.asarray(np.asarray(resume_aux["srch_lin_p2"],
-                                              np.int32)),
-                    ops=jnp.asarray(np.asarray(resume_aux["srch_lin_ops"],
-                                               np.int8)),
-                    depth=jnp.asarray(np.asarray(
-                        resume_aux["srch_lin_depth"], np.int32)),
-                ), mesh)
-                op_tab = jax.device_put(OperatorTable(
-                    produced=jnp.asarray(np.asarray(
-                        resume_aux["srch_op_produced"], np.int32)),
-                    novel=jnp.asarray(np.asarray(
-                        resume_aux["srch_op_novel"], np.int32)),
-                    survived=jnp.asarray(np.asarray(
-                        resume_aux["srch_op_survived"], np.int32)),
+                # The initial batch runs the template itself: generation-0
+                # lanes (no parents, no operators, depth 0).
+                slot_lin = shard_worlds(lanes_origin(w0), mesh)
+                op_tab = jax.device_put(table_zeros(),
+                                        NamedSharding(mesh, scalar_spec()))
+            if search_corpus is not None:
+                # Exchange seeding (fleet/exchange.py): start from a merged
+                # host corpus instead of the template-only init. The per-
+                # sweep gen/inserted counters still start at zero — they
+                # count THIS sweep's refills/inserts.
+                sc_sched = np.asarray(search_corpus.sched, np.int32)
+                k = int(search.corpus)
+                if sc_sched.shape != (k, f_rows, 4):
+                    raise ValueError(
+                        f"search_corpus.sched must be (K, F, 4) = "
+                        f"({k}, {f_rows}, 4) for SearchConfig.corpus={k} and "
+                        f"the {f_rows}-row template; got {sc_sched.shape}")
+                for name in ("sig", "score", "filled", "entry", "depth"):
+                    shp = np.asarray(getattr(search_corpus, name)).shape
+                    if shp != (k,):
+                        raise ValueError(
+                            f"search_corpus.{name} must be ({k},) for "
+                            f"SearchConfig.corpus={k}; got {shp}")
+                # gen starts at the epoch stream offset (fleet/exchange.py):
+                # generation is the third key of the mutation lanes, so the
+                # shift moves this sweep onto a fresh splitmix64 stream
+                # family instead of redrawing the seed corpus's parents'.
+                corpus = jax.device_put(CorpusState(
+                    sched=jnp.asarray(sc_sched),
+                    sig=jnp.asarray(np.asarray(search_corpus.sig, np.uint32)),
+                    score=jnp.asarray(np.asarray(search_corpus.score,
+                                                 np.int32)),
+                    filled=jnp.asarray(np.asarray(search_corpus.filled, bool)),
+                    gen=jnp.int32(search_gen0), inserted=jnp.int32(0),
+                    entry=jnp.asarray(np.asarray(search_corpus.entry,
+                                                 np.int32)),
+                    depth=jnp.asarray(np.asarray(search_corpus.depth,
+                                                 np.int32)),
                 ), NamedSharding(mesh, scalar_spec()))
-                if "srch_ret_lin_p1" in resume_aux:
-                    retired_lin.append(tuple(
-                        np.asarray(resume_aux[f"srch_ret_lin_{k}"])
-                        for k in ("p1", "p2", "ops", "depth")))
-    n_active_hist: List[int] = []
-    n_active_chunk: List[int] = []     # chunk index each entry measured at
-    issued_slot_steps = 0              # sum over chunks of width*chunk_steps
-    live_world_steps = 0               # steps that advanced a live world
-    perf = {"device_wait_s": 0.0, "host_decision_s": 0.0, "dispatch_s": 0.0,
-            "retire_wait_s": 0.0, "scalar_fetches": 0, "retire_fetches": 0,
-            "dispatches": 0, "dispatch_depth": 0}
-    t_loop0 = _clk()
+            else:
+                # Corpus seeded with the (normalized) template: parents
+                # always exist, so generation-1 children mutate the original
+                # schedule.
+                template = _normalize_sched(
+                    faults_p[0] if per_world_faults else faults_p)
+                c0 = corpus_init(int(search.corpus), template)
+                if search_gen0:
+                    c0 = c0._replace(gen=jnp.int32(search_gen0))
+                corpus = jax.device_put(
+                    c0, NamedSharding(mesh, scalar_spec()))
+        if resumed and recycle:
+            # Rehydrate the sweep-level bookkeeping the checkpoint carried:
+            # the slot→seed index (device-resident again), the refill
+            # cursor, and the observations of every world retired before the
+            # snapshot. With these restored, the continuation re-attributes
+            # recycled slots exactly as the unbroken run would have.
+            cursor = int(np.asarray(resume_aux["cursor"]))
+            idx = shard_worlds(
+                jnp.asarray(np.asarray(resume_aux["idx"], np.int32)), mesh)
+            reordered = True
+            if "ret_rows" in resume_aux:
+                retired_rows.append(np.asarray(resume_aux["ret_rows"]))
+                for key in resume_aux:
+                    if key.startswith("ret_") and key != "ret_rows":
+                        retired[key[4:]] = [np.asarray(resume_aux[key])]
+            if search_on != ("srch_sched" in resume_aux):
+                raise ckpt.CheckpointError(
+                    f"checkpoint {checkpoint_path!r} was written by a "
+                    f"{'guided' if 'srch_sched' in resume_aux else 'plain'} "
+                    f"sweep but this resume is "
+                    f"{'guided (search=...)' if search_on else 'plain'}: "
+                    "the per-slot schedules and search corpus cannot be "
+                    "reconciled — resume with the original search setting")
+            if search_on:
+                # Restore the search state bit-exactly: the per-slot
+                # schedules, the parent corpus (incl. its generation and
+                # insert counters), and the retired-schedule attribution.
+                from ..search.corpus import CorpusState
 
-    # -- observatory hooks (docs/observability.md) ------------------------
-    # Telemetry emitter + profiler window are host-side observation only:
-    # every record is built from scalars the loop already fetched, so the
-    # sync discipline (one _fetch per superstep) is unchanged.
-    from ..obs import observatory as _obsy
+                if lineage_on != ("srch_lin_p1" in resume_aux):
+                    raise ckpt.CheckpointError(
+                        f"checkpoint {checkpoint_path!r} was written with "
+                        f"lineage "
+                        f"{'on' if 'srch_lin_p1' in resume_aux else 'off'} "
+                        f"but this resume runs SearchConfig(lineage="
+                        f"{lineage_on}): the provenance lanes cannot be "
+                        "reconciled — resume with the original lineage "
+                        "setting")
+                slot_sched = shard_worlds(jnp.asarray(
+                    np.asarray(resume_aux["srch_sched"], np.int32)), mesh)
+                corpus = jax.device_put(CorpusState(
+                    sched=jnp.asarray(np.asarray(resume_aux["srch_c_sched"],
+                                                 np.int32)),
+                    sig=jnp.asarray(np.asarray(resume_aux["srch_c_sig"],
+                                               np.uint32)),
+                    score=jnp.asarray(np.asarray(resume_aux["srch_c_score"],
+                                                 np.int32)),
+                    filled=jnp.asarray(np.asarray(resume_aux["srch_c_filled"],
+                                                  bool)),
+                    gen=jnp.asarray(np.asarray(resume_aux["srch_c_gen"],
+                                               np.int32).reshape(())),
+                    inserted=jnp.asarray(np.asarray(
+                        resume_aux["srch_c_inserted"], np.int32).reshape(())),
+                    entry=jnp.asarray(np.asarray(resume_aux["srch_c_entry"],
+                                                 np.int32)),
+                    depth=jnp.asarray(np.asarray(resume_aux["srch_c_depth"],
+                                                 np.int32)),
+                ), NamedSharding(mesh, scalar_spec()))
+                if "srch_ret" in resume_aux:
+                    retired_sched.append(
+                        np.asarray(resume_aux["srch_ret"], np.int32))
+                if lineage_on:
+                    # Lineage lanes + operator table ride the same aux
+                    # channel — a resumed hunt's ancestry and outcome
+                    # accounting equal an unbroken run's bit for bit.
+                    from ..obs.lineage import LineageLanes, OperatorTable
 
-    emit_telemetry, close_telemetry = _obsy.make_observer(observe)
-    prof = _obsy.ProfilerWindow(profile_dir, profile_window)
-    novelty_hist: List[int] = []       # cumulative distinct, per chunk
-    cov_hits = cov_first = n_real_dev = None
-    if cov_on:
-        cov_hits, cov_first = jax.device_put(
-            ledger_zeros(cov_k), NamedSharding(mesh, scalar_spec()))
-        n_real_dev = jnp.int32(n)
-        if resumed and "cov_hits" in resume_aux:
-            # Recycled checkpoints persist the ledger itself (retired-
-            # and-refilled slots no longer carry their histograms, so a
-            # pre-pass could not rebuild it): restore and continue.
-            # Folds trigger on active FALLING within a chunk, so worlds
-            # already inactive in the snapshot never re-fold.
+                    slot_lin = shard_worlds(LineageLanes(
+                        p1=jnp.asarray(np.asarray(resume_aux["srch_lin_p1"],
+                                                  np.int32)),
+                        p2=jnp.asarray(np.asarray(resume_aux["srch_lin_p2"],
+                                                  np.int32)),
+                        ops=jnp.asarray(np.asarray(resume_aux["srch_lin_ops"],
+                                                   np.int8)),
+                        depth=jnp.asarray(np.asarray(
+                            resume_aux["srch_lin_depth"], np.int32)),
+                    ), mesh)
+                    op_tab = jax.device_put(OperatorTable(
+                        produced=jnp.asarray(np.asarray(
+                            resume_aux["srch_op_produced"], np.int32)),
+                        novel=jnp.asarray(np.asarray(
+                            resume_aux["srch_op_novel"], np.int32)),
+                        survived=jnp.asarray(np.asarray(
+                            resume_aux["srch_op_survived"], np.int32)),
+                    ), NamedSharding(mesh, scalar_spec()))
+                    if "srch_ret_lin_p1" in resume_aux:
+                        retired_lin.append(tuple(
+                            np.asarray(resume_aux[f"srch_ret_lin_{k}"])
+                            for k in ("p1", "p2", "ops", "depth")))
+        n_active_hist: List[int] = []
+        n_active_chunk: List[int] = []     # chunk index each entry measured at
+        issued_slot_steps = 0              # sum of width*chunk_steps
+        live_world_steps = 0               # steps that advanced a live world
+        # Counts the spans do not keep; the rest are tr.entered/tr.stats.
+        perf = {"retire_fetches": 0, "dispatch_depth": 0}
+        t_loop0 = tr.clock()
+
+        # -- observatory hooks (docs/observability.md) ------------------------
+        # Telemetry emitter + profiler window are host-side observation only:
+        # every record is built from scalars the loop already fetched, so the
+        # sync discipline (one _fetch per superstep) is unchanged.
+        emit_telemetry, close_telemetry = _obsy.make_observer(observe)
+        prof = _obsy.ProfilerWindow(profile_dir, profile_window)
+        novelty_hist: List[int] = []       # cumulative distinct, per chunk
+        cov_hits = cov_first = n_real_dev = None
+        if cov_on:
             cov_hits, cov_first = jax.device_put(
-                (jnp.asarray(np.asarray(resume_aux["cov_hits"], np.int32)),
-                 jnp.asarray(np.asarray(resume_aux["cov_first"], np.int32))),
-                NamedSharding(mesh, scalar_spec()))
-        elif resumed:
-            # Resume pre-pass: worlds that retired before the checkpoint
-            # carry frozen histograms but will never transition
-            # active→inactive in THIS call — fold them up front. The
-            # ledger is fold-order invariant (counts + minima), so the
-            # final hits/first_seen equal an unbroken run's bit for bit.
-            cov_hits, cov_first = _cov_endfolder(eng, mesh)(
-                state, cov_hits, cov_first, idx, n_real_dev,
-                jnp.asarray(False))
+                ledger_zeros(cov_k), NamedSharding(mesh, scalar_spec()))
+            n_real_dev = jnp.int32(n)
+            if resumed and "cov_hits" in resume_aux:
+                # Recycled checkpoints persist the ledger itself (retired-
+                # and-refilled slots no longer carry their histograms, so a
+                # pre-pass could not rebuild it): restore and continue.
+                # Folds trigger on active FALLING within a chunk, so worlds
+                # already inactive in the snapshot never re-fold.
+                cov_hits, cov_first = jax.device_put(
+                    tuple(jnp.asarray(np.asarray(resume_aux[k], np.int32))
+                          for k in ("cov_hits", "cov_first")),
+                    NamedSharding(mesh, scalar_spec()))
+            elif resumed:
+                # Resume pre-pass: worlds that retired before the checkpoint
+                # carry frozen histograms but will never transition
+                # active→inactive in THIS call — fold them up front. The
+                # ledger is fold-order invariant (counts + minima), so the
+                # final hits/first_seen equal an unbroken run's bit for bit.
+                cov_hits, cov_first = _cov_endfolder(eng, mesh)(
+                    state, cov_hits, cov_first, idx, n_real_dev,
+                    jnp.asarray(False))
 
     def emit_point(n_act: int, bug_seen: bool, depth: int) -> None:
         """One live-telemetry record per host read of the loop scalars
         (host data only — never a device pull)."""
         if emit_telemetry is None:
             return
-        elapsed = _clk() - t_loop0
+        elapsed = tr.clock() - t_loop0
         done = int(min(max(cursor - n_act, 0), n))
         rate = done / elapsed if elapsed > 0 else 0.0
         remaining = n - done
@@ -1385,7 +1389,7 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
         rec = {
             "schema": _SEARCH_SCHEMA,
             "event": "refill",
-            "elapsed_s": round(_clk() - t_loop0, 6),
+            "elapsed_s": round(tr.clock() - t_loop0, 6),
             "generation": search_host["gen"],
             "corpus_size": search_host["corpus_size"],
             "corpus_inserted": search_host["inserted"],
@@ -1415,10 +1419,9 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
         ride the existing cadence" half of the zero-new-syncs contract
         (tests/test_search.py counts this)."""
         obs_t, idx_t, tail_len, sched_t, stats_t, lin_t, op_t = handles
-        t0 = _clk()
-        obs_h, idx_h, sched_h, stats_h, lin_h, op_h = _fetch(
-            (obs_t, idx_t, sched_t, stats_t, lin_t, op_t))
-        perf["retire_wait_s"] += _clk() - t0
+        with tr.span("madsim:pull", "retire_wait_s"):
+            obs_h, idx_h, sched_h, stats_h, lin_h, op_h = _fetch(
+                (obs_t, idx_t, sched_t, stats_t, lin_t, op_t))
         perf["retire_fetches"] += 1
         if stats_h is not None:
             search_host["corpus_size"] = int(stats_h[0])
@@ -1611,58 +1614,59 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
             # mega-dispatches and mirroring telemetry scalars. ---------
             from ..obs.lineage import lanes_buffer
 
-            rep_sh = NamedSharding(mesh, scalar_spec())
-            n_ids_b = _pow2_at_least(n_ids)
-            fused_k_bucket = _pow2_at_least(max(min(c_max, _FUSED_K_CAP),
-                                                1))
-            # Replicated seed/fault tables the in-loop refill gathers
-            # from, bucketed to a power of two: every seed count in a
-            # bucket reuses ONE compiled program (the PR 3 zero-
-            # recompile contract extended to fused). Rows past n_ids
-            # are never gathered (the traced cursor clamps at the real
-            # count), so zero/repeat padding is inert.
-            lo = (seeds_p & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-            hi = (seeds_p >> np.uint64(32)).astype(np.uint32)
-            if n_ids_b > n_ids:
-                pad = n_ids_b - n_ids
-                lo = np.concatenate([lo, np.zeros(pad, np.uint32)])
-                hi = np.concatenate([hi, np.zeros(pad, np.uint32)])
-            tabs = {"lo": jnp.asarray(lo), "hi": jnp.asarray(hi)}
-            if search_on:
-                fault_mode = "search"
-            elif faults_p is None:
-                fault_mode = "none"
-            elif per_world_faults:
-                fault_mode = "per_world"
-                ftab = faults_p
+            with tr.span("madsim:upload", "upload_s"):
+                rep_sh = NamedSharding(mesh, scalar_spec())
+                n_ids_b = _pow2_at_least(n_ids)
+                fused_k_bucket = _pow2_at_least(max(min(c_max, _FUSED_K_CAP),
+                                                    1))
+                # Replicated seed/fault tables the in-loop refill gathers
+                # from, bucketed to a power of two: every seed count in a
+                # bucket reuses ONE compiled program (the PR 3 zero-
+                # recompile contract extended to fused). Rows past n_ids
+                # are never gathered (the traced cursor clamps at the real
+                # count), so zero/repeat padding is inert.
+                lo = (seeds_p & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+                hi = (seeds_p >> np.uint64(32)).astype(np.uint32)
                 if n_ids_b > n_ids:
-                    ftab = np.concatenate(
-                        [ftab, ftab[:1].repeat(n_ids_b - n_ids, axis=0)],
-                        axis=0)
-                tabs["faults"] = jnp.asarray(ftab, jnp.int32)
-            else:
-                fault_mode = "shared"
-                tabs["faults"] = jnp.asarray(faults_p, jnp.int32)
-            tabs = jax.device_put(tabs, rep_sh)
-            # Per-seed observation buffers (+ one dump row for masked
-            # scatters): retiring rows land at retire time INSIDE the
-            # loop, live rows at each mega-dispatch boundary, and the
-            # host pulls the whole thing ONCE at the end. eval_shape
-            # keeps buffer setup compile-free.
-            obs_shapes = jax.eval_shape(eng.observe_device, state)
-            fused_bufs = jax.device_put(
-                {k: jnp.zeros((n_ids_b + 1,) + tuple(sh.shape[1:]),
-                              sh.dtype)
-                 for k, sh in obs_shapes.items()}, rep_sh)
-            if search_on:
-                sb = np.full((n_ids_b + 1, f_rows, 4), -1, np.int32)
-                sb[:, :, 1:] = 0       # canonical disabled-row padding
-                fused_sched_buf = jax.device_put(jnp.asarray(sb), rep_sh)
-            if lineage_on:
-                fused_lin_buf = jax.device_put(
-                    lanes_buffer(n_ids_b), rep_sh)
-            cursor_dev = jax.device_put(jnp.int32(cursor), rep_sh)
-            epochs_dev = jax.device_put(jnp.int32(0), rep_sh)
+                    pad = n_ids_b - n_ids
+                    lo = np.concatenate([lo, np.zeros(pad, np.uint32)])
+                    hi = np.concatenate([hi, np.zeros(pad, np.uint32)])
+                tabs = {"lo": jnp.asarray(lo), "hi": jnp.asarray(hi)}
+                if search_on:
+                    fault_mode = "search"
+                elif faults_p is None:
+                    fault_mode = "none"
+                elif per_world_faults:
+                    fault_mode = "per_world"
+                    ftab = faults_p
+                    if n_ids_b > n_ids:
+                        ftab = np.concatenate(
+                            [ftab, ftab[:1].repeat(n_ids_b - n_ids, axis=0)],
+                            axis=0)
+                    tabs["faults"] = jnp.asarray(ftab, jnp.int32)
+                else:
+                    fault_mode = "shared"
+                    tabs["faults"] = jnp.asarray(faults_p, jnp.int32)
+                tabs = jax.device_put(tabs, rep_sh)
+                # Per-seed observation buffers (+ one dump row for masked
+                # scatters): retiring rows land at retire time INSIDE the
+                # loop, live rows at each mega-dispatch boundary, and the
+                # host pulls the whole thing ONCE at the end. eval_shape
+                # keeps buffer setup compile-free.
+                obs_shapes = jax.eval_shape(eng.observe_device, state)
+                fused_bufs = jax.device_put(
+                    {k: jnp.zeros((n_ids_b + 1,) + tuple(sh.shape[1:]),
+                                  sh.dtype)
+                     for k, sh in obs_shapes.items()}, rep_sh)
+                if search_on:
+                    sb = np.full((n_ids_b + 1, f_rows, 4), -1, np.int32)
+                    sb[:, :, 1:] = 0       # canonical disabled-row padding
+                    fused_sched_buf = jax.device_put(jnp.asarray(sb), rep_sh)
+                if lineage_on:
+                    fused_lin_buf = jax.device_put(
+                        lanes_buffer(n_ids_b), rep_sh)
+                cursor_dev = jax.device_put(jnp.int32(cursor), rep_sh)
+                epochs_dev = jax.device_put(jnp.int32(0), rep_sh)
             runner = _fused_hunt(
                 eng, mesh, search, w=w_cur, n_ids_b=n_ids_b,
                 f_rows=(f_rows if search_on else 0),
@@ -1679,14 +1683,13 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
             while first or (chunks < c_max and not stop):
                 first = False
                 k = max(0, min(fused_k_bucket, c_max - chunks))
-                t0 = _clk()
                 prof.before_dispatch()
                 srch_in = ()
                 if search_on:
                     srch_in = (slot_sched, corpus, fused_sched_buf)
                     if lineage_on:
                         srch_in += (slot_lin, op_tab, fused_lin_buf)
-                with prof.annotate("madsim:fused_hunt"):
+                with tr.span("madsim:fused_hunt", "dispatch_s"):
                     (state, idx, cursor_dev, epochs_dev, fused_bufs,
                      cov_pair, srch_out, any_bug, n_active, k_done,
                      hist, cov_h, stats_t) = runner(
@@ -1696,71 +1699,66 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                         jnp.int32(search_lin_base),
                         jnp.asarray(bool(stop_on_first_bug)),
                         jnp.int32(k))
-                perf["dispatch_s"] += _clk() - t0
-                perf["dispatches"] += 1
                 if cov_on:
                     cov_hits, cov_first = cov_pair
                 if search_on:
                     slot_sched, corpus, fused_sched_buf = srch_out[:3]
                     if lineage_on:
                         slot_lin, op_tab, fused_lin_buf = srch_out[3:]
-                t0 = _clk()
                 # ONE scalar batch per mega-dispatch — the sanctioned
                 # mid-hunt read (occupancy telemetry, novelty lane,
                 # cursor/epoch mirrors, stop_on_first_bug).
-                (bug_h, n_act_h, k_done_h, hist_h, cur_h, ep_h, cov_np,
-                 stats_h) = _fetch(
-                    (any_bug, n_active, k_done, hist, cursor_dev,
-                     epochs_dev, cov_h if cov_on else None,
-                     stats_t if search_on else None))
-                perf["device_wait_s"] += _clk() - t0
-                perf["scalar_fetches"] += 1
+                with tr.span("madsim:wait", "device_wait_s"):
+                    (bug_h, n_act_h, k_done_h, hist_h, cur_h, ep_h, cov_np,
+                     stats_h) = _fetch(
+                        (any_bug, n_active, k_done, hist, cursor_dev,
+                         epochs_dev, cov_h if cov_on else None,
+                         stats_t if search_on else None))
                 prof.after_read()
-                t0 = _clk()
-                k_done = int(k_done_h)
-                n_act = int(n_act_h)
-                hist_np = np.asarray(hist_h)
-                cov_arr = np.asarray(cov_np) if cov_on else None
-                for j in range(k_done):
-                    n_active_hist.append(int(hist_np[j]))
-                    n_active_chunk.append(chunks + j)
-                    if cov_on:
-                        novelty_hist.append(int(cov_arr[j]))
-                chunks += k_done
-                steps = chunks * chunk_steps
-                issued_slot_steps += w_cur * chunk_steps * k_done
-                cursor = int(cur_h)
-                if search_on and int(ep_h) > fused_epochs:
-                    # Host mirrors of the corpus telemetry, refreshed
-                    # from the LAST device refill's stats — once per
-                    # mega-dispatch rather than once per refill (the
-                    # per-refill cadence lives on device now; see
-                    # docs/observability.md). The operator table is NOT
-                    # pulled mid-hunt — its record rows fold at the end.
-                    search_host["corpus_size"] = int(stats_h[0])
-                    search_host["inserted"] = int(stats_h[1])
-                    if lineage_on:
-                        search_host["gen"] = int(stats_h[2])
-                        search_host["refill_novel"] = int(stats_h[3])
-                        search_host["refill_inserted"] = int(stats_h[4])
-                    search_host["epochs_on_device"] = int(ep_h)
-                    emit_search_point(None)
-                if int(ep_h) > 0:
-                    reordered = True
-                fused_epochs = int(ep_h)
-                more_seeds = cursor < n_ids
-                if (n_act == 0 and not more_seeds) or \
-                        (stop_on_first_bug and bool(bug_h)):
-                    stop = True
-                elif k_done < k:
-                    # The device loop exits early only on its stop
-                    # predicate; a short count means the predicate
-                    # fired on-device — mirror it (the scalars above
-                    # necessarily agree, but int rounding of a pulled
-                    # bool keeps this branch as the belt to their
-                    # suspenders).
-                    stop = True
-                perf["host_decision_s"] += _clk() - t0
+                with tr.span("madsim:decide", "host_decision_s"):
+                    k_done = int(k_done_h)
+                    n_act = int(n_act_h)
+                    hist_np = np.asarray(hist_h)
+                    cov_arr = np.asarray(cov_np) if cov_on else None
+                    for j in range(k_done):
+                        n_active_hist.append(int(hist_np[j]))
+                        n_active_chunk.append(chunks + j)
+                        if cov_on:
+                            novelty_hist.append(int(cov_arr[j]))
+                    chunks += k_done
+                    steps = chunks * chunk_steps
+                    issued_slot_steps += w_cur * chunk_steps * k_done
+                    cursor = int(cur_h)
+                    if search_on and int(ep_h) > fused_epochs:
+                        # Host mirrors of the corpus telemetry, refreshed
+                        # from the LAST device refill's stats — once per
+                        # mega-dispatch rather than once per refill (the
+                        # per-refill cadence lives on device now; see
+                        # docs/observability.md). The operator table is NOT
+                        # pulled mid-hunt — its record rows fold at the end.
+                        search_host["corpus_size"] = int(stats_h[0])
+                        search_host["inserted"] = int(stats_h[1])
+                        if lineage_on:
+                            search_host["gen"] = int(stats_h[2])
+                            search_host["refill_novel"] = int(stats_h[3])
+                            search_host["refill_inserted"] = int(stats_h[4])
+                        search_host["epochs_on_device"] = int(ep_h)
+                        emit_search_point(None)
+                    if int(ep_h) > 0:
+                        reordered = True
+                    fused_epochs = int(ep_h)
+                    more_seeds = cursor < n_ids
+                    if (n_act == 0 and not more_seeds) or \
+                            (stop_on_first_bug and bool(bug_h)):
+                        stop = True
+                    elif k_done < k:
+                        # The device loop exits early only on its stop
+                        # predicate; a short count means the predicate
+                        # fired on-device — mirror it (the scalars above
+                        # necessarily agree, but int rounding of a pulled
+                        # bool keeps this branch as the belt to their
+                        # suspenders).
+                        stop = True
                 emit_point(n_act, bool(bug_h), 0)
         elif pipeline:
             # -- pipelined, superstepped orchestration ---------------------
@@ -1815,9 +1813,8 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                     min_one=epoch_fresh,
                     coverage=cov_k if cov_on else None)
                 epoch_fresh = False
-                t0 = _clk()
                 prof.before_dispatch()
-                with prof.annotate("madsim:superstep"):
+                with tr.span("madsim:superstep", "dispatch_s"):
                     if cov_on:
                         (state, any_bug, n_active, k_done, hist, cov_hits,
                          cov_first, cov_h) = runner(
@@ -1831,8 +1828,6 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                             state, jnp.int32(threshold()),
                             jnp.asarray(bool(stop_on_first_bug)),
                             jnp.int32(k))
-                perf["dispatch_s"] += _clk() - t0
-                perf["dispatches"] += 1
                 inflight = _Flight(
                     any_bug, n_active, k_done, hist, k, w_cur, epoch,
                     state if writer is not None else None, cov_h,
@@ -1853,20 +1848,18 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                 # no-op (its entry condition is already false).
                 if not stop and chunks + prev.planned < c_max:
                     dispatch(reserve=prev.planned)
-                t0 = _clk()
-                if cov_on:
-                    # The novelty lane rides the SAME scalar batch — one
-                    # _fetch per superstep either way (tier-1-counted).
-                    bug_h, n_act_h, k_done_h, hist_h, cov_h = _fetch(
-                        (prev.any_bug, prev.n_active, prev.k_done,
-                         prev.hist, prev.cov_hist))
-                else:
-                    cov_h = None
-                    bug_h, n_act_h, k_done_h, hist_h = _fetch(
-                        (prev.any_bug, prev.n_active, prev.k_done,
-                         prev.hist))
-                perf["device_wait_s"] += _clk() - t0
-                perf["scalar_fetches"] += 1
+                with tr.span("madsim:wait", "device_wait_s"):
+                    if cov_on:
+                        # The novelty lane rides the SAME scalar batch — one
+                        # _fetch per superstep either way (tier-1-counted).
+                        bug_h, n_act_h, k_done_h, hist_h, cov_h = _fetch(
+                            (prev.any_bug, prev.n_active, prev.k_done,
+                             prev.hist, prev.cov_hist))
+                    else:
+                        cov_h = None
+                        bug_h, n_act_h, k_done_h, hist_h = _fetch(
+                            (prev.any_bug, prev.n_active, prev.k_done,
+                             prev.hist))
                 prof.after_read()
                 perf["dispatch_depth"] = max(
                     perf["dispatch_depth"], 1 if inflight is not None else 0)
@@ -1874,65 +1867,64 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                 # drain them here, where the loop blocks anyway.
                 while pending_retires:
                     fetch_retire(pending_retires.pop(0))
-                t0 = _clk()
-                k_done = int(k_done_h)
-                n_act = int(n_act_h)
-                hist_np = np.asarray(hist_h)
-                cov_np = np.asarray(cov_h) if cov_on else None
-                for j in range(k_done):
-                    n_active_hist.append(int(hist_np[j]))
-                    n_active_chunk.append(chunks + j)
-                    if cov_on:
-                        novelty_hist.append(int(cov_np[j]))
-                chunks += k_done
-                steps = chunks * chunk_steps
-                issued_slot_steps += prev.w * chunk_steps * k_done
-                if prev.epoch == epoch:
-                    # Superstep sizing adapts to the observed retirement
-                    # rate: double while supersteps run to plan (slow
-                    # start), and after an early exit settle on the
-                    # chunks it actually ran — the measured
-                    # chunks-per-decision of this workload. Deterministic
-                    # — every input is a sim output; and since K is a
-                    # traced scalar, the schedule costs no recompiles.
-                    if k_done == prev.planned:
-                        k_cur = min(k_cur * 2, superstep_max)
-                    else:
-                        k_cur = max(k_done, 1)
-                if writer is not None and checkpoint_every_chunks and \
-                        prev.epoch == epoch and \
-                        chunks // checkpoint_every_chunks > ckpt_mark:
-                    # Async: the pull + write overlap later supersteps'
-                    # device work; the submitted state is a COMPLETED
-                    # superstep output (donation is off with a writer).
-                    # Epoch-gated: a stale pass-through superstep's state
-                    # predates the refill the host idx/cursor already
-                    # reflect — submitting it would tear the snapshot
-                    # (the current epoch's next superstep submits soon).
-                    writer.submit(prev.out_state, ckpt_aux(prev.out_cov))
-                    submitted_chunks = chunks
-                    ckpt_mark = chunks // checkpoint_every_chunks
-                if prev.epoch == epoch and not stop:
-                    more_seeds = cursor < n_ids
-                    if n_act == 0 and not more_seeds:
-                        stop = True
-                    elif stop_on_first_bug and bool(bug_h):
-                        stop = True
-                    elif recycle and more_seeds and n_act <= w_cur // 2:
-                        pending_retires.append(do_refill(n_act))
-                        epoch += 1
-                        epoch_fresh = True
-                    else:
-                        new_w = _compact_bucket(n_act, w_cur, n_dev)
-                        # Dry-cursor shrink only without a writer: every
-                        # snapshot written must stay full-shape-resumable.
-                        if (compact or (recycle and not more_seeds
-                                        and writer is None)) \
-                                and new_w < w_cur:
-                            pending_retires.append(do_shrink(new_w))
+                with tr.span("madsim:decide", "host_decision_s"):
+                    k_done = int(k_done_h)
+                    n_act = int(n_act_h)
+                    hist_np = np.asarray(hist_h)
+                    cov_np = np.asarray(cov_h) if cov_on else None
+                    for j in range(k_done):
+                        n_active_hist.append(int(hist_np[j]))
+                        n_active_chunk.append(chunks + j)
+                        if cov_on:
+                            novelty_hist.append(int(cov_np[j]))
+                    chunks += k_done
+                    steps = chunks * chunk_steps
+                    issued_slot_steps += prev.w * chunk_steps * k_done
+                    if prev.epoch == epoch:
+                        # Superstep sizing adapts to the observed retirement
+                        # rate: double while supersteps run to plan (slow
+                        # start), and after an early exit settle on the
+                        # chunks it actually ran — the measured
+                        # chunks-per-decision of this workload. Deterministic
+                        # — every input is a sim output; and since K is a
+                        # traced scalar, the schedule costs no recompiles.
+                        if k_done == prev.planned:
+                            k_cur = min(k_cur * 2, superstep_max)
+                        else:
+                            k_cur = max(k_done, 1)
+                    if writer is not None and checkpoint_every_chunks and \
+                            prev.epoch == epoch and \
+                            chunks // checkpoint_every_chunks > ckpt_mark:
+                        # Async: the pull + write overlap later supersteps'
+                        # device work; the submitted state is a COMPLETED
+                        # superstep output (donation is off with a writer).
+                        # Epoch-gated: a stale pass-through superstep's state
+                        # predates the refill the host idx/cursor already
+                        # reflect — submitting it would tear the snapshot
+                        # (the current epoch's next superstep submits soon).
+                        writer.submit(prev.out_state, ckpt_aux(prev.out_cov))
+                        submitted_chunks = chunks
+                        ckpt_mark = chunks // checkpoint_every_chunks
+                    if prev.epoch == epoch and not stop:
+                        more_seeds = cursor < n_ids
+                        if n_act == 0 and not more_seeds:
+                            stop = True
+                        elif stop_on_first_bug and bool(bug_h):
+                            stop = True
+                        elif recycle and more_seeds and n_act <= w_cur // 2:
+                            pending_retires.append(do_refill(n_act))
                             epoch += 1
                             epoch_fresh = True
-                perf["host_decision_s"] += _clk() - t0
+                        else:
+                            new_w = _compact_bucket(n_act, w_cur, n_dev)
+                            # Dry-cursor shrink only without a writer: every
+                            # snapshot written must stay full-shape-resumable.
+                            if (compact or (recycle and not more_seeds
+                                            and writer is None)) \
+                                    and new_w < w_cur:
+                                pending_retires.append(do_shrink(new_w))
+                                epoch += 1
+                                epoch_fresh = True
                 emit_point(n_act, bool(bug_h),
                            1 if inflight is not None else 0)
                 if stop:
@@ -1946,9 +1938,8 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
             runner = sharded_engine(eng, mesh, chunk_steps, donate=donate,
                                     coverage=cov_k if cov_on else None)
             while steps < max_steps:
-                t0 = _clk()
                 prof.before_dispatch()
-                with prof.annotate("madsim:chunk"):
+                with tr.span("madsim:chunk", "dispatch_s"):
                     if cov_on:
                         (state, any_bug, n_active, cov_hits, cov_first,
                          distinct) = runner(state, cov_hits, cov_first,
@@ -1956,8 +1947,6 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                     else:
                         distinct = None
                         state, any_bug, n_active = runner(state)
-                perf["dispatch_s"] += _clk() - t0
-                perf["dispatches"] += 1
                 steps += chunk_steps
                 chunks += 1
                 issued_slot_steps += w_cur * chunk_steps
@@ -1968,43 +1957,37 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                     writer.submit(state, ckpt_aux(
                         (cov_hits, cov_first) if cov_on else None))
                     submitted_chunks = chunks
-                t0 = _clk()
-                if cov_on:
-                    n_act_h, bug_h, dist_h = _fetch(
-                        (n_active, any_bug, distinct))
-                else:
-                    n_act_h, bug_h = _fetch((n_active, any_bug))
-                perf["device_wait_s"] += _clk() - t0
-                perf["scalar_fetches"] += 1
+                with tr.span("madsim:wait", "device_wait_s"):
+                    if cov_on:
+                        n_act_h, bug_h, dist_h = _fetch(
+                            (n_active, any_bug, distinct))
+                    else:
+                        n_act_h, bug_h = _fetch((n_active, any_bug))
                 prof.after_read()
                 n_act = int(n_act_h)
                 if cov_on:
                     novelty_hist.append(int(dist_h))
                 emit_point(n_act, bool(bug_h), 0)
-                t0 = _clk()
-                n_active_hist.append(n_act)
-                n_active_chunk.append(chunks - 1)
-                more_seeds = cursor < n_ids
-                if n_act == 0 and not more_seeds:
-                    perf["host_decision_s"] += _clk() - t0
+                handles = None
+                with tr.span("madsim:decide", "host_decision_s"):
+                    n_active_hist.append(n_act)
+                    n_active_chunk.append(chunks - 1)
+                    more_seeds = cursor < n_ids
+                    stop = (n_act == 0 and not more_seeds) or \
+                        (stop_on_first_bug and bool(bug_h))
+                    if not stop and recycle and more_seeds \
+                            and n_act <= w_cur // 2:
+                        handles = do_refill(n_act)
+                    elif not stop:
+                        new_w = _compact_bucket(n_act, w_cur, n_dev)
+                        if (compact or (recycle and not more_seeds
+                                        and writer is None)) \
+                                and new_w < w_cur:
+                            handles = do_shrink(new_w)
+                if stop:
                     break
-                if stop_on_first_bug and bool(bug_h):
-                    perf["host_decision_s"] += _clk() - t0
-                    break
-                if recycle and more_seeds and n_act <= w_cur // 2:
-                    handles = do_refill(n_act)
-                    perf["host_decision_s"] += _clk() - t0
+                if handles is not None:
                     fetch_retire(handles)
-                    continue
-                new_w = _compact_bucket(n_act, w_cur, n_dev)
-                if (compact or (recycle and not more_seeds
-                                and writer is None)) \
-                        and new_w < w_cur:
-                    handles = do_shrink(new_w)
-                    perf["host_decision_s"] += _clk() - t0
-                    fetch_retire(handles)
-                else:
-                    perf["host_decision_s"] += _clk() - t0
         if writer is not None and submitted_chunks != chunks:
             # The final state is always durable.
             writer.submit(state, ckpt_aux(
@@ -2035,192 +2018,187 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
         # whole result crosses in ONE pull — the "pulled once at the
         # end" half of the fused contract. Everything below is host
         # slicing of bucket padding.
-        t0 = _clk()
-        (bufs_h, cov_pack_h, sched_b_h, corpus_h, lin_b_h,
-         op_tab_h) = _fetch(
-            (fused_bufs, (cov_hits, cov_first) if cov_on else None,
-             fused_sched_buf, corpus, fused_lin_buf, op_tab))
-        perf["retire_wait_s"] += _clk() - t0
+        with tr.span("madsim:pull", "retire_wait_s"):
+            (bufs_h, cov_pack_h, sched_b_h, corpus_h, lin_b_h,
+             op_tab_h) = _fetch(
+                (fused_bufs, (cov_hits, cov_first) if cov_on else None,
+                 fused_sched_buf, corpus, fused_lin_buf, op_tab))
         perf["retire_fetches"] += 1
-        if cov_on:
-            cov_hits_h, cov_first_h = (np.asarray(x) for x in cov_pack_h)
-        obs = {k: np.asarray(v)[:n_ids] for k, v in bufs_h.items()}
-        live_world_steps += int(np.asarray(obs["steps"]).sum())
-        if search_on:
-            sched_per_seed = np.asarray(sched_b_h, np.int32)[:n_ids]
-        if lineage_on:
-            lin_per_seed = tuple(np.asarray(a, np.int32)[:n_ids]
-                                 for a in lin_b_h)
     else:
-        obs_live = eng.observe(state)
-        if cov_on and search_on:
-            # Search state rides the final ledger pull — still ONE _fetch.
-            (idx_h, cov_hits_h, cov_first_h, sched_live_h, corpus_h,
-             lin_live_h, op_tab_h) = _fetch(
-                (idx, cov_hits, cov_first, slot_sched, corpus, slot_lin,
-                 op_tab))
-            idx_h, cov_hits_h, cov_first_h = (
-                np.asarray(x) for x in (idx_h, cov_hits_h, cov_first_h))
-            sched_live_h = np.asarray(sched_live_h, np.int32)
-            if lin_live_h is not None:
-                lin_live_h = tuple(np.asarray(a) for a in lin_live_h)
-        elif cov_on:
-            # The ledger rides the final slot-index pull — still ONE
-            # _fetch.
-            idx_h, cov_hits_h, cov_first_h = (
-                np.asarray(x) for x in _fetch((idx, cov_hits, cov_first)))
-        else:
-            idx_h = np.asarray(_fetch(idx))
-        live_keep = idx_h >= 0
-        live_world_steps += int(
-            np.asarray(obs_live["steps"])[live_keep].sum())
-        # Scatter whenever the live batch does not cover the full id
-        # space in seed order — after any reorder/retirement, OR when a
-        # recycled sweep exited (stop_on_first_bug / max_steps) before
-        # its first refill, so only the first w0 < n_ids seeds were
-        # ever admitted.
-        if reordered or retired_rows or w0 < n_ids:
-            rows = np.concatenate(retired_rows + [idx_h[live_keep]])
-            obs = {}
-            for k, v_live in obs_live.items():
-                v_live = np.asarray(v_live)[live_keep]
-                merged = np.concatenate(retired.get(k, []) + [v_live],
-                                        axis=0)
-                # Zeros, not empty: an early stop (stop_on_first_bug)
-                # can leave streamed seeds never admitted — they report
-                # zeroed observations (bug=False) rather than garbage.
-                out = np.zeros((n_ids,) + merged.shape[1:], merged.dtype)
-                out[rows] = merged
-                obs[k] = out
+        with tr.span("madsim:pull", "retire_wait_s"):
+            obs_live = eng.observe(state)
+            if cov_on and search_on:
+                # Search state rides the final ledger pull — still ONE
+                # _fetch.
+                (idx_h, cov_hits_h, cov_first_h, sched_live_h, corpus_h,
+                 lin_live_h, op_tab_h) = _fetch(
+                    (idx, cov_hits, cov_first, slot_sched, corpus,
+                     slot_lin, op_tab))
+            elif cov_on:
+                # The ledger rides the final slot-index pull — still ONE
+                # _fetch.
+                idx_h, cov_hits_h, cov_first_h = _fetch(
+                    (idx, cov_hits, cov_first))
+            else:
+                idx_h = _fetch(idx)
+    with tr.span("madsim:assemble", "assemble_s"):
+        if fused:
+            if cov_on:
+                cov_hits_h, cov_first_h = (np.asarray(x) for x in cov_pack_h)
+            obs = {k: np.asarray(v)[:n_ids] for k, v in bufs_h.items()}
+            live_world_steps += int(np.asarray(obs["steps"]).sum())
             if search_on:
-                merged_s = np.concatenate(
-                    retired_sched + [sched_live_h[live_keep]], axis=0)
-                sched_out = np.full((n_ids,) + merged_s.shape[1:], -1,
-                                    np.int32)
-                sched_out[:, :, 1:] = 0  # canonical DISABLED_ROW padding
-                sched_out[rows] = merged_s
-                sched_per_seed = sched_out
-            if lin_live_h is not None:
-                # Per-seed lineage lanes scatter exactly like the
-                # schedules; never-admitted seeds read as generation 0
-                # (-1 parents, no operators, depth 0).
-                lanes_out = []
-                for i, dflt in enumerate((-1, -1, 0, 0)):
-                    merged_l = np.concatenate(
-                        [t[i] for t in retired_lin]
-                        + [lin_live_h[i][live_keep]], axis=0)
-                    out = np.full((n_ids,), dflt, np.int32)
-                    out[rows] = np.asarray(merged_l, np.int32)
-                    lanes_out.append(out)
-                lin_per_seed = tuple(lanes_out)
+                sched_per_seed = np.asarray(sched_b_h, np.int32)[:n_ids]
+            if lineage_on:
+                lin_per_seed = tuple(np.asarray(a, np.int32)[:n_ids]
+                                     for a in lin_b_h)
         else:
-            obs = obs_live
-            if search_on:
-                sched_per_seed = sched_live_h
-            if lin_live_h is not None:
-                lin_per_seed = tuple(np.asarray(a, np.int32)
-                                     for a in lin_live_h)
-    obs = {k: v[:n] for k, v in obs.items()}
-    if sched_per_seed is not None:
-        sched_per_seed = sched_per_seed[:n]
-    if lin_per_seed is not None:
-        lin_per_seed = tuple(a[:n] for a in lin_per_seed)
-    util = (live_world_steps / issued_slot_steps if issued_slot_steps
-            else 0.0)
-    loop_stats = {
-        "pipelined": bool(pipeline) and not fused,
-        "fused": bool(fused),
-        "superstep_max": (int(fused_k_bucket) if fused
-                          else int(superstep_max) if pipeline else 1),
-        "chunk_steps": int(chunk_steps),
-        "chunks": int(chunks),
-        "dispatches": int(perf["dispatches"]),
-        "chunks_per_dispatch": round(
-            chunks / max(perf["dispatches"], 1), 3),
-        "dispatches_per_seed": round(
-            perf["dispatches"] / max(n, 1), 6),
-        # The fused headline (and its reciprocal): how many seeds one
-        # host dispatch retires end to end. epochs_on_device counts the
-        # refill epochs that ran INSIDE fused mega-dispatches (0 on the
-        # host-orchestrated paths, where every epoch is its own
-        # dispatch).
-        "seeds_per_dispatch": round(
-            n / max(perf["dispatches"], 1), 3),
-        "epochs_on_device": int(fused_epochs),
-        "dispatch_depth": int(perf["dispatch_depth"]),
-        "device_wait_s": round(perf["device_wait_s"], 6),
-        "host_decision_s": round(perf["host_decision_s"], 6),
-        "dispatch_s": round(perf["dispatch_s"], 6),
-        "retire_wait_s": round(perf["retire_wait_s"], 6),
-        "scalar_fetches": int(perf["scalar_fetches"]),
-        "retire_fetches": int(perf["retire_fetches"]),
-        "loop_wall_s": round(_clk() - t_loop0, 6),
-    }
-    coverage = (coverage_from_device(cov_k, cov_hits_h, cov_first_h,
-                                     novelty_hist) if cov_on else None)
-    search_report = None
-    triage_faults = faults
-    if search_on:
-        from ..search import SearchReport
-
-        lineage_rep = op_stats = None
+            live_keep = idx_h >= 0
+            live_world_steps += int(
+                np.asarray(obs_live["steps"])[live_keep].sum())
+            # Scatter whenever the live batch does not cover the full id
+            # space in seed order — after any reorder/retirement, OR when a
+            # recycled sweep exited (stop_on_first_bug / max_steps) before
+            # its first refill, so only the first w0 < n_ids seeds were
+            # ever admitted.
+            if reordered or retired_rows or w0 < n_ids:
+                rows = np.concatenate(retired_rows + [idx_h[live_keep]])
+                obs = {}
+                for k, v_live in obs_live.items():
+                    v_live = np.asarray(v_live)[live_keep]
+                    merged = np.concatenate(retired.get(k, []) + [v_live],
+                                            axis=0)
+                    # Zeros, not empty: an early stop (stop_on_first_bug)
+                    # can leave streamed seeds never admitted — they report
+                    # zeroed observations (bug=False) rather than garbage.
+                    out = np.zeros((n_ids,) + merged.shape[1:], merged.dtype)
+                    out[rows] = merged
+                    obs[k] = out
+                if search_on:
+                    merged_s = np.concatenate(
+                        retired_sched + [sched_live_h[live_keep]], axis=0)
+                    sched_out = np.full((n_ids,) + merged_s.shape[1:], -1,
+                                        np.int32)
+                    sched_out[:, :, 1:] = 0  # canonical DISABLED_ROW padding
+                    sched_out[rows] = merged_s
+                    sched_per_seed = sched_out
+                if lin_live_h is not None:
+                    # Per-seed lineage lanes scatter exactly like the
+                    # schedules; never-admitted seeds read as generation 0
+                    # (-1 parents, no operators, depth 0).
+                    lanes_out = []
+                    for i, dflt in enumerate((-1, -1, 0, 0)):
+                        merged_l = np.concatenate(
+                            [t[i] for t in retired_lin]
+                            + [lin_live_h[i][live_keep]], axis=0)
+                        out = np.full((n_ids,), dflt, np.int32)
+                        out[rows] = np.asarray(merged_l, np.int32)
+                        lanes_out.append(out)
+                    lin_per_seed = tuple(lanes_out)
+            else:
+                obs = obs_live
+                if search_on:
+                    sched_per_seed = sched_live_h
+                if lin_live_h is not None:
+                    lin_per_seed = tuple(np.asarray(a, np.int32)
+                                         for a in lin_live_h)
+        obs = {k: v[:n] for k, v in obs.items()}
+        if sched_per_seed is not None:
+            sched_per_seed = sched_per_seed[:n]
         if lin_per_seed is not None:
-            from ..obs.lineage import (
-                N_OPS,
-                SearchLineage,
-                host_credit,
-                operator_stats,
-            )
+            lin_per_seed = tuple(a[:n] for a in lin_per_seed)
+        util = (live_world_steps / issued_slot_steps if issued_slot_steps
+                else 0.0)
+        n_disp = tr.entered["madsim:fused_hunt" if fused else
+                            "madsim:superstep" if pipeline else "madsim:chunk"]
+        loop_stats = {
+            "pipelined": bool(pipeline) and not fused,
+            "fused": bool(fused),
+            "superstep_max": (int(fused_k_bucket) if fused
+                              else int(superstep_max) if pipeline else 1),
+            "chunk_steps": int(chunk_steps),
+            "chunks": int(chunks),
+            "dispatches": n_disp,
+            "chunks_per_dispatch": round(chunks / max(n_disp, 1), 3),
+            "dispatches_per_seed": round(n_disp / max(n, 1), 6),
+            # The fused headline (and its reciprocal): how many seeds one
+            # host dispatch retires end to end. epochs_on_device counts the
+            # refill epochs that ran INSIDE fused mega-dispatches (0 on the
+            # host-orchestrated paths, where every epoch is its own
+            # dispatch).
+            "seeds_per_dispatch": round(n / max(n_disp, 1), 3),
+            "epochs_on_device": int(fused_epochs),
+            "dispatch_depth": int(perf["dispatch_depth"]),
+            "scalar_fetches": tr.entered["madsim:wait"],
+            "retire_fetches": int(perf["retire_fetches"]),
+            "loop_wall_s": round(tr.clock() - t_loop0, 6),
+        }
+        coverage = (coverage_from_device(cov_k, cov_hits_h, cov_first_h,
+                                         novelty_hist) if cov_on else None)
+        search_report = None
+        triage_faults = faults
+        if search_on:
+            from ..search import SearchReport
 
-            lineage_rep = SearchLineage(
-                parent1=lin_per_seed[0], parent2=lin_per_seed[1],
-                ops=lin_per_seed[2], depth=lin_per_seed[3],
-                entry_base=int(search_lin_base))
-            # Bug credit folds HOST-side over the per-seed lanes: a find
-            # that halted the sweep (or sat live at exit) never crossed
-            # a harvest edge, so only this fold counts every find
-            # exactly once (obs/lineage.py OperatorTable).
-            op_bug = host_credit(np.zeros(N_OPS, np.int32),
-                                 lineage_rep.ops,
-                                 np.asarray(obs["bug"], bool))
-            op_stats = operator_stats(*(tuple(op_tab_h) + (op_bug,)))
-        c_filled = np.asarray(corpus_h.filled, bool)
-        search_report = SearchReport(
-            # Generations THIS sweep ran: the epoch stream offset
-            # (search_gen0) is a key-space shift, not work done here.
-            generations=int(np.asarray(corpus_h.gen)) - int(search_gen0),
-            inserted=int(np.asarray(corpus_h.inserted)),
-            corpus_size=int(c_filled.sum()),
-            corpus_capacity=int(c_filled.shape[0]),
-            corpus_sched=np.asarray(corpus_h.sched, np.int32),
-            corpus_sig=np.asarray(corpus_h.sig, np.uint32),
-            corpus_score=np.asarray(corpus_h.score, np.int32),
-            corpus_filled=c_filled,
-            schedules=sched_per_seed,
-            corpus_entry=np.asarray(corpus_h.entry, np.int32),
-            corpus_depth=np.asarray(corpus_h.depth, np.int32),
-            lineage=lineage_rep,
-            operator_stats=op_stats,
-        )
-        # Triage sees the MATERIALIZED per-seed schedules: a guided
-        # find's minimize/triage path re-executes the child schedule
-        # the world actually ran, not the template.
-        triage_faults = sched_per_seed
-    result = SweepResult(seeds=seeds, bug=obs["bug"], observations=obs,
-                         steps_run=steps, n_devices=n_dev,
-                         n_active_history=np.asarray(n_active_hist,
-                                                     np.int64),
-                         world_utilization=util,
-                         n_active_chunks=np.asarray(n_active_chunk,
-                                                    np.int64),
-                         loop_stats=loop_stats,
-                         faults_sha256=(seeds_meta["faults_sha256"]
-                                        if faults is not None else None),
-                         coverage=coverage,
-                         search=search_report,
-                         triage_ctx=TriageContext(engine=eng,
-                                                  faults=triage_faults,
-                                                  mesh=mesh))
+            lineage_rep = op_stats = None
+            if lin_per_seed is not None:
+                from ..obs.lineage import (
+                    N_OPS,
+                    SearchLineage,
+                    host_credit,
+                    operator_stats,
+                )
+
+                lineage_rep = SearchLineage(
+                    parent1=lin_per_seed[0], parent2=lin_per_seed[1],
+                    ops=lin_per_seed[2], depth=lin_per_seed[3],
+                    entry_base=int(search_lin_base))
+                # Bug credit folds HOST-side over the per-seed lanes: a find
+                # that halted the sweep (or sat live at exit) never crossed
+                # a harvest edge, so only this fold counts every find
+                # exactly once (obs/lineage.py OperatorTable).
+                op_bug = host_credit(np.zeros(N_OPS, np.int32),
+                                     lineage_rep.ops,
+                                     np.asarray(obs["bug"], bool))
+                op_stats = operator_stats(*(tuple(op_tab_h) + (op_bug,)))
+            c_filled = np.asarray(corpus_h.filled, bool)
+            search_report = SearchReport(
+                # Generations THIS sweep ran: the epoch stream offset
+                # (search_gen0) is a key-space shift, not work done here.
+                generations=int(np.asarray(corpus_h.gen)) - int(search_gen0),
+                inserted=int(np.asarray(corpus_h.inserted)),
+                corpus_size=int(c_filled.sum()),
+                corpus_capacity=int(c_filled.shape[0]),
+                corpus_sched=np.asarray(corpus_h.sched, np.int32),
+                corpus_sig=np.asarray(corpus_h.sig, np.uint32),
+                corpus_score=np.asarray(corpus_h.score, np.int32),
+                corpus_filled=c_filled,
+                schedules=sched_per_seed,
+                corpus_entry=np.asarray(corpus_h.entry, np.int32),
+                corpus_depth=np.asarray(corpus_h.depth, np.int32),
+                lineage=lineage_rep,
+                operator_stats=op_stats,
+            )
+            # Triage sees the MATERIALIZED per-seed schedules: a guided
+            # find's minimize/triage path re-executes the child schedule
+            # the world actually ran, not the template.
+            triage_faults = sched_per_seed
+        result = SweepResult(seeds=seeds, bug=obs["bug"], observations=obs,
+                             steps_run=steps, n_devices=n_dev,
+                             n_active_history=np.asarray(n_active_hist,
+                                                         np.int64),
+                             world_utilization=util,
+                             n_active_chunks=np.asarray(n_active_chunk,
+                                                        np.int64),
+                             loop_stats=loop_stats,
+                             faults_sha256=(seeds_meta["faults_sha256"]
+                                            if faults is not None else None),
+                             coverage=coverage,
+                             search=search_report,
+                             triage_ctx=TriageContext(engine=eng,
+                                                      faults=triage_faults,
+                                                      mesh=mesh))
+    loop_stats.update(tr.seconds())
     if emit_telemetry is not None:
         final = {
             # /2: seeds_per_dispatch + epochs_on_device surfaced top-
@@ -2443,6 +2421,8 @@ def _fused_hunt(eng: DeviceEngine, mesh: Mesh, scfg, *, w: int,
     program (the PR 3 zero-recompile contract extended to fused).
     ``fault_mode``: ``search`` (children), ``per_world`` (gather the
     replicated table), ``shared`` (broadcast the template) or ``none``.
+    The phases' ops carry ``jax.named_scope`` names (``madsim/compact``
+    and kin, docs/observability.md) for the device trace.
     """
     cache = eng.__dict__.setdefault("_fused_hunt_cache", {})
     key = (mesh, w, n_ids_b, f_rows, chunk_steps, k_bucket, cov_k,
@@ -2469,68 +2449,72 @@ def _fused_hunt(eng: DeviceEngine, mesh: Mesh, scfg, *, w: int,
         # (1) Stable active-first compaction — the _compactor program's
         # exact permutation, applied to the state, the slot→seed index
         # and (guided) the schedule/lane arrays in lockstep.
-        order = jnp.argsort((~s.active).astype(jnp.int32), stable=True)
-        perm = (s, ex["idx"])
-        if search_on:
-            perm = perm + (ex["sched"],)
-        if lineage_on:
-            perm = perm + (ex["lin"],)
-        perm = jax.tree.map(lambda x: x[order], perm)
-        s, idx = perm[0], perm[1]
-        sched = perm[2] if search_on else None
-        lin = perm[3] if lineage_on else None
+        with jax.named_scope("madsim/compact"):
+            order = jnp.argsort((~s.active).astype(jnp.int32), stable=True)
+            perm = (s, ex["idx"])
+            if search_on:
+                perm = perm + (ex["sched"],)
+            if lineage_on:
+                perm = perm + (ex["lin"],)
+            perm = jax.tree.map(lambda x: x[order], perm)
+            s, idx = perm[0], perm[1]
+            sched = perm[2] if search_on else None
+            lin = perm[3] if lineage_on else None
         # (2) Retiring-tail harvest: scatter the frozen rows' final
         # observations by slot→seed idx into the per-seed buffers (the
         # serial loop's retire() attribution, kept on device). Dead
         # slots (idx < 0, dry-cursor leftovers already harvested) land
         # on the dump row.
-        tail = (rows_r >= n_act) & (idx >= 0)
-        tgt = jnp.where(tail, idx, dump)
-        obs = eng.observe_device(s)
-        ex = dict(ex, idx=idx)
-        ex["bufs"] = {k: ex["bufs"][k].at[tgt].set(obs[k])
-                      for k in ex["bufs"]}
+        with jax.named_scope("madsim/harvest"):
+            tail = (rows_r >= n_act) & (idx >= 0)
+            tgt = jnp.where(tail, idx, dump)
+            obs = eng.observe_device(s)
+            ex = dict(ex, idx=idx)
+            ex["bufs"] = {k: ex["bufs"][k].at[tgt].set(obs[k])
+                          for k in ex["bufs"]}
         # (3) Admit the next seeds from the device-resident cursor —
         # the same take/repl/mask arithmetic do_refill ran on host.
-        take = jnp.minimum(jnp.int32(w) - n_act,
-                           n_ids_real - ex["cursor"])
-        fill = (rows_r >= n_act) & (rows_r < n_act + take)
-        repl = jnp.where(fill, ex["cursor"] + rows_r - n_act,
-                         jnp.int32(-1))
-        fill_ids = jnp.maximum(repl, 0)
-        if search_on:
-            # Park the retiring schedules (and provenance lanes) BEFORE
-            # the children overwrite them — the pre-refill read order of
-            # the serial _sched_tail gather.
-            ex["sched_buf"] = ex["sched_buf"].at[tgt].set(sched)
-            if lineage_on:
-                ex["lin_buf"] = jax.tree.map(
-                    lambda b, v: b.at[tgt].set(v), ex["lin_buf"], lin)
-                (children, child_lin, ex["corpus"], ex["op_tab"],
-                 ex["stats"]) = gen_fn(
-                    s, sched, idx, ex["corpus"], n_act, fill_ids, fill,
-                    lin, ex["op_tab"], lin_base)
-                ex["lin"] = jax.tree.map(
-                    lambda c, o: jnp.where(fill, c, o), child_lin, lin)
+        with jax.named_scope("madsim/generate"):
+            take = jnp.minimum(jnp.int32(w) - n_act,
+                               n_ids_real - ex["cursor"])
+            fill = (rows_r >= n_act) & (rows_r < n_act + take)
+            repl = jnp.where(fill, ex["cursor"] + rows_r - n_act,
+                             jnp.int32(-1))
+            fill_ids = jnp.maximum(repl, 0)
+            if search_on:
+                # Park the retiring schedules (and provenance lanes) BEFORE
+                # the children overwrite them — the pre-refill read order of
+                # the serial _sched_tail gather.
+                ex["sched_buf"] = ex["sched_buf"].at[tgt].set(sched)
+                if lineage_on:
+                    ex["lin_buf"] = jax.tree.map(
+                        lambda b, v: b.at[tgt].set(v), ex["lin_buf"], lin)
+                    (children, child_lin, ex["corpus"], ex["op_tab"],
+                     ex["stats"]) = gen_fn(
+                        s, sched, idx, ex["corpus"], n_act, fill_ids, fill,
+                        lin, ex["op_tab"], lin_base)
+                    ex["lin"] = jax.tree.map(
+                        lambda c, o: jnp.where(fill, c, o), child_lin, lin)
+                else:
+                    children, ex["corpus"], ex["stats"] = gen_fn(
+                        s, sched, idx, ex["corpus"], n_act, fill_ids)
+                f_new = children
+                ex["sched"] = jnp.where(fill[:, None, None], children, sched)
+            elif fault_mode == "per_world":
+                f_new = tabs["faults"][fill_ids]
+            elif fault_mode == "shared":
+                f_new = jnp.broadcast_to(tabs["faults"],
+                                         (w,) + tabs["faults"].shape)
             else:
-                children, ex["corpus"], ex["stats"] = gen_fn(
-                    s, sched, idx, ex["corpus"], n_act, fill_ids)
-            f_new = children
-            ex["sched"] = jnp.where(fill[:, None, None], children, sched)
-        elif fault_mode == "per_world":
-            f_new = tabs["faults"][fill_ids]
-        elif fault_mode == "shared":
-            f_new = jnp.broadcast_to(tabs["faults"],
-                                     (w,) + tabs["faults"].shape)
-        else:
-            f_new = jnp.zeros((w, 0, 4), jnp.int32)
+                f_new = jnp.zeros((w, 0, 4), jnp.int32)
         # (4) Re-key the refilled slots: the traced twin of
         # DeviceEngine.refill (same vmapped _init_one, same select).
-        s = eng.refill_traced(s, fill, tabs["lo"][fill_ids],
-                              tabs["hi"][fill_ids], f_new)
-        ex["idx"] = jnp.where(rows_r >= n_act, repl, idx)
-        ex["cursor"] = ex["cursor"] + take
-        ex["epochs"] = ex["epochs"] + jnp.int32(1)
+        with jax.named_scope("madsim/rekey"):
+            s = eng.refill_traced(s, fill, tabs["lo"][fill_ids],
+                                  tabs["hi"][fill_ids], f_new)
+            ex["idx"] = jnp.where(rows_r >= n_act, repl, idx)
+            ex["cursor"] = ex["cursor"] + take
+            ex["epochs"] = ex["epochs"] + jnp.int32(1)
         return s, ex
 
     def run(state, idx, cursor, epochs, bufs, cov, srch, tabs,
@@ -2565,14 +2549,15 @@ def _fused_hunt(eng: DeviceEngine, mesh: Mesh, scfg, *, w: int,
 
         def post_chunk(s, ex, act0, any_bug, n_active, i):
             if cov_on:
-                hits, first = ex["cov"]
-                fmask = (act0 & ~s.active & (ex["idx"] >= 0)
-                         & (ex["idx"] < n_real))
-                hits, first = fold_retired_local(hits, first, s.metrics,
-                                                 fmask, ex["idx"])
-                ex = dict(ex, cov=(hits, first))
-                ex["cov_hist"] = jax.lax.dynamic_update_index_in_dim(
-                    ex["cov_hist"], distinct_count(hits), i, 0)
+                with jax.named_scope("madsim/coverage_fold"):
+                    hits, first = ex["cov"]
+                    fmask = (act0 & ~s.active & (ex["idx"] >= 0)
+                             & (ex["idx"] < n_real))
+                    hits, first = fold_retired_local(hits, first, s.metrics,
+                                                     fmask, ex["idx"])
+                    ex = dict(ex, cov=(hits, first))
+                    ex["cov_hist"] = jax.lax.dynamic_update_index_in_dim(
+                        ex["cov_hist"], distinct_count(hits), i, 0)
             # The serial loop's exact decision order: hunt-over checks
             # first (a bug under stop_on_bug, or nothing active with a
             # dry cursor), THEN the refill trigger — a stop never
@@ -2602,22 +2587,23 @@ def _fused_hunt(eng: DeviceEngine, mesh: Mesh, scfg, *, w: int,
         # is one buffer slice. Later dispatches overwrite with newer
         # values; retire-time scatters of refilled slots already moved
         # their idx, so no double attribution is possible.
-        live_tgt = jnp.where(ex["idx"] >= 0, ex["idx"], dump)
-        obs = eng.observe_device(state)
-        bufs = {k: ex["bufs"][k].at[live_tgt].set(obs[k])
-                for k in ex["bufs"]}
-        cov_out = ex["cov"] if cov_on else ()
-        ch_out = ex["cov_hist"] if cov_on else ()
-        srch_out = ()
-        stats_out = ex["stats"] if search_on else ()
-        if search_on:
-            sched_buf = ex["sched_buf"].at[live_tgt].set(ex["sched"])
-            srch_out = (ex["sched"], ex["corpus"], sched_buf)
-            if lineage_on:
-                lin_buf = jax.tree.map(
-                    lambda b, v: b.at[live_tgt].set(v), ex["lin_buf"],
-                    ex["lin"])
-                srch_out = srch_out + (ex["lin"], ex["op_tab"], lin_buf)
+        with jax.named_scope("madsim/park_live"):
+            live_tgt = jnp.where(ex["idx"] >= 0, ex["idx"], dump)
+            obs = eng.observe_device(state)
+            bufs = {k: ex["bufs"][k].at[live_tgt].set(obs[k])
+                    for k in ex["bufs"]}
+            cov_out = ex["cov"] if cov_on else ()
+            ch_out = ex["cov_hist"] if cov_on else ()
+            srch_out = ()
+            stats_out = ex["stats"] if search_on else ()
+            if search_on:
+                sched_buf = ex["sched_buf"].at[live_tgt].set(ex["sched"])
+                srch_out = (ex["sched"], ex["corpus"], sched_buf)
+                if lineage_on:
+                    lin_buf = jax.tree.map(
+                        lambda b, v: b.at[live_tgt].set(v), ex["lin_buf"],
+                        ex["lin"])
+                    srch_out = srch_out + (ex["lin"], ex["op_tab"], lin_buf)
         return (state, ex["idx"], ex["cursor"], ex["epochs"], bufs,
                 cov_out, srch_out, any_bug, n_active, k_done, hist,
                 ch_out, stats_out)
@@ -2744,6 +2730,7 @@ class SweepSession:
         return hashlib.sha256(
             np.ascontiguousarray(fp).tobytes()).hexdigest()
 
+    @_obsy.spanned("madsim:sweep")
     def run_group(self, parts: List[Dict[str, Any]],
                   observe: Any = None) -> List[SweepResult]:
         """Advance several seed ranges as one standing device batch;
@@ -2759,21 +2746,15 @@ class SweepSession:
         fleet worker's heartbeat (and therefore every chaos preemption
         point) ride the grouped loop at the same cadence.
         """
-        from time import perf_counter
-
-        from ..obs import observatory as _obsy
         from ..obs.coverage import (
             DEFAULT_BUCKETS,
             coverage_from_device,
             ledger_zeros,
         )
 
-        def _clk() -> float:
-            # Loop wall telemetry only; never feeds a sim decision.
-            return perf_counter()  # detlint: allow[DET001]
-
         if not parts:
             raise ValueError("run_group needs at least one range")
+        tr = _obsy.LoopTracer(_LOOP_SECONDS)
         eng, mesh = self.engine, self.mesh
         n_dev = mesh.devices.size
         chunk_steps, superstep_max = self.chunk_steps, self.superstep_max
@@ -2782,74 +2763,77 @@ class SweepSession:
                  else DEFAULT_BUCKETS)
 
         # -- combine ranges into one batch --------------------------------
-        seeds_list: List[np.ndarray] = []
-        faults_list: List[Optional[np.ndarray]] = []
-        for p in parts:
-            s = np.asarray(p["seeds"], np.uint64)
-            if s.shape[0] == 0:
-                raise ValueError("run_group ranges must be non-empty")
-            f = p.get("faults")
-            if f is not None:
-                f = np.asarray(f, np.int32)
-                if f.ndim not in (2, 3) or f.shape[-1] != 4:
-                    raise ValueError(
-                        f"range fault schedules must be (F, 4) or "
-                        f"(n_i, F, 4); got shape {f.shape}")
-                if f.ndim == 3 and f.shape[0] != s.shape[0]:
-                    raise ValueError(
-                        f"per-world schedules carry one (F, 4) block per "
-                        f"seed: got leading dim {f.shape[0]} for "
-                        f"{s.shape[0]} seeds")
-            seeds_list.append(s)
-            faults_list.append(f)
-        forms = {(None if f is None else f.ndim) for f in faults_list}
-        if len(forms) > 1:
-            raise ValueError(
-                "run_group ranges must agree on the faults form "
-                "(all None, all shared (F, 4), or all per-world)")
-        form = forms.pop()
+        with tr.span("madsim:prepare", "prepare_s"):
+            seeds_list: List[np.ndarray] = []
+            faults_list: List[Optional[np.ndarray]] = []
+            for p in parts:
+                s = np.asarray(p["seeds"], np.uint64)
+                if s.shape[0] == 0:
+                    raise ValueError("run_group ranges must be non-empty")
+                f = p.get("faults")
+                if f is not None:
+                    f = np.asarray(f, np.int32)
+                    if f.ndim not in (2, 3) or f.shape[-1] != 4:
+                        raise ValueError(
+                            f"range fault schedules must be (F, 4) or "
+                            f"(n_i, F, 4); got shape {f.shape}")
+                    if f.ndim == 3 and f.shape[0] != s.shape[0]:
+                        raise ValueError(
+                            f"per-world schedules carry one (F, 4) block "
+                            f"per seed: got leading dim {f.shape[0]} for "
+                            f"{s.shape[0]} seeds")
+                seeds_list.append(s)
+                faults_list.append(f)
+            forms = {(None if f is None else f.ndim) for f in faults_list}
+            if len(forms) > 1:
+                raise ValueError(
+                    "run_group ranges must agree on the faults form "
+                    "(all None, all shared (F, 4), or all per-world)")
+            form = forms.pop()
 
-        n_list = [int(s.shape[0]) for s in seeds_list]
-        offs = np.concatenate([[0], np.cumsum(n_list)]).astype(int)
-        n_tot = int(offs[-1])
-        w = n_tot + ((-n_tot) % n_dev)
-        seeds_c = np.concatenate(seeds_list)
-        if w > n_tot:  # mesh padding: dummy worlds, sliced off below
-            seeds_c = np.concatenate([seeds_c, seeds_c[:1].repeat(w - n_tot)])
-        if form is None:
-            faults_init = None
-        elif form == 2:
-            faults_init = faults_list[0]
-            for f in faults_list[1:]:
-                if not np.array_equal(f, faults_init):
-                    raise ValueError(
-                        "shared (F, 4) templates must be identical "
-                        "across grouped ranges")
-        else:
-            faults_init = np.concatenate(faults_list, axis=0)
-            if w > n_tot:
-                faults_init = np.concatenate(
-                    [faults_init, faults_init[:1].repeat(w - n_tot, axis=0)],
-                    axis=0)
+            n_list = [int(s.shape[0]) for s in seeds_list]
+            offs = np.concatenate([[0], np.cumsum(n_list)]).astype(int)
+            n_tot = int(offs[-1])
+            w = n_tot + ((-n_tot) % n_dev)
+            seeds_c = np.concatenate(seeds_list)
+            if w > n_tot:  # mesh padding: dummy worlds, sliced off below
+                seeds_c = np.concatenate(
+                    [seeds_c, seeds_c[:1].repeat(w - n_tot)])
+            if form is None:
+                faults_init = None
+            elif form == 2:
+                faults_init = faults_list[0]
+                for f in faults_list[1:]:
+                    if not np.array_equal(f, faults_init):
+                        raise ValueError(
+                            "shared (F, 4) templates must be identical "
+                            "across grouped ranges")
+            else:
+                faults_init = np.concatenate(faults_list, axis=0)
+                if w > n_tot:
+                    faults_init = np.concatenate(
+                        [faults_init,
+                         faults_init[:1].repeat(w - n_tot, axis=0)], axis=0)
 
         # -- install: recycle the standing slots, else fresh init ---------
-        reused = self._slot_state is not None and self._slot_w == w
-        if reused:
-            prev_state, self._slot_state = self._slot_state, None
-            state = shard_worlds(
-                eng.refill(prev_state, np.ones(w, bool), seeds_c,
-                           faults=faults_init), mesh)
-        else:
-            self._slot_state = None
-            state = shard_worlds(eng.init(seeds_c, faults=faults_init), mesh)
+        with tr.span("madsim:init", "init_s"):
+            reused = self._slot_state is not None and self._slot_w == w
+            if reused:
+                prev_state, self._slot_state = self._slot_state, None
+                state = shard_worlds(
+                    eng.refill(prev_state, np.ones(w, bool), seeds_c,
+                               faults=faults_init), mesh)
+            else:
+                self._slot_state = None
+                state = shard_worlds(
+                    eng.init(seeds_c, faults=faults_init), mesh)
         first = self._runs == 0
         self._runs += 1
         self.reuse_hits += len(parts) - (1 if first else 0)
 
         emit_telemetry, close_telemetry = _obsy.make_observer(observe)
-        t_loop0 = _clk()
-        perf = {"dispatches": 0, "scalar_fetches": 0, "device_wait_s": 0.0,
-                "dispatch_s": 0.0, "dispatch_depth": 0}
+        t_loop0 = tr.clock()
+        dispatch_depth = 0
 
         # -- pipelined dispatch-ahead loop (the solo loop, minus the
         # refill/shrink/search edges grouped mode never takes) ------------
@@ -2870,11 +2854,9 @@ class SweepSession:
                 eng, mesh, chunk_steps, superstep_max, donate=True,
                 min_one=epoch_fresh, coverage=None)
             epoch_fresh = False
-            t0 = _clk()
-            state, any_bug, n_active, k_done, hist = runner(
-                state, jnp.int32(0), jnp.asarray(False), jnp.int32(k))
-            perf["dispatch_s"] += _clk() - t0
-            perf["dispatches"] += 1
+            with tr.span("madsim:superstep", "dispatch_s"):
+                state, any_bug, n_active, k_done, hist = runner(
+                    state, jnp.int32(0), jnp.asarray(False), jnp.int32(k))
             inflight = _Flight(any_bug, n_active, k_done, hist, k, w, 0, None)
 
         try:
@@ -2884,24 +2866,24 @@ class SweepSession:
                 prev, inflight = inflight, None
                 if not stop and chunks + prev.planned < c_max:
                     dispatch(reserve=prev.planned)
-                t0 = _clk()
-                bug_h, n_act_h, k_done_h, _hist_h = _fetch(
-                    (prev.any_bug, prev.n_active, prev.k_done, prev.hist))
-                perf["device_wait_s"] += _clk() - t0
-                perf["scalar_fetches"] += 1
-                perf["dispatch_depth"] = max(
-                    perf["dispatch_depth"], 1 if inflight is not None else 0)
-                k_done = int(k_done_h)
-                n_act = int(n_act_h)
-                chunks += k_done
-                if k_done == prev.planned:
-                    k_cur = min(k_cur * 2, superstep_max)
-                else:
-                    k_cur = max(k_done, 1)
-                if not stop and n_act == 0:
-                    stop = True
+                with tr.span("madsim:wait", "device_wait_s"):
+                    bug_h, n_act_h, k_done_h, _hist_h = _fetch(
+                        (prev.any_bug, prev.n_active, prev.k_done,
+                         prev.hist))
+                dispatch_depth = max(dispatch_depth,
+                                     1 if inflight is not None else 0)
+                with tr.span("madsim:decide", "host_decision_s"):
+                    k_done = int(k_done_h)
+                    n_act = int(n_act_h)
+                    chunks += k_done
+                    if k_done == prev.planned:
+                        k_cur = min(k_cur * 2, superstep_max)
+                    else:
+                        k_cur = max(k_done, 1)
+                    if not stop and n_act == 0:
+                        stop = True
                 if emit_telemetry is not None:
-                    elapsed = _clk() - t_loop0
+                    elapsed = tr.clock() - t_loop0
                     done = max(n_tot - n_act, 0)
                     emit_telemetry({
                         "schema": "madsim.sweep.telemetry/1",
@@ -2955,52 +2937,55 @@ class SweepSession:
                 hits, first = folder(state, hits, first, idx_r, n_real,
                                      jnp.asarray(True))
                 ledgers.append((hits, first))
-            ledgers_h = _fetch(ledgers)
-        obs_all = eng.observe(state)
-
-        self._slot_state = state
-        self._slot_w = w
-        self._k_warm = k_cur
-
-        steps = chunks * chunk_steps
-        issued = w * chunk_steps * chunks
-        live_steps = int(np.asarray(obs_all["steps"])[:n_tot].sum())
-        util = live_steps / issued if issued else 0.0
-        loop_stats_base = {
-            "pipelined": True,
-            "session": True,
-            "session_group": len(parts),
-            "session_reused_slots": bool(reused),
-            "superstep_max": int(superstep_max),
-            "chunk_steps": int(chunk_steps),
-            "chunks": int(chunks),
-            "dispatches": int(perf["dispatches"]),
-            "chunks_per_dispatch": round(
-                chunks / max(perf["dispatches"], 1), 3),
-            "dispatch_depth": int(perf["dispatch_depth"]),
-            "device_wait_s": round(perf["device_wait_s"], 6),
-            "dispatch_s": round(perf["dispatch_s"], 6),
-            "scalar_fetches": int(perf["scalar_fetches"]),
-            "loop_wall_s": round(_clk() - t_loop0, 6),
-        }
-
-        results: List[SweepResult] = []
-        for i, (s, f) in enumerate(zip(seeds_list, faults_list)):
-            lo, hi = int(offs[i]), int(offs[i + 1])
-            obs = {k: np.asarray(v)[lo:hi] for k, v in obs_all.items()}
-            coverage = None
+        with tr.span("madsim:pull", "retire_wait_s"):
             if cov_on:
-                hits_h, first_h = ledgers_h[i]
-                coverage = coverage_from_device(
-                    cov_k, np.asarray(hits_h), np.asarray(first_h), [])
-            results.append(SweepResult(
-                seeds=s, bug=obs["bug"], observations=obs,
-                steps_run=steps, n_devices=n_dev,
-                world_utilization=util,
-                loop_stats=dict(loop_stats_base),
-                faults_sha256=self._part_sha256(f),
-                coverage=coverage,
-                triage_ctx=TriageContext(engine=eng, faults=f, mesh=mesh)))
+                ledgers_h = _fetch(ledgers)
+            obs_all = eng.observe(state)
+
+        with tr.span("madsim:assemble", "assemble_s"):
+            self._slot_state = state
+            self._slot_w = w
+            self._k_warm = k_cur
+
+            steps = chunks * chunk_steps
+            issued = w * chunk_steps * chunks
+            live_steps = int(np.asarray(obs_all["steps"])[:n_tot].sum())
+            util = live_steps / issued if issued else 0.0
+            loop_stats_base = {
+                "pipelined": True,
+                "session": True,
+                "session_group": len(parts),
+                "session_reused_slots": bool(reused),
+                "superstep_max": int(superstep_max),
+                "chunk_steps": int(chunk_steps),
+                "chunks": int(chunks),
+                "dispatches": tr.entered["madsim:superstep"],
+                "chunks_per_dispatch": round(
+                    chunks / max(tr.entered["madsim:superstep"], 1), 3),
+                "dispatch_depth": dispatch_depth,
+                "scalar_fetches": tr.entered["madsim:wait"],
+                "loop_wall_s": round(tr.clock() - t_loop0, 6),
+            }
+
+            results: List[SweepResult] = []
+            for i, (s, f) in enumerate(zip(seeds_list, faults_list)):
+                lo, hi = int(offs[i]), int(offs[i + 1])
+                obs = {k: np.asarray(v)[lo:hi] for k, v in obs_all.items()}
+                coverage = None
+                if cov_on:
+                    hits_h, first_h = ledgers_h[i]
+                    coverage = coverage_from_device(
+                        cov_k, np.asarray(hits_h), np.asarray(first_h), [])
+                results.append(SweepResult(
+                    seeds=s, bug=obs["bug"], observations=obs,
+                    steps_run=steps, n_devices=n_dev,
+                    world_utilization=util,
+                    loop_stats=dict(loop_stats_base),
+                    faults_sha256=self._part_sha256(f),
+                    coverage=coverage,
+                    triage_ctx=TriageContext(eng, f, mesh)))
+        for res in results:
+            res.loop_stats.update(tr.seconds())
         if close_telemetry is not None:
             close_telemetry()
         return results

@@ -317,7 +317,8 @@ def test_fused_loop_stats_schema(raft_eng):
                   "epochs_on_device", "pipelined", "fused",
                   "superstep_max", "chunk_steps", "chunks", "dispatches",
                   "chunks_per_dispatch", "dispatch_s", "retire_wait_s",
-                  "loop_wall_s"}
+                  "loop_wall_s", "prepare_s", "init_s", "upload_s",
+                  "assemble_s"}
     assert documented <= set(ls), sorted(ls)
     assert ls["fused"] is True and ls["pipelined"] is False
     assert isinstance(ls["seeds_per_dispatch"], float)

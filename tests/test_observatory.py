@@ -319,6 +319,81 @@ def test_profile_dir_captures_and_stays_invisible(eng_off, tmp_path):
     assert plain.loop_stats["dispatches"] == prof.loop_stats["dispatches"]
 
 
+# The host spans of one call (docs/observability.md "Loop spans"): the
+# phases every sweep() loop has, plus the dispatch span each loop names.
+_PHASES = {"madsim:prepare", "madsim:init", "madsim:wait", "madsim:decide",
+           "madsim:pull", "madsim:assemble"}
+_LOOPS = {
+    "serial": ({"pipeline": False}, {"madsim:upload", "madsim:chunk"}),
+    "pipelined": ({}, {"madsim:upload", "madsim:superstep"}),
+    "fused": ({"fused": True, "recycle": True, "batch_worlds": 8},
+              {"madsim:upload", "madsim:fused_hunt"}),
+    # SweepSession.run_group installs a standing batch: no upload phase.
+    "run_group": (None, {"madsim:superstep"}),
+}
+_NEW_SECONDS = ("prepare_s", "init_s", "upload_s", "assemble_s")
+
+
+def _madsim_spans(trace_dir):
+    """(line, start_ns, end_ns, name) of every ``madsim:*`` host span in
+    the capture under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            out += [(line.name, int(e.start_ns),
+                     int(e.start_ns + e.duration_ns), e.name)
+                    for e in line.events if e.name.startswith("madsim:")]
+    return out
+
+
+@pytest.mark.parametrize("loop", sorted(_LOOPS))
+def test_loop_spans_nest_in_the_call_on_the_host_plane(eng_off, tmp_path,
+                                                       loop):
+    """Every phase span of one call is on the host plane of a capture
+    started outside the sweep, inside that call's ``madsim:sweep`` span,
+    on the calling thread; sibling spans do not overlap; each loop_stats
+    seconds key they feed is a non-negative float."""
+    import jax
+
+    from madsim_tpu.parallel.sweep import SweepSession
+
+    kw, own = _LOOPS[loop]
+    pdir = str(tmp_path / "trace")
+    with jax.profiler.trace(pdir):
+        if kw is None:
+            sess = SweepSession(engine=eng_off, chunk_steps=64,
+                                max_steps=2_048)
+            results = sess.run_group([{"seeds": np.arange(8)},
+                                      {"seeds": np.arange(8, 20)}])
+        else:
+            results = [sweep(None, eng_off.cfg, np.arange(24),
+                             engine=eng_off, chunk_steps=64,
+                             max_steps=2_048, **kw)]
+    spans = _madsim_spans(pdir)
+    roots = [s for s in spans if s[3] == "madsim:sweep"]
+    assert len(roots) == 1, roots
+    line, lo, hi, _ = roots[0]
+    children = sorted(s for s in spans if s[3] != "madsim:sweep")
+    assert {s[3] for s in children} == _PHASES | own
+    for s in children:
+        assert s[0] == line and lo <= s[1] <= s[2] <= hi, s
+    for a, b in zip(children, children[1:]):
+        assert a[2] <= b[1], (a, b)
+    for res in results:
+        ls = res.loop_stats
+        for key in _NEW_SECONDS + ("dispatch_s", "device_wait_s",
+                                   "host_decision_s", "retire_wait_s"):
+            assert isinstance(ls[key], float) and ls[key] >= 0.0, key
+
+
 def test_profile_window_validation(eng_off, tmp_path):
     with pytest.raises(ValueError, match="profile_window"):
         sweep(None, eng_off.cfg, np.arange(8), engine=eng_off,

@@ -298,7 +298,8 @@ def test_loop_stats_schema_both_paths(raft_eng, pipeline):
                   "seeds_per_dispatch", "epochs_on_device", "fused",
                   "pipelined", "superstep_max", "chunk_steps", "chunks",
                   "dispatches", "chunks_per_dispatch", "dispatch_s",
-                  "retire_wait_s", "loop_wall_s"}
+                  "retire_wait_s", "loop_wall_s",
+                  "prepare_s", "init_s", "upload_s", "assemble_s"}
     assert documented <= set(ls), sorted(ls)
     assert ls["pipelined"] is pipeline
     assert ls["fused"] is False
@@ -306,7 +307,8 @@ def test_loop_stats_schema_both_paths(raft_eng, pipeline):
     assert ls["seeds_per_dispatch"] == pytest.approx(
         48 / ls["dispatches"], abs=1e-3)
     for key in ("device_wait_s", "host_decision_s", "dispatch_s",
-                "retire_wait_s", "loop_wall_s"):
+                "retire_wait_s", "loop_wall_s", "prepare_s", "init_s",
+                "upload_s", "assemble_s"):
         assert isinstance(ls[key], float) and ls[key] >= 0.0, key
     for key in ("scalar_fetches", "retire_fetches", "dispatch_depth",
                 "chunks", "dispatches", "superstep_max", "chunk_steps"):
