@@ -220,7 +220,7 @@ def phase_multichip(w=HEADLINE_W):
         first, res, compile_s, run_s = _twice(
             lambda: sweep(None, eng.cfg, seeds, engine=eng, mesh=mesh))
         # Where the worlds live: one sharded chunk from a fresh batch.
-        state, _bug, _n = sharded_engine(eng, mesh, chunk_steps=8)(
+        state, _bug, _n, _steps = sharded_engine(eng, mesh, chunk_steps=8)(
             shard_worlds(eng.init(seeds), mesh))
         shards = state.now.addressable_shards
         per_device = sorted(s.data.shape[0] for s in shards)

@@ -86,6 +86,11 @@ from .rng import (
 # Device-engine RNG stream id (host streams occupy 0..3, see core/rng.py).
 STREAM_DEVICE = 16
 
+# Steps per block of a chunk: a chunk checks its shard for a live world
+# before each block and ends at the first check that finds none
+# (docs/perf.md "Chunk exit on frozen shards").
+EXIT_BLOCK = 16
+
 # Fault-injection ops (event kind when FLAG_FAULT is set). The analogs of
 # Handle::kill/restart (`runtime/mod.rs:241-258`) and NetSim::clog_node /
 # clog_link (`net/mod.rs:147-170`, `network.rs:159-190`).
@@ -960,16 +965,57 @@ class DeviceEngine:
     # Batched run loops
     # ------------------------------------------------------------------
     def _run_steps_impl(self, state: WorldState, k: int) -> WorldState:
+        """``k`` masked steps, as blocks of :data:`EXIT_BLOCK` steps that
+        stop at the first block boundary where no world of the batch is
+        live. The step is the identity on a frozen world, so the blocks
+        left out change no leaf: the result equals ``k`` steps bitwise.
+        ``k <= EXIT_BLOCK`` is the plain scan. Inside ``shard_map`` the
+        check sees its own shard only and adds no collective."""
         batched = self._batched_step
 
-        def body(s, _):
-            return batched(s), None
+        def scan(s, n):
+            s, _ = jax.lax.scan(lambda c, _: (batched(c), None), s, None,
+                                length=n)
+            return s
 
-        state, _ = jax.lax.scan(body, state, None, length=k)
+        if k <= EXIT_BLOCK:
+            return scan(state, k)
+        n_blocks, rem = divmod(k, EXIT_BLOCK)
+
+        def cond(carry):
+            s, i = carry
+            return (i < n_blocks) & jnp.any(s.active)
+
+        def body(carry):
+            s, i = carry
+            return scan(s, EXIT_BLOCK), i + 1
+
+        state, _ = jax.lax.while_loop(cond, body, (state, jnp.int32(0)))
+        if rem:
+            state = jax.lax.cond(jnp.any(state.active),
+                                 lambda s: scan(s, rem), lambda s: s, state)
         return state
 
+    @staticmethod
+    def _steps_executed(steps0, state: WorldState, k: int):
+        """Steps :meth:`_run_steps_impl` executed on a batch whose per-world
+        ``steps`` counters read ``steps0`` before the call: int32 scalar.
+
+        A world's counter advances on exactly the steps that start with it
+        live, and a block runs iff some world is live at its start. So with
+        M the most steps any world took, the loop ran ⌈M / EXIT_BLOCK⌉
+        blocks, and the remainder only after all of them (then M exceeds
+        the whole blocks' steps and the count is ``k``)."""
+        if k <= EXIT_BLOCK:
+            return jnp.int32(k)
+        most = jnp.max(state.steps - steps0, initial=0)
+        blocks = (most + (EXIT_BLOCK - 1)) // EXIT_BLOCK
+        return jnp.minimum(blocks * EXIT_BLOCK, k).astype(jnp.int32)
+
     def run_steps(self, state: WorldState, k: int) -> WorldState:
-        """Advance every world by exactly ``k`` masked steps (fixed cost).
+        """Advance every world by ``k`` masked steps. The device stops
+        early, at a 16-step block boundary, once no world is live: the
+        steps it leaves out would change nothing.
 
         ``state`` is **donated**: its buffers are updated in place and the
         passed-in pytree is dead after the call — rebind
@@ -1014,9 +1060,12 @@ class DeviceEngine:
         ``reduce_sum`` reduces a per-shard int32 scalar over the world
         axis — ``lax.psum`` inside a shard_mapped sweep, ``jnp.sum``'s
         identity under plain vmap use. Returns ``(state, any_bug,
-        n_active, k_done, hist)`` where ``hist[j]`` is the active count
-        measured after chunk ``j`` (-1 for chunks not run), exactly the
-        per-chunk sequence the serial loop observed.
+        n_active, k_done, hist, shard_steps)`` where ``hist[j]`` is the
+        active count measured after chunk ``j`` (-1 for chunks not run),
+        exactly the per-chunk sequence the serial loop observed, and
+        ``shard_steps`` is the steps each shard's chunks executed
+        (:meth:`_steps_executed`), summed over shards: times the shard
+        width, the slot-steps the device ran.
 
         ``cov``/``cov_fold`` (obs/coverage.py, set together or not at
         all): the retire-time coverage fold. ``cov`` is the behavior
@@ -1028,8 +1077,8 @@ class DeviceEngine:
         loop's because both execute identical chunk bodies. Purely
         read-only over the simulation state (the bitwise-invisibility
         contract of ``MetricsBlock`` extends to it). With coverage on
-        the return grows to ``(..., hist, cov, cov_hist)`` where
-        ``cov_hist[j]`` is the cumulative distinct-behavior count after
+        the return grows to ``(..., hist, cov, cov_hist, shard_steps)``
+        where ``cov_hist[j]`` is the cumulative distinct-behavior count after
         chunk ``j`` (-1 beyond ``k_done``) — the novelty curve sampled
         at exactly the ``hist`` cadence.
         """
@@ -1053,7 +1102,7 @@ class DeviceEngine:
         cov_hist0 = jnp.full((k_max,), -1, jnp.int32) if with_cov else None
 
         def cond(carry):
-            _s, i, any_bug, n_active, _hist, _cov, _ch = carry
+            _s, i, any_bug, n_active, _hist, _cov, _ch, _ran = carry
             run_more = ((n_active > stop_threshold)
                         & ~(stop_on_bug & any_bug))
             if min_one:
@@ -1061,25 +1110,28 @@ class DeviceEngine:
             return (i < k_chunks) & run_more
 
         def body(carry):
-            s, i, _any_bug, _n_active, hist, cv, ch = carry
-            act0 = s.active
+            s, i, _any_bug, _n_active, hist, cv, ch, ran = carry
+            act0, steps0 = s.active, s.steps
             s = self._run_steps_impl(s, chunk_steps)
+            ran = ran + self._steps_executed(steps0, s, chunk_steps)
             any_bug, n_active = measure(s)
             hist = jax.lax.dynamic_update_index_in_dim(hist, n_active, i, 0)
             if with_cov:
                 cv = cov_fold(cv, act0, s)
                 ch = jax.lax.dynamic_update_index_in_dim(
                     ch, distinct_count(cv[0]), i, 0)
-            return s, i + 1, any_bug, n_active, hist, cv, ch
+            return s, i + 1, any_bug, n_active, hist, cv, ch, ran
 
-        state, k_done, any_bug, n_active, hist, cov, cov_hist = \
+        state, k_done, any_bug, n_active, hist, cov, cov_hist, ran = \
             jax.lax.while_loop(
                 cond, body,
                 (state, jnp.int32(0), any_bug0, n_active0, hist0,
-                 cov, cov_hist0))
+                 cov, cov_hist0, jnp.int32(0)))
+        shard_steps = reduce_sum(ran)
         if with_cov:
-            return state, any_bug, n_active, k_done, hist, cov, cov_hist
-        return state, any_bug, n_active, k_done, hist
+            return (state, any_bug, n_active, k_done, hist, cov, cov_hist,
+                    shard_steps)
+        return state, any_bug, n_active, k_done, hist, shard_steps
 
     def _fused_superstep_impl(self, state: WorldState, extras, stop_on_bug,
                               k_chunks, *, chunk_steps: int, k_max: int,

@@ -76,12 +76,15 @@ def _cov_reducers(mesh: Mesh):
 def sharded_engine(eng: DeviceEngine, mesh: Mesh, chunk_steps: int = 512,
                    donate: bool = False,
                    coverage: Optional[int] = None):
-    """Compile a chunk runner: state → (state, any_bug, n_active).
+    """Compile a chunk runner: state → (state, any_bug, n_active,
+    shard_steps).
 
     The body is `shard_map`'d so each device advances only its world shard
-    (no resharding possible); the two scalar outputs are psum/any reductions
+    (no resharding possible); the scalar outputs are psum/any reductions
     over ALL mesh axes — ICI within a host, DCN across hosts on a 2-D
     ``multihost_mesh`` — the only cross-chip communication in a sweep.
+    ``shard_steps`` is the steps each shard executed before its worlds
+    all froze (``DeviceEngine._steps_executed``), summed over shards.
 
     ``donate=True`` donates the input state: XLA updates the sharded
     batch in place instead of double-buffering it, which roughly doubles
@@ -93,11 +96,11 @@ def sharded_engine(eng: DeviceEngine, mesh: Mesh, chunk_steps: int = 512,
     ``coverage`` (bucket count, or None): the retire-time behavior fold
     (obs/coverage.py). The runner signature widens to
     ``(state, hits, first_seen, idx, n_real) → (state, any_bug,
-    n_active, hits, first_seen, distinct)``: after the chunk body, the
-    worlds whose active flag fell during the chunk scatter their
-    behavior signatures into the replicated K-bucket ledger (psum/pmin
-    over the mesh — the only additions; the chunk body itself is
-    untouched, so trajectories stay bitwise identical and with
+    n_active, hits, first_seen, distinct, shard_steps)``: after the
+    chunk body, the worlds whose active flag fell during the chunk
+    scatter their behavior signatures into the replicated K-bucket
+    ledger (psum/pmin over the mesh — the only additions; the chunk body
+    itself is untouched, so trajectories stay bitwise identical and with
     ``coverage=None`` this compiles the exact pre-coverage program).
 
     Runners are cached per (mesh, chunk_steps, donate, coverage) on the
@@ -112,16 +115,20 @@ def sharded_engine(eng: DeviceEngine, mesh: Mesh, chunk_steps: int = 512,
     axes = tuple(mesh.axis_names)
     sp = scalar_spec()
 
-    if coverage is None:
-        def chunk(state: WorldState):
-            state = eng._run_steps_impl(state, chunk_steps)
-            any_bug = jax.lax.psum(
-                jnp.any(state.bug).astype(jnp.int32), axes) > 0
-            n_active = jax.lax.psum(
-                jnp.sum(state.active, dtype=jnp.int32), axes)
-            return state, any_bug, n_active
+    def run(state: WorldState):
+        steps0 = state.steps
+        state = eng._run_steps_impl(state, chunk_steps)
+        any_bug = jax.lax.psum(
+            jnp.any(state.bug).astype(jnp.int32), axes) > 0
+        n_active = jax.lax.psum(
+            jnp.sum(state.active, dtype=jnp.int32), axes)
+        shard_steps = jax.lax.psum(
+            eng._steps_executed(steps0, state, chunk_steps), axes)
+        return state, any_bug, n_active, shard_steps
 
-        in_specs, out_specs = (spec,), (spec, sp, sp)
+    if coverage is None:
+        chunk = run
+        in_specs, out_specs = (spec,), (spec, sp, sp, sp)
     else:
         from ..obs.coverage import distinct_count, fold_retired
 
@@ -129,19 +136,15 @@ def sharded_engine(eng: DeviceEngine, mesh: Mesh, chunk_steps: int = 512,
 
         def chunk(state: WorldState, hits, first, idx, n_real):
             act0 = state.active
-            state = eng._run_steps_impl(state, chunk_steps)
-            any_bug = jax.lax.psum(
-                jnp.any(state.bug).astype(jnp.int32), axes) > 0
-            n_active = jax.lax.psum(
-                jnp.sum(state.active, dtype=jnp.int32), axes)
+            state, any_bug, n_active, shard_steps = run(state)
             mask = act0 & ~state.active & (idx >= 0) & (idx < n_real)
             hits, first = fold_retired(hits, first, state.metrics, mask,
                                        idx, rsum, rmin)
             return state, any_bug, n_active, hits, first, \
-                distinct_count(hits)
+                distinct_count(hits), shard_steps
 
         in_specs = (spec, sp, sp, spec, sp)
-        out_specs = (spec, sp, sp, sp, sp, sp)
+        out_specs = (spec, sp, sp, sp, sp, sp, sp)
 
     mapped = shard_map(chunk, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
@@ -156,7 +159,8 @@ def sharded_superstep(eng: DeviceEngine, mesh: Mesh, chunk_steps: int,
                       coverage: Optional[int] = None):
     """Compile a superstep runner:
     ``(state, stop_threshold, stop_on_bug, k_chunks) → (state, any_bug,
-    n_active, k_done, hist)``.
+    n_active, k_done, hist, shard_steps)`` (``shard_steps`` as in
+    :func:`sharded_engine`, over every chunk the superstep ran).
 
     The superstep folds up to ``k_chunks`` chunk bodies into ONE jitted
     dispatch (`DeviceEngine._superstep_impl`): a ``lax.while_loop`` whose
@@ -181,8 +185,8 @@ def sharded_superstep(eng: DeviceEngine, mesh: Mesh, chunk_steps: int,
     behavior ledger (obs/coverage.py) through the on-device chunk loop:
     the runner widens to ``(state, hits, first_seen, idx, n_real,
     stop_threshold, stop_on_bug, k_chunks) → (state, any_bug, n_active,
-    k_done, hist, hits, first_seen, cov_hist)``, where ``cov_hist[j]``
-    is the cumulative distinct-behavior count after chunk ``j`` — the
+    k_done, hist, hits, first_seen, cov_hist, shard_steps)``, where
+    ``cov_hist[j]`` is the cumulative distinct-behavior count after chunk ``j`` — the
     novelty curve at exactly the ``hist`` cadence, riding the SAME
     scalar fetch (zero extra device→host syncs). A pass-through
     superstep (entry condition already false) folds nothing, which is
@@ -206,7 +210,7 @@ def sharded_superstep(eng: DeviceEngine, mesh: Mesh, chunk_steps: int,
                 reduce_sum=rsum, min_one=min_one)
 
         in_specs = (spec, sp, sp, sp)
-        out_specs = (spec, sp, sp, sp, sp)
+        out_specs = (spec, sp, sp, sp, sp, sp)
     else:
         from ..obs.coverage import fold_retired
 
@@ -219,16 +223,17 @@ def sharded_superstep(eng: DeviceEngine, mesh: Mesh, chunk_steps: int,
                 mask = act0 & ~s.active & (idx >= 0) & (idx < n_real)
                 return fold_retired(h, f, s.metrics, mask, idx, rsum, rmin)
 
-            state, any_bug, n_active, k_done, hist, (hits, first), ch = \
-                eng._superstep_impl(
-                    state, stop_threshold, stop_on_bug, k_chunks,
-                    chunk_steps=chunk_steps, k_max=k_max,
-                    reduce_sum=rsum, min_one=min_one,
-                    cov=(hits, first), cov_fold=fold)
-            return state, any_bug, n_active, k_done, hist, hits, first, ch
+            (state, any_bug, n_active, k_done, hist, (hits, first), ch,
+             ran) = eng._superstep_impl(
+                state, stop_threshold, stop_on_bug, k_chunks,
+                chunk_steps=chunk_steps, k_max=k_max,
+                reduce_sum=rsum, min_one=min_one,
+                cov=(hits, first), cov_fold=fold)
+            return (state, any_bug, n_active, k_done, hist, hits, first, ch,
+                    ran)
 
         in_specs = (spec, sp, sp, spec, sp, sp, sp, sp)
-        out_specs = (spec, sp, sp, sp, sp, sp, sp, sp)
+        out_specs = (spec, sp, sp, sp, sp, sp, sp, sp, sp)
 
     mapped = shard_map(sstep, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
@@ -303,6 +308,7 @@ class _Flight(NamedTuple):
     n_active: Any
     k_done: Any
     hist: Any
+    shard_steps: Any      # steps its chunks executed, summed over shards
     planned: int          # chunks this dispatch may run (its K)
     w: int                # batch width at dispatch time
     epoch: int            # occupancy epoch at dispatch time
@@ -432,10 +438,12 @@ class SweepResult:
     steps_run: int               # executed chunks * chunk_steps
     n_devices: int
     # Occupancy telemetry (docs/perf.md "world recycling"): the active
-    # world count after each chunk, and the fraction of issued slot-steps
-    # that advanced a live world — useful/(sum over chunks of
-    # batch_width*chunk_steps). Frozen worlds riding masked in the batch
-    # are the difference; 1.0 means the mesh never ran a frozen slot.
+    # world count after each chunk, and the fraction of executed
+    # slot-steps that advanced a live world — useful/(sum over chunks of
+    # shard_width * the steps each shard ran before its worlds all froze;
+    # the fused path counts batch_width*chunk_steps). Frozen worlds riding
+    # masked in the batch are the difference; 1.0 means the mesh never
+    # ran a frozen slot.
     n_active_history: np.ndarray = dataclasses.field(
         default_factory=lambda: np.zeros(0, np.int64))
     world_utilization: float = 0.0
@@ -462,7 +470,7 @@ class SweepResult:
     # dispatches_per_seed, seeds_per_dispatch, epochs_on_device,
     # dispatch_depth, device_wait_s, host_decision_s, dispatch_s,
     # retire_wait_s, scalar_fetches, retire_fetches, loop_wall_s,
-    # superstep_max, chunk_steps.
+    # superstep_max, chunk_steps, slot_steps_skipped.
     loop_stats: Dict[str, Any] = dataclasses.field(default_factory=dict)
     # Fault-schedule fingerprint (sha256 over the padded rows, or of
     # b"none"): rides the result so repro banners and bundles can assert
@@ -1264,7 +1272,11 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                             for k in ("p1", "p2", "ops", "depth")))
         n_active_hist: List[int] = []
         n_active_chunk: List[int] = []     # chunk index each entry measured at
-        issued_slot_steps = 0              # sum of width*chunk_steps
+        # Slot-steps the chunks executed (the fused path counts them as
+        # planned), and those the chunk exit left out: every chunk plans
+        # width * chunk_steps.
+        issued_slot_steps = 0
+        skipped_slot_steps = 0
         live_world_steps = 0               # steps that advanced a live world
         # Counts the spans do not keep; the rest are tr.entered/tr.stats.
         perf = {"retire_fetches": 0, "dispatch_depth": 0}
@@ -1817,20 +1829,21 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                 with tr.span("madsim:superstep", "dispatch_s"):
                     if cov_on:
                         (state, any_bug, n_active, k_done, hist, cov_hits,
-                         cov_first, cov_h) = runner(
+                         cov_first, cov_h, shard_steps) = runner(
                             state, cov_hits, cov_first, idx, n_real_dev,
                             jnp.int32(threshold()),
                             jnp.asarray(bool(stop_on_first_bug)),
                             jnp.int32(k))
                     else:
                         cov_h = None
-                        state, any_bug, n_active, k_done, hist = runner(
+                        (state, any_bug, n_active, k_done, hist,
+                         shard_steps) = runner(
                             state, jnp.int32(threshold()),
                             jnp.asarray(bool(stop_on_first_bug)),
                             jnp.int32(k))
                 inflight = _Flight(
-                    any_bug, n_active, k_done, hist, k, w_cur, epoch,
-                    state if writer is not None else None, cov_h,
+                    any_bug, n_active, k_done, hist, shard_steps, k, w_cur,
+                    epoch, state if writer is not None else None, cov_h,
                     ((cov_hits, cov_first)
                      if writer is not None and cov_on else None))
 
@@ -1849,17 +1862,12 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                 if not stop and chunks + prev.planned < c_max:
                     dispatch(reserve=prev.planned)
                 with tr.span("madsim:wait", "device_wait_s"):
-                    if cov_on:
-                        # The novelty lane rides the SAME scalar batch — one
-                        # _fetch per superstep either way (tier-1-counted).
-                        bug_h, n_act_h, k_done_h, hist_h, cov_h = _fetch(
-                            (prev.any_bug, prev.n_active, prev.k_done,
-                             prev.hist, prev.cov_hist))
-                    else:
-                        cov_h = None
-                        bug_h, n_act_h, k_done_h, hist_h = _fetch(
-                            (prev.any_bug, prev.n_active, prev.k_done,
-                             prev.hist))
+                    # The executed-step count and (coverage on) the novelty
+                    # lane ride the SAME scalar batch — one _fetch per
+                    # superstep either way (tier-1-counted).
+                    bug_h, n_act_h, k_done_h, hist_h, ran_h, cov_h = _fetch(
+                        (prev.any_bug, prev.n_active, prev.k_done,
+                         prev.hist, prev.shard_steps, prev.cov_hist))
                 prof.after_read()
                 perf["dispatch_depth"] = max(
                     perf["dispatch_depth"], 1 if inflight is not None else 0)
@@ -1879,7 +1887,9 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                             novelty_hist.append(int(cov_np[j]))
                     chunks += k_done
                     steps = chunks * chunk_steps
-                    issued_slot_steps += prev.w * chunk_steps * k_done
+                    ran = prev.w // n_dev * int(ran_h)
+                    issued_slot_steps += ran
+                    skipped_slot_steps += prev.w * chunk_steps * k_done - ran
                     if prev.epoch == epoch:
                         # Superstep sizing adapts to the observed retirement
                         # rate: double while supersteps run to plan (slow
@@ -1942,14 +1952,13 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                 with tr.span("madsim:chunk", "dispatch_s"):
                     if cov_on:
                         (state, any_bug, n_active, cov_hits, cov_first,
-                         distinct) = runner(state, cov_hits, cov_first,
-                                            idx, n_real_dev)
+                         distinct, shard_steps) = runner(
+                            state, cov_hits, cov_first, idx, n_real_dev)
                     else:
                         distinct = None
-                        state, any_bug, n_active = runner(state)
+                        state, any_bug, n_active, shard_steps = runner(state)
                 steps += chunk_steps
                 chunks += 1
-                issued_slot_steps += w_cur * chunk_steps
                 if writer is not None and checkpoint_every_chunks and \
                         chunks % checkpoint_every_chunks == 0:
                     # Async: the pull + write overlap the next chunk's
@@ -1958,13 +1967,13 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                         (cov_hits, cov_first) if cov_on else None))
                     submitted_chunks = chunks
                 with tr.span("madsim:wait", "device_wait_s"):
-                    if cov_on:
-                        n_act_h, bug_h, dist_h = _fetch(
-                            (n_active, any_bug, distinct))
-                    else:
-                        n_act_h, bug_h = _fetch((n_active, any_bug))
+                    n_act_h, bug_h, ran_h, dist_h = _fetch(
+                        (n_active, any_bug, shard_steps, distinct))
                 prof.after_read()
                 n_act = int(n_act_h)
+                ran = w_cur // n_dev * int(ran_h)
+                issued_slot_steps += ran
+                skipped_slot_steps += w_cur * chunk_steps - ran
                 if cov_on:
                     novelty_hist.append(int(dist_h))
                 emit_point(n_act, bool(bug_h), 0)
@@ -2118,6 +2127,9 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                               else int(superstep_max) if pipeline else 1),
             "chunk_steps": int(chunk_steps),
             "chunks": int(chunks),
+            # Slot-steps the chunks' exit on all-frozen shards left out
+            # (docs/perf.md "Chunk exit on frozen shards"); 0 when fused.
+            "slot_steps_skipped": int(skipped_slot_steps),
             "dispatches": n_disp,
             "chunks_per_dispatch": round(chunks / max(n_disp, 1), 3),
             "dispatches_per_seed": round(n_disp / max(n, 1), 6),
@@ -2839,6 +2851,7 @@ class SweepSession:
         # refill/shrink/search edges grouped mode never takes) ------------
         c_max = -(-self.max_steps // chunk_steps)
         chunks = 0
+        issued = 0                     # slot-steps the chunks executed
         k_cur = max(1, min(self._k_warm, superstep_max))
         epoch_fresh = True
         inflight: Optional[_Flight] = None
@@ -2855,9 +2868,10 @@ class SweepSession:
                 min_one=epoch_fresh, coverage=None)
             epoch_fresh = False
             with tr.span("madsim:superstep", "dispatch_s"):
-                state, any_bug, n_active, k_done, hist = runner(
+                state, any_bug, n_active, k_done, hist, shard_steps = runner(
                     state, jnp.int32(0), jnp.asarray(False), jnp.int32(k))
-            inflight = _Flight(any_bug, n_active, k_done, hist, k, w, 0, None)
+            inflight = _Flight(any_bug, n_active, k_done, hist, shard_steps,
+                               k, w, 0, None)
 
         try:
             if c_max > 0:
@@ -2867,15 +2881,16 @@ class SweepSession:
                 if not stop and chunks + prev.planned < c_max:
                     dispatch(reserve=prev.planned)
                 with tr.span("madsim:wait", "device_wait_s"):
-                    bug_h, n_act_h, k_done_h, _hist_h = _fetch(
+                    bug_h, n_act_h, k_done_h, _hist_h, ran_h = _fetch(
                         (prev.any_bug, prev.n_active, prev.k_done,
-                         prev.hist))
+                         prev.hist, prev.shard_steps))
                 dispatch_depth = max(dispatch_depth,
                                      1 if inflight is not None else 0)
                 with tr.span("madsim:decide", "host_decision_s"):
                     k_done = int(k_done_h)
                     n_act = int(n_act_h)
                     chunks += k_done
+                    issued += w // n_dev * int(ran_h)
                     if k_done == prev.planned:
                         k_cur = min(k_cur * 2, superstep_max)
                     else:
@@ -2948,7 +2963,6 @@ class SweepSession:
             self._k_warm = k_cur
 
             steps = chunks * chunk_steps
-            issued = w * chunk_steps * chunks
             live_steps = int(np.asarray(obs_all["steps"])[:n_tot].sum())
             util = live_steps / issued if issued else 0.0
             loop_stats_base = {
@@ -2959,6 +2973,7 @@ class SweepSession:
                 "superstep_max": int(superstep_max),
                 "chunk_steps": int(chunk_steps),
                 "chunks": int(chunks),
+                "slot_steps_skipped": int(w * chunk_steps * chunks - issued),
                 "dispatches": tr.entered["madsim:superstep"],
                 "chunks_per_dispatch": round(
                     chunks / max(tr.entered["madsim:superstep"], 1), 3),

@@ -104,6 +104,32 @@ def test_headline_step_fits_one_chip(topo):
         ca["bytes accessed"] / w
 
 
+def test_headline_superstep_compiles_for_one_chip(topo):
+    """The default sweep's superstep (512-step chunks, so the chunk loop
+    is blocks of 16 steps that stop once the shard has frozen) at
+    W=524,288 compiles for one v5e, updates the state in place and fits
+    the chip."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+
+    from madsim_tpu.parallel.mesh import scalar_spec, world_sharding
+    from madsim_tpu.parallel.sweep import sharded_superstep
+
+    eng = chip_smoke.headline_engine()
+    mesh = _mesh(topo.devices[:1])
+    state = _state_shapes(eng, chip_smoke.HEADLINE_W, world_sharding(mesh))
+    rep = NamedSharding(mesh, scalar_spec())
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
+    flag = jax.ShapeDtypeStruct((), jnp.bool_, sharding=rep)
+    comp = sharded_superstep(eng, mesh, 512, 16, donate=True).lower(
+        state, i32, flag, i32).compile()
+    ma = comp.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
+    assert ma.alias_size_in_bytes >= 0.99 * ma.argument_size_in_bytes
+    assert comp.as_text().count(" while(") >= 3   # superstep, blocks, scan
+
+
 def test_fused_hunt_compiles_for_one_chip(topo):
     """Phase C's whole-hunt fused program (recycled, no search/coverage)
     at its real geometry on a one-device mesh of the described chip."""

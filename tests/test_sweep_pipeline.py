@@ -298,7 +298,7 @@ def test_loop_stats_schema_both_paths(raft_eng, pipeline):
                   "seeds_per_dispatch", "epochs_on_device", "fused",
                   "pipelined", "superstep_max", "chunk_steps", "chunks",
                   "dispatches", "chunks_per_dispatch", "dispatch_s",
-                  "retire_wait_s", "loop_wall_s",
+                  "retire_wait_s", "loop_wall_s", "slot_steps_skipped",
                   "prepare_s", "init_s", "upload_s", "assemble_s"}
     assert documented <= set(ls), sorted(ls)
     assert ls["pipelined"] is pipeline
@@ -311,7 +311,8 @@ def test_loop_stats_schema_both_paths(raft_eng, pipeline):
                 "upload_s", "assemble_s"):
         assert isinstance(ls[key], float) and ls[key] >= 0.0, key
     for key in ("scalar_fetches", "retire_fetches", "dispatch_depth",
-                "chunks", "dispatches", "superstep_max", "chunk_steps"):
+                "chunks", "dispatches", "superstep_max", "chunk_steps",
+                "slot_steps_skipped"):
         assert isinstance(ls[key], int) and ls[key] >= 0, key
     assert ls["chunks"] >= 1 and ls["dispatches"] >= 1
     assert ls["scalar_fetches"] >= 1
