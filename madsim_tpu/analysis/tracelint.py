@@ -221,6 +221,30 @@ def _build_engine_run_blackbox() -> Built:
                  trace_args=(state,))
 
 
+def _build_engine_run_snapshot() -> Built:
+    """The Raft step with log compaction at the lab 2D deployment's shapes
+    (benchmark/configs/raft3snap.json: 3 servers, a 32-entry window, a
+    snapshot every 10 applied entries, the client stream's second timer
+    row, 10% loss)."""
+    import numpy as np
+
+    if "snap_eng" not in _ENGINE_CACHE:
+        from ..engine import (DeviceEngine, EngineConfig, RaftActor,
+                              RaftDeviceConfig)
+
+        _ENGINE_CACHE["snap_eng"] = DeviceEngine(
+            RaftActor(RaftDeviceConfig(
+                n=3, log_cap=32, n_proposals=250, propose_start_us=500_000,
+                propose_interval_us=10_000, snapshot_interval=10)),
+            EngineConfig(n_nodes=3, outbox_cap=5, queue_cap=32,
+                         loss_rate=0.1, t_limit_us=3_000_000))
+    eng = _ENGINE_CACHE["snap_eng"]
+    state = eng.init(np.arange(RUN_WORLDS))
+    return Built(fn=eng._run, args=(state, RUN_MAX_STEPS),
+                 trace_fn=lambda s: eng._run_impl(s, RUN_MAX_STEPS),
+                 trace_args=(state,))
+
+
 # Pallas kernel shape: smaller than RUN_WORLDS — the interpret-mode
 # kernel is traced/compiled per check and the contract (one fused
 # kernel, full donation, narrow lanes) is width-invariant.
@@ -671,6 +695,12 @@ def registry() -> Dict[str, TraceProgram]:
             "the K=64 state_bytes_per_world ceiling",
             _build_engine_run_blackbox, budget=True, donates=True,
             unit_div=RUN_WORLDS, packed=True),
+        TraceProgram(
+            "engine.run_snapshot", "DeviceEngine.run while-loop of the "
+            "Raft step with log compaction (lab 2D deployment: "
+            "InstallSnapshot, the client stream's timer row, digests; "
+            f"W={RUN_WORLDS})", _build_engine_run_snapshot, budget=True,
+            donates=True, unit_div=RUN_WORLDS, packed=True),
         TraceProgram(
             "engine.pallas_step", "fused Pallas step kernel "
             f"(interpret mode, raft bug config, W={PALLAS_WORLDS}, "

@@ -7,7 +7,8 @@ reference (raft_actor) calls it directly, and the actor compiler
 step for the spec-defined families (tpc, pb, paxos). Keeping the
 layout in one place means a change to it cannot silently diverge the
 actors — and the compiled/host-twin crosscheck (actorc/conformance.py)
-now pins the layout bitwise per event on top.
+now pins the layout bitwise per event on top. An actor that arms a
+second timer per handler appends its row with :func:`add_timer`.
 """
 from __future__ import annotations
 
@@ -43,4 +44,20 @@ def make_outbox(cfg: EngineConfig, n: int, msg_valid, msg_kind, msg_payload,
         delay_us=app(jnp.zeros((n,), jnp.int32),
                      jnp.asarray(timer_delay, jnp.int32)),
         payload=jnp.concatenate([msg_payload, timer_payload[None]], axis=0),
+    )
+
+
+def add_timer(ob: Outbox, valid, kind, dst, delay, payload) -> Outbox:
+    """``ob`` with one more timer row after its last: for an actor whose
+    handler may arm a second timer (``outbox_cap`` one above the usual
+    N + 1)."""
+    app = lambda xs, x: jnp.concatenate(  # noqa: E731
+        [xs, jnp.asarray(x, xs.dtype)[None]], axis=0)
+    return Outbox(
+        valid=app(ob.valid, valid),
+        is_timer=app(ob.is_timer, True),
+        kind=app(ob.kind, kind),
+        dst=app(ob.dst, dst),
+        delay_us=app(ob.delay_us, delay),
+        payload=app(ob.payload, payload),
     )

@@ -20,18 +20,52 @@ disk) and resets volatile state, mirroring crash-recovery semantics.
 The ``buggy_double_vote`` switch deliberately breaks the "one vote per term"
 rule so seed sweeps have a real bug to find — the analog of the interleaving
 bugs madsim exists to catch.
+
+Log compaction (``RaftDeviceConfig.snapshot_interval > 0``; MIT 6.824 lab
+2D, Raft paper section 7 and Figure 13), a separate static path that
+leaves the program without it exactly as it was:
+
+- Each server's log is a window of ``log_cap`` entries above its snapshot
+  (``snap_idx``, ``snap_term``, ``snap_digest``), held as a ring: entry k
+  sits at position ``(k - 1) % log_cap``, so indices stay absolute and
+  compaction moves no entry. Snapshot and window are persistent; a restart
+  sets ``commit`` back to ``snap_idx``.
+- A server applies an entry when it commits it, folding the command into
+  ``applied_digest`` (:func:`entry_hash`, summed mod 2^32), and compacts
+  when its commit index crosses a multiple of ``snapshot_interval``.
+- A leader sends InstallSnapshot in place of AppendEntries to a peer whose
+  ``next_idx`` its snapshot covers; the follower installs it as Figure 13
+  says (stale terms refused, a matching log suffix kept) and answers with
+  an AppendReply whose match is the snapshot's last index.
+- Replies drive catch-up: a successful AppendReply from a follower that
+  still trails, or a refusal (whose match word then carries the highest
+  index the follower's log may share with the leader's), makes the leader
+  send that follower its next message at once. Entries still travel one
+  per message, and ``next_idx`` runs ahead of what is acknowledged.
+- The election timer is a deadline (``elect_timeout``, ``elect_deadline``):
+  one pending timer per server, re-armed at the deadline that votes and
+  leader messages move; only an election that starts draws a fresh
+  timeout. (An epoch-stamped timer per reset, as without compaction,
+  would queue one stale timer per AppendEntries at this command rate.)
+- The client stream is one self-rescheduling Propose timer per server in a
+  second timer row (``outbox_cap`` = n + 2): command p arrives at
+  ``propose_start_us + p * propose_interval_us`` at every live server, and a
+  restart re-arms it at the next arrival.
+- The bug flag adds state-machine safety (lab 2D's ``checkLogs``): two
+  servers' digests must agree at the highest index both have applied, when
+  that index lies at or above both snapshots.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import List, NamedTuple, Tuple
+from typing import Any, List, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from .actor_util import bcast_payload, make_outbox, pad_payload
+from .actor_util import add_timer, bcast_payload, make_outbox, pad_payload
 from .core import EngineConfig, Outbox
-from .lanes import take_small, upd, upd2, widen
+from .lanes import join_wide, split_wide, take_small, upd, upd2, widen
 from .queue import Event, FLAG_TIMER, INF_TIME
 from .rng import DevRng, uniform_u32
 
@@ -50,6 +84,19 @@ K_APPEND = 4        # msg [term, leader, prev_idx, prev_term, n, e_term, e_cmd, 
 K_APPENDREPLY = 5   # msg [term, success, match_idx, follower]
 K_PROPOSE = 6       # scheduled client proposal [cmd]
 NUM_KINDS = 7
+# With log compaction only:
+K_INSTALL = 7       # msg [term, leader, last_idx, last_term, digest_lo, digest_hi, l_commit]
+
+
+def entry_hash(idx, cmd) -> jnp.ndarray:
+    """The digest term of command ``cmd`` applied at absolute index
+    ``idx`` (uint32; a state machine's digest is the sum of its applied
+    entries' terms mod 2^32, so a range folds in one masked sum)."""
+    x = (jnp.asarray(idx).astype(jnp.uint32) * jnp.uint32(0x9E3779B1)) \
+        ^ jnp.asarray(cmd).astype(jnp.uint32)
+    x = x ^ (x >> 16)
+    x = x * jnp.uint32(0x85EBCA6B)
+    return x ^ (x >> 13)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,6 +115,10 @@ class RaftDeviceConfig:
     propose_interval_us: int = 100_000
     # Injected bug: grant votes ignoring the one-vote-per-term rule.
     buggy_double_vote: bool = False
+    # Log compaction every this many applied entries (0: none, and the
+    # program is the one without it). Above 0 it needs a power-of-two
+    # log_cap of at least this interval and outbox_cap == n + 2.
+    snapshot_interval: int = 0
 
 
 class RaftState(NamedTuple):
@@ -101,6 +152,18 @@ class RaftState(NamedTuple):
     # win time even if the first winner stepped down — or won newer terms —
     # since (a purely simultaneous check misses those).
     won_terms: jnp.ndarray          # (N, WON_WORDS) i32 bitmask
+    # Log compaction (snapshot_interval > 0), else None: an empty subtree,
+    # so the state without it keeps its leaves. Indices are absolute; the
+    # log arrays hold the window (snap_idx, snap_idx + L] as a ring.
+    snap_idx: Any = None        # (N,) slot lane — last index the snapshot covers
+    snap_term: Any = None       # (N,) slot lane — its term
+    snap_digest: Any = None     # (N,) u32 — digest of commands 1..snap_idx
+    applied_digest: Any = None  # (N,) u32 — digest of commands 1..commit
+    snapshots: Any = None       # i32 — compactions, summed over servers
+    installs: Any = None        # i32 — InstallSnapshots delivered
+    # The election timer as a deadline (one pending timer per server):
+    elect_timeout: Any = None   # (N,) i32 µs — the randomized timeout
+    elect_deadline: Any = None  # (N,) i32 µs — last reset + elect_timeout
 
 
 class RaftActor:
@@ -113,6 +176,14 @@ class RaftActor:
 
     def __init__(self, rcfg: RaftDeviceConfig):
         self.rcfg = rcfg
+        self.snap = rcfg.snapshot_interval > 0
+        if self.snap:
+            L = rcfg.log_cap
+            if L & (L - 1) or rcfg.snapshot_interval > L:
+                raise ValueError("log compaction needs a power-of-two "
+                                 "log_cap >= snapshot_interval")
+            self.num_kinds = NUM_KINDS + 1
+            self.kind_names = self.kind_names + ["InstallSnapshot"]
 
     # ------------------------------------------------------------------
     # Protocol: init
@@ -123,9 +194,11 @@ class RaftActor:
         n, L = r.n, r.log_cap
         if cfg.n_nodes != n:
             raise ValueError("EngineConfig.n_nodes must match RaftDeviceConfig.n")
-        if cfg.m != n + 1:
-            raise ValueError("RaftActor needs outbox_cap == n + 1 "
-                             "(n-1 peer messages + 1 timer per handler)")
+        if cfg.m != n + 1 + self.snap:
+            raise ValueError(
+                f"RaftActor needs outbox_cap == n + {1 + self.snap} "
+                "(n-1 peer messages + 1 timer per handler"
+                + (", + the client stream's timer)" if self.snap else ")"))
         if cfg.payload_words < 8:
             raise ValueError("RaftActor needs payload_words >= 8")
         lt = cfg.lanes
@@ -151,6 +224,23 @@ class RaftActor:
             events.append(Event.make(
                 time=delay, kind=K_ELECTION, payload_words=cfg.payload_words,
                 flags=FLAG_TIMER, src=i, dst=i, payload=[0]))
+        if self.snap:
+            timeouts = jnp.stack([ev.time for ev in events])
+            s = s._replace(
+                snap_idx=jnp.zeros((n,), lt.slot),
+                snap_term=jnp.zeros((n,), lt.slot),
+                snap_digest=jnp.zeros((n,), jnp.uint32),
+                applied_digest=jnp.zeros((n,), jnp.uint32),
+                snapshots=jnp.int32(0), installs=jnp.int32(0),
+                elect_timeout=timeouts, elect_deadline=timeouts)
+            # The client stream: each server's first arrival; the handler
+            # re-arms the next one (one pending client event per server).
+            for i in range(n if r.n_proposals else 0):
+                events.append(Event.make(
+                    time=r.propose_start_us, kind=K_PROPOSE,
+                    payload_words=cfg.payload_words, flags=FLAG_TIMER,
+                    src=i, dst=i, payload=[1]))
+            return s, events, rng
         for p in range(r.n_proposals):
             t = r.propose_start_us + p * r.propose_interval_us
             for i in range(n):
@@ -186,7 +276,29 @@ class RaftActor:
             timer_dst=me, timer_delay=delay,
             timer_payload=self._pad(cfg, [epoch2]),
         )
+        if self.snap:
+            s, ob = self._restart_snap(cfg, s, me, now, delay, ob)
         return s, ob, rng
+
+    def _restart_snap(self, cfg, s, me, now, delay, ob):
+        """Crash recovery with log compaction: the snapshot and the log
+        window survive, so the server has applied exactly its snapshot;
+        the election timer restarts with the fresh ``delay``; the client
+        stream resumes at the first arrival at or after ``now``."""
+        r = self.rcfg
+        now = jnp.asarray(now, jnp.int32)
+        snap_me = widen(take_small(s.snap_idx, me))
+        s = s._replace(
+            commit=upd(s.commit, me, snap_me),
+            applied_digest=upd(s.applied_digest, me,
+                               take_small(s.snap_digest, me)),
+            elect_timeout=upd(s.elect_timeout, me, delay),
+            elect_deadline=upd(s.elect_deadline, me, now + delay))
+        start, gap = r.propose_start_us, r.propose_interval_us
+        k = jnp.maximum(0, (now - start + gap - 1) // gap)
+        ob = add_timer(ob, k < r.n_proposals, K_PROPOSE, me,
+                       start + k * gap - now, self._pad(cfg, [k + 1]))
+        return s, ob
 
     # ------------------------------------------------------------------
     # Protocol: event dispatch
@@ -208,6 +320,8 @@ class RaftActor:
         the same counter, so one draw serves them all; the counter
         advances only when the taken kind actually drew.
         """
+        if self.snap:
+            return self._handle_snap(cfg, s, ev, now, rng)
         r = self.rcfg
         n, L = r.n, r.log_cap
         kind = jnp.clip(ev.kind, 0, NUM_KINDS - 1)
@@ -227,18 +341,7 @@ class RaftActor:
         # Narrow-lane reads widen to i32 here (lanes.widen — the
         # wide-in-flight discipline, tracelint TRC005); the upd writes
         # below saturate back into the packed lanes.
-        sd = is_rv | is_vr | is_ap | is_ar
-        term_pre = widen(take_small(s.term, me))
-        role_pre = widen(take_small(s.role, me))
-        higher = sd & (t > term_pre)
-        demote = higher | (is_ap & (t == term_pre) & (role_pre == CANDIDATE))
-        s = s._replace(
-            term=upd(s.term, me, jnp.where(higher, t, term_pre)),
-            voted_for=upd(s.voted_for, me,
-                          jnp.where(higher, -1,
-                                    widen(take_small(s.voted_for, me)))),
-            role=upd(s.role, me, jnp.where(demote, FOLLOWER, role_pre)),
-        )
+        s = self._step_down(s, me, t, is_rv | is_vr | is_ap | is_ar, is_ap)
 
         # -- shared views of the post-step-down row (widened; see above) --
         term_me = widen(take_small(s.term, me))
@@ -259,35 +362,10 @@ class RaftActor:
         rng = rng._replace(counter=jnp.where(draws, rng_drawn.counter,
                                              rng.counter))
 
-        # -- election fire --
-        fire = is_elec & (p[0] == epoch_me) & (role_me != LEADER)
-        term2 = term_me + 1
-
-        # -- reqvote grant --
-        cand = jnp.clip(p[1], 0, n - 1)
-        up_to_date = (p[3] > my_last_term) | \
-                     ((p[3] == my_last_term) & (p[2] >= llen_me))
-        if r.buggy_double_vote:
-            can_vote = jnp.asarray(True)
-        else:
-            can_vote = (voted_me == -1) | (voted_me == cand)
-        grant = is_rv & ~reject & up_to_date & can_vote
-        epoch2 = epoch_me + 1
-
-        # -- votereply win + historical election safety --
-        voter = jnp.clip(p[2], 0, n - 1)
-        counted = is_vr & (p[1] != 0) & (role_me == CANDIDATE) & (t == term_me)
-        votes2 = jnp.where(counted, votes_me | (1 << voter), votes_me)
-        win = counted & (jax.lax.population_count(votes2) > n // 2)
-        bit_index = jnp.clip(term_me, 0, 32 * WON_WORDS - 1)
-        word = bit_index // 32
-        term_mask = jnp.where(jnp.arange(WON_WORDS) == word,
-                              jnp.int32(1) << (bit_index % 32),
-                              jnp.int32(0))                       # (W,)
-        node_won_term = jnp.any((s.won_terms & term_mask[None, :]) != 0,
-                                axis=1)                           # (N,)
-        hist_bug = win & jnp.any((jnp.arange(n) != me) & node_won_term)
-        my_won = take_small(s.won_terms, me)                      # (W,)
+        (fire, term2, cand, grant, epoch2, votes2, win, term_mask, hist_bug,
+         my_won) = self._elect(s, me, p, t, is_elec, is_rv, is_vr, term_me,
+                               role_me, voted_me, votes_me, epoch_me, llen_me,
+                               my_last_term, reject)
 
         # -- append --
         leader = jnp.clip(p[1], 0, n - 1)
@@ -445,6 +523,275 @@ class RaftActor:
         )
         return s2, ob, rng, hist_bug
 
+    def _handle_snap(self, cfg, s, ev, now, rng):
+        """:meth:`handle` with log compaction (module docstring): the same
+        merged form over a ring window above each server's snapshot, plus
+        InstallSnapshot, reply-driven catch-up and the client stream's
+        timer."""
+        r = self.rcfg
+        n, L = r.n, r.log_cap
+        ring = L - 1                    # entry k sits at (k - 1) & ring
+        i32 = jnp.int32
+        kind = jnp.clip(ev.kind, 0, K_INSTALL)
+        me = jnp.clip(ev.dst, 0, n - 1)
+        p = ev.payload
+        t = p[0]
+
+        is_elec = kind == K_ELECTION
+        is_hb = kind == K_HEARTBEAT
+        is_rv = kind == K_REQVOTE
+        is_vr = kind == K_VOTEREPLY
+        is_ap = kind == K_APPEND
+        is_ar = kind == K_APPENDREPLY
+        is_pr = kind == K_PROPOSE
+        is_is = kind == K_INSTALL
+        from_leader = is_ap | is_is
+
+        s = self._step_down(s, me, t, is_rv | is_vr | is_ar | from_leader,
+                            from_leader)
+
+        term_me = widen(take_small(s.term, me))
+        role_me = widen(take_small(s.role, me))
+        voted_me = widen(take_small(s.voted_for, me))
+        votes_me = take_small(s.votes, me)
+        commit_me = widen(take_small(s.commit, me))
+        llen_me = widen(take_small(s.log_len, me))
+        epoch_me = widen(take_small(s.elect_epoch, me))
+        log_term_row = widen(take_small(s.log_term, me))   # (L,) ring
+        log_cmd_row = widen(take_small(s.log_cmd, me))     # (L,) ring
+        snap_me = widen(take_small(s.snap_idx, me))
+        snapterm_me = widen(take_small(s.snap_term, me))
+        snapdig_me = take_small(s.snap_digest, me)          # u32
+        appdig_me = take_small(s.applied_digest, me)        # u32
+        timeout_me = take_small(s.elect_timeout, me)
+        deadline_me = take_small(s.elect_deadline, me)
+        now = jnp.asarray(now, i32)
+        # Absolute index held at each ring position: (snap, snap + L].
+        abs_pos = snap_me + 1 + ((jnp.arange(L) - snap_me) & ring)
+
+        def term_at(idx):
+            """Term of entry ``idx`` for snap_me <= idx <= log end."""
+            return jnp.where(idx == snap_me, snapterm_me,
+                             take_small(log_term_row, (idx - 1) & ring))
+
+        my_last_term = term_at(llen_me)
+        reject = t < term_me  # rv/ap/install stale-term test
+
+        (fire, term2, cand, grant, _, votes2, win, term_mask, hist_bug,
+         my_won) = self._elect(s, me, p, t, is_elec, is_rv, is_vr, term_me,
+                               role_me, voted_me, votes_me, epoch_me, llen_me,
+                               my_last_term, reject)
+        # -- the election timer: one pending per server, re-armed at the
+        # deadline while resets keep moving it; only an election that
+        # starts draws a fresh randomized timeout (Raft section 5.2) --
+        elect_timer = is_elec & (p[0] == epoch_me)
+        due = now >= deadline_me
+        fire = fire & due
+        delay, rng_drawn = uniform_u32(rng, r.elect_min_us, r.elect_max_us)
+        rng = rng._replace(counter=jnp.where(fire, rng_drawn.counter,
+                                             rng.counter))
+        timeout2 = jnp.where(fire, delay, timeout_me)
+        elect_delay = jnp.where(due, timeout2, deadline_me - now)
+        heard = grant | (from_leader & ~reject)
+        deadline2 = jnp.where(elect_timer & due, now + timeout2, jnp.where(
+            heard, now + timeout_me, deadline_me))
+
+        # -- append: an entry at or below my snapshot is one I hold --
+        leader = jnp.clip(p[1], 0, n - 1)
+        prev_idx, prev_term = p[2], p[3]
+        n_ent, e_term, e_cmd, l_commit = p[4], p[5], p[6], p[7]
+        behind = prev_idx < snap_me
+        prev_ok = (prev_idx <= llen_me) & (term_at(prev_idx) == prev_term)
+        success = is_ap & ~reject & (behind | prev_ok)
+        idx = prev_idx + 1
+        write = success & ~behind & (n_ent > 0) & (idx - snap_me <= L)
+        pos_ap = (idx - 1) & ring
+        same = (idx <= llen_me) & \
+               (take_small(log_term_row, pos_ap) == e_term) & \
+               (take_small(log_cmd_row, pos_ap) == e_cmd)
+        new_len_ap = jnp.where(write, jnp.where(same, llen_me, idx), llen_me)
+        match_ap = jnp.where(behind, snap_me, jnp.where(write, idx, prev_idx))
+        # Figure 2: commit up to the last entry this message vouched for.
+        commit_ap = jnp.where(success,
+                              jnp.maximum(commit_me,
+                                          jnp.minimum(l_commit, match_ap)),
+                              commit_me)
+
+        # -- install snapshot (Figure 13) --
+        with jax.named_scope("madsim/snapshot"):
+            last_idx, last_term = p[2], p[3]
+            ok_is = is_is & ~reject
+            inst = ok_is & (last_idx > commit_me)
+            keep = (last_idx <= llen_me) & (term_at(last_idx) == last_term)
+            dig_is = jax.lax.bitcast_convert_type(join_wide(p[4], p[5]),
+                                                  jnp.uint32)
+
+        # -- propose --
+        accept = is_pr & (role_me == LEADER) & (llen_me - snap_me < L)
+        pos_pr = llen_me & ring
+        llen_pr = llen_me + accept.astype(i32)
+
+        # -- appendreply (a refusal's match word is the highest index the
+        # follower's log may still share: its end, or below the refused
+        # entry's predecessor) --
+        follower = jnp.clip(p[3], 0, n - 1)
+        live_ar = is_ar & (role_me == LEADER) & (t == term_me)
+        ok_ar = live_ar & (p[1] != 0)
+        fail_ar = live_ar & (p[1] == 0)
+        cur_match = widen(take_small(take_small(s.match_idx, me), follower))
+        cur_next = widen(take_small(take_small(s.next_idx, me), follower))
+        match2 = jnp.maximum(cur_match, p[2])
+
+        # -- one combined log write (append XOR propose position) --
+        pos = jnp.where(is_ap, pos_ap, pos_pr)
+        lt_at = take_small(log_term_row, pos)
+        lc_at = take_small(log_cmd_row, pos)
+        lt_new = jnp.where(write, e_term, jnp.where(accept, term_me, lt_at))
+        lc_new = jnp.where(write, e_cmd, jnp.where(accept, p[0], lc_at))
+
+        # -- per-row combines --
+        arange_n = jnp.arange(n)
+        oh_follower = arange_n == follower
+        match_row0 = widen(take_small(s.match_idx, me))
+        next_row0 = widen(take_small(s.next_idx, me))
+        match_row = jnp.where(
+            win, jnp.where(arange_n == me, llen_me, 0),
+            jnp.where(is_ar & oh_follower,
+                      jnp.where(ok_ar, match2, cur_match),
+                      jnp.where(is_pr & (arange_n == me) & accept,
+                                llen_pr, match_row0)))
+        # next_idx runs ahead of what is acknowledged (the leader's sends
+        # advance it below); a refusal steps it back to one above the
+        # follower's hint.
+        next_f = jnp.where(ok_ar, jnp.maximum(cur_next, match2 + 1), jnp.where(
+            fail_ar, jnp.maximum(1, jnp.minimum(cur_next - 1, p[2] + 1)),
+            cur_next))
+        next_row = jnp.where(
+            win, 1 + llen_me,
+            jnp.where(is_ar & oh_follower, next_f, next_row0))
+
+        # -- appendreply commit advance over the window's indices --
+        counts = jnp.sum(match_row[:, None] >= abs_pos[None, :], axis=0,
+                         dtype=i32)
+        okn = (abs_pos <= llen_me) & (counts > n // 2) \
+            & (log_term_row == term_me)
+        best = jnp.max(jnp.where(okn, abs_pos, 0))
+        commit_ar = jnp.where(live_ar, jnp.maximum(commit_me, best), commit_me)
+
+        # -- the row after the event --
+        oh_pos = jnp.arange(L) == pos
+        log_term_row2 = jnp.where(oh_pos, lt_new, log_term_row)
+        log_cmd_row2 = jnp.where(oh_pos, lc_new, log_cmd_row)
+        llen2 = jnp.where(is_ap, new_len_ap, jnp.where(
+            is_pr, llen_pr, jnp.where(inst & ~keep, last_idx, llen_me)))
+        commit2 = jnp.where(is_ap, commit_ap, jnp.where(
+            is_ar, commit_ar, jnp.where(inst, last_idx, commit_me)))
+        term_me2 = jnp.where(fire, term2, term_me)
+
+        # -- apply what was committed; compact at each interval --
+        with jax.named_scope("madsim/snapshot"):
+            hv = entry_hash(abs_pos, log_cmd_row2)                # (L,)
+
+            def applied(hi):
+                """Digest of commands 1..hi, hi >= commit_me."""
+                return appdig_me + jnp.sum(
+                    jnp.where((abs_pos > commit_me) & (abs_pos <= hi), hv,
+                              jnp.uint32(0)), dtype=jnp.uint32)
+
+            cut = commit2 // r.snapshot_interval * r.snapshot_interval
+            compact = ~inst & (cut > snap_me)
+            snap2 = jnp.where(inst, last_idx, jnp.where(compact, cut, snap_me))
+            snapterm2 = jnp.where(inst, last_term, jnp.where(
+                compact, take_small(log_term_row2, (cut - 1) & ring),
+                snapterm_me))
+            snapdig2 = jnp.where(inst, dig_is, jnp.where(
+                compact, applied(cut), snapdig_me))
+            appdig2 = jnp.where(inst, dig_is, applied(commit2))
+
+        s2 = s._replace(
+            term=upd(s.term, me, term_me2),
+            voted_for=upd(s.voted_for, me, jnp.where(
+                fire, me, jnp.where(grant, cand, voted_me))),
+            role=upd(s.role, me, jnp.where(
+                fire, CANDIDATE, jnp.where(win, LEADER, role_me))),
+            votes=upd(s.votes, me, jnp.where(
+                fire, 1 << me, jnp.where(is_vr, votes2, votes_me))),
+            won_terms=upd(s.won_terms, me,
+                          jnp.where(win, my_won | term_mask, my_won)),
+            log_term=upd2(s.log_term, me, pos, lt_new),
+            log_cmd=upd2(s.log_cmd, me, pos, lc_new),
+            log_len=upd(s.log_len, me, llen2),
+            commit=upd(s.commit, me, commit2),
+            match_idx=upd(s.match_idx, me, match_row),
+            first_leader_time=jnp.where(
+                win,
+                jnp.minimum(s.first_leader_time, now), s.first_leader_time),
+            elections_won=s.elections_won + win.astype(i32),
+            snap_idx=upd(s.snap_idx, me, snap2),
+            snap_term=upd(s.snap_term, me, snapterm2),
+            snap_digest=upd(s.snap_digest, me, snapdig2),
+            applied_digest=upd(s.applied_digest, me, appdig2),
+            snapshots=s.snapshots + compact.astype(i32),
+            installs=s.installs + is_is.astype(i32),
+            elect_timeout=upd(s.elect_timeout, me, timeout2),
+            elect_deadline=upd(s.elect_deadline, me, deadline2),
+        )
+
+        # -- per-peer AppendEntries or InstallSnapshot --
+        with jax.named_scope("madsim/snapshot"):
+            am_kind, am_payload, am_next = self._peer_msgs_snap(
+                cfg, me, llen2, log_term_row2, log_cmd_row2, next_row,
+                term_me2, commit2, snap2, snapterm2, snapdig2)
+        live_hb = is_hb & (role_me == LEADER) & (term_me == p[0])
+
+        # -- outbox: broadcasts, catch-up to one follower, or a reply. A
+        # reply that advances a follower's match while it has entries to
+        # receive, or that refuses an entry, sends it its next message --
+        bcast = live_hb | win | accept
+        catch = (ok_ar & (p[2] > cur_match) & (next_f <= llen_me)) | fail_ar
+        to_peers = bcast | catch
+        msg_valid = jnp.where(
+            bcast, arange_n != me,
+            jnp.where(catch, oh_follower,
+                      jnp.where(fire, arange_n != me,
+                                jnp.where(is_rv, arange_n == cand,
+                                          jnp.where(from_leader,
+                                                    arange_n == leader,
+                                                    jnp.zeros((n,), bool))))))
+        s2 = s2._replace(next_idx=upd(s.next_idx, me, jnp.where(
+            to_peers & msg_valid, am_next, next_row)))
+        reply_kind = jnp.where(is_elec, K_REQVOTE, jnp.where(
+            is_rv, K_VOTEREPLY, K_APPENDREPLY))
+        msg_kind = jnp.where(to_peers, am_kind, reply_kind).astype(i32)
+        w0 = jnp.where(is_elec, term2, term_me)
+        w1 = jnp.where(is_elec, me, jnp.where(
+            is_rv, grant, jnp.where(is_is, ok_is, success))).astype(i32)
+        w2 = jnp.where(is_elec, llen_me, jnp.where(
+            is_rv, me, jnp.where(
+                is_is, jnp.where(ok_is, last_idx, 0), jnp.where(
+                    success, match_ap,
+                    jnp.where(reject, 0,
+                              jnp.minimum(llen_me, prev_idx - 1))))))
+        w3 = jnp.where(is_elec, my_last_term, jnp.where(is_rv, 0, me))
+        small = self._bcast_payload(cfg, [w0, w1, w2, w3])
+        msg_payload = jnp.where(to_peers, am_payload, small)
+
+        timer_valid = elect_timer | live_hb | win
+        hb_timer = is_hb | is_vr
+        timer_kind = jnp.where(hb_timer, K_HEARTBEAT, K_ELECTION).astype(i32)
+        timer_delay = jnp.where(hb_timer, i32(r.heartbeat_us), elect_delay)
+        tp = jnp.where(is_elec, epoch_me, jnp.where(is_hb, p[0], term_me))
+        ob = self._outbox(
+            cfg,
+            msg_valid=msg_valid, msg_kind=msg_kind, msg_payload=msg_payload,
+            timer_valid=timer_valid, timer_kind=timer_kind, timer_dst=me,
+            timer_delay=timer_delay, timer_payload=self._pad(cfg, [tp]),
+        )
+        # The client stream: command p's arrival arms command p + 1's.
+        ob = add_timer(ob, is_pr & (p[0] < r.n_proposals), K_PROPOSE, me,
+                       r.propose_interval_us, self._pad(cfg, [p[0] + 1]))
+        return s2, ob, rng, hist_bug
+
     # ------------------------------------------------------------------
     # Protocol: invariants (the bug flag)
     # ------------------------------------------------------------------
@@ -463,6 +810,8 @@ class RaftActor:
         # of the full (N, N, L) broadcast — same bug flag, under half the
         # per-step lanes. This runs on EVERY step (it is the bug flag),
         # so its op count is hot-loop cost (docs/perf.md r7).
+        if self.snap:
+            return self._invariant_snap(s)
         n = self.rcfg.n
         k = jnp.arange(self.rcfg.log_cap)
         bad = jnp.asarray(False)
@@ -476,6 +825,34 @@ class RaftActor:
                 bad = bad | jnp.any((k < lim) & diff)
         return bad
 
+    def _invariant_snap(self, s: RaftState) -> jnp.ndarray:
+        """Log matching on the committed entries both windows hold, and
+        state-machine safety: at the highest index both servers have
+        applied, when it lies at or above both snapshots, their digests
+        (snapshot digest + the window's entries up to it) agree; and no
+        server's commit index passes its log's end."""
+        n, L = self.rcfg.n, self.rcfg.log_cap
+        snap = widen(s.snap_idx)                                    # (N,)
+        commit = widen(s.commit)
+        abs_pos = snap[:, None] + 1 + (
+            (jnp.arange(L)[None, :] - snap[:, None]) & (L - 1))     # (N, L)
+        hv = entry_hash(abs_pos, widen(s.log_cmd))                  # (N, L)
+        # A server never un-logs an entry it has applied.
+        bad = jnp.any(commit > widen(s.log_len))
+        for i in range(n):
+            for j in range(i + 1, n):
+                lo = jnp.maximum(snap[i], snap[j])
+                hi = jnp.minimum(commit[i], commit[j])
+                # The ring puts index k at one position on every server.
+                both = (abs_pos[i] > lo) & (abs_pos[i] <= hi)
+                diff = (s.log_term[i] != s.log_term[j]) | \
+                       (s.log_cmd[i] != s.log_cmd[j])
+                d_i, d_j = (s.snap_digest[x] + jnp.sum(
+                    jnp.where(abs_pos[x] <= hi, hv[x], jnp.uint32(0)),
+                    dtype=jnp.uint32) for x in (i, j))
+                bad = bad | jnp.any(both & diff) | ((hi >= lo) & (d_i != d_j))
+        return bad
+
     # ------------------------------------------------------------------
     # Protocol: observation
     # ------------------------------------------------------------------
@@ -486,11 +863,67 @@ class RaftActor:
             "elections_won": s.elections_won,
             "max_commit": jnp.max(s.commit, axis=-1),
             "max_term": jnp.max(s.term, axis=-1),
+            **({"snapshots": s.snapshots, "installs": s.installs}
+               if self.snap else {}),
         }
 
     # ==================================================================
     # Helpers
     # ==================================================================
+    def _step_down(self, s, me, t, carries_term, from_leader):
+        """Step down on a higher term, and a candidate on its term's
+        leader (``carries_term``/``from_leader``: the event's kind masks)."""
+        term_pre = widen(take_small(s.term, me))
+        role_pre = widen(take_small(s.role, me))
+        higher = carries_term & (t > term_pre)
+        demote = higher | (from_leader & (t == term_pre)
+                           & (role_pre == CANDIDATE))
+        return s._replace(
+            term=upd(s.term, me, jnp.where(higher, t, term_pre)),
+            voted_for=upd(s.voted_for, me,
+                          jnp.where(higher, -1,
+                                    widen(take_small(s.voted_for, me)))),
+            role=upd(s.role, me, jnp.where(demote, FOLLOWER, role_pre)),
+        )
+
+    def _elect(self, s, me, p, t, is_elec, is_rv, is_vr, term_me, role_me,
+               voted_me, votes_me, epoch_me, llen_me, my_last_term, reject):
+        """Election timeout, vote grant and vote count, with the
+        historical election-safety check of a win."""
+        r = self.rcfg
+        n = r.n
+        # -- election fire --
+        fire = is_elec & (p[0] == epoch_me) & (role_me != LEADER)
+        term2 = term_me + 1
+
+        # -- reqvote grant --
+        cand = jnp.clip(p[1], 0, n - 1)
+        up_to_date = (p[3] > my_last_term) | \
+                     ((p[3] == my_last_term) & (p[2] >= llen_me))
+        if r.buggy_double_vote:
+            can_vote = jnp.asarray(True)
+        else:
+            can_vote = (voted_me == -1) | (voted_me == cand)
+        grant = is_rv & ~reject & up_to_date & can_vote
+        epoch2 = epoch_me + 1
+
+        # -- votereply win + historical election safety --
+        voter = jnp.clip(p[2], 0, n - 1)
+        counted = is_vr & (p[1] != 0) & (role_me == CANDIDATE) & (t == term_me)
+        votes2 = jnp.where(counted, votes_me | (1 << voter), votes_me)
+        win = counted & (jax.lax.population_count(votes2) > n // 2)
+        bit_index = jnp.clip(term_me, 0, 32 * WON_WORDS - 1)
+        word = bit_index // 32
+        term_mask = jnp.where(jnp.arange(WON_WORDS) == word,
+                              jnp.int32(1) << (bit_index % 32),
+                              jnp.int32(0))                       # (W,)
+        node_won_term = jnp.any((s.won_terms & term_mask[None, :]) != 0,
+                                axis=1)                           # (N,)
+        hist_bug = win & jnp.any((jnp.arange(n) != me) & node_won_term)
+        my_won = take_small(s.won_terms, me)                      # (W,)
+        return (fire, term2, cand, grant, epoch2, votes2, win, term_mask,
+                hist_bug, my_won)
+
     def _row_term_at(self, log_term_row, idx):
         L = self.rcfg.log_cap
         pos = jnp.clip(idx - 1, 0, L - 1)
@@ -522,6 +955,39 @@ class RaftActor:
         ], axis=1)
         pad = jnp.zeros((n, cfg.payload_words - 8), jnp.int32)
         return jnp.arange(n) != me, jnp.concatenate([payload, pad], axis=1)
+
+    def _peer_msgs_snap(self, cfg, me, llen, term_row, cmd_row, next_row,
+                        term, commit, snap, snap_term, snap_digest):
+        """Per-peer kinds, payloads and next ``next_idx`` from the leader's
+        row after the event: AppendEntries with the entry at
+        ``next_idx``, or InstallSnapshot to a peer whose ``next_idx`` the
+        snapshot covers (its digest as two words, lanes.split_wide)."""
+        n, L = self.rcfg.n, self.rcfg.log_cap
+        ring = L - 1
+        nxt = jnp.maximum(next_row, 1)                  # (N,)
+        install = nxt <= snap
+        prev = nxt - 1
+        prev_term = jnp.where(prev == snap, snap_term,
+                              take_small(term_row, (prev - 1) & ring))
+        have = nxt <= llen
+        pos = (nxt - 1) & ring
+        e_term = jnp.where(have, take_small(term_row, pos), 0)
+        e_cmd = jnp.where(have, take_small(cmd_row, pos), 0)
+        dig_lo, dig_hi = split_wide(
+            jax.lax.bitcast_convert_type(snap_digest, jnp.int32))
+        full = lambda x: jnp.full((n,), x, jnp.int32)  # noqa: E731
+        append = jnp.stack([full(term), full(me), prev, prev_term,
+                            have.astype(jnp.int32), e_term, e_cmd,
+                            full(commit)], axis=1)
+        snapshot = jnp.stack([full(term), full(me), full(snap),
+                              full(snap_term), full(dig_lo), full(dig_hi),
+                              full(commit), full(0)], axis=1)
+        payload = jnp.where(install[:, None], snapshot, append)
+        pad = jnp.zeros((n, cfg.payload_words - 8), jnp.int32)
+        # A peer sent an entry (or the snapshot) is next sent the one after.
+        sent_next = jnp.where(install, snap + 1, nxt + have)
+        return (jnp.where(install, K_INSTALL, K_APPEND),
+                jnp.concatenate([payload, pad], axis=1), sent_next)
 
     def _bcast_payload(self, cfg, words):
         return bcast_payload(cfg, self.rcfg.n, words)

@@ -170,6 +170,33 @@ def test_fused_hunt_compiles_for_one_chip(topo):
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
 
 
+def test_snapshot_step_fits_one_chip(topo):
+    """The Raft step with log compaction (benchmark/configs/raft3snap.json,
+    the raft3snap.uncrash cell's 65,536 worlds) compiles for one v5e, fits
+    the chip, and holds no gather or scatter."""
+    import json
+
+    import jax
+
+    from madsim_tpu.engine import (DeviceEngine, EngineConfig, RaftActor,
+                                   RaftDeviceConfig)
+    from madsim_tpu.parallel.mesh import world_sharding
+
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "raft3snap.json")) as f:
+        cfg = json.load(f)
+    eng = DeviceEngine(RaftActor(RaftDeviceConfig(**cfg["raft"])),
+                       EngineConfig(**cfg["engine"]))
+    mesh = _mesh(topo.devices[:1])
+    state = _state_shapes(eng, 65_536, world_sharding(mesh))
+    comp = jax.jit(eng._batched_step, donate_argnums=0).lower(
+        state).compile()
+    ma = comp.memory_analysis()
+    assert 0 < ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
+    hlo = comp.as_text()
+    assert " gather(" not in hlo and " scatter(" not in hlo
+
+
 def test_sharded_chunk_all_reduces_over_four_chips(topo):
     """sharded_engine's chunk at W=524,288 over a mesh of all four
     described chips compiles, and its bug/active scalars cross chips in

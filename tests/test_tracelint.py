@@ -331,7 +331,8 @@ def test_self_scan_trace_rules_clean():
 
 def test_registry_covers_the_hot_paths():
     names = set(TL.registry())
-    for required in ("engine.run", "engine.pallas_step", "engine.push_many",
+    for required in ("engine.run", "engine.run_snapshot",
+                     "engine.pallas_step", "engine.push_many",
                      "engine.refill_select", "sweep.superstep",
                      "sweep.superstep_min_one", "sweep.superstep_coverage",
                      "sweep.coverage_endfold", "sweep.compactor",
