@@ -31,6 +31,7 @@ kept as the equivalence reference and tier-1-tested against).
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -60,9 +61,12 @@ _fetch = jax.device_get  # detlint: allow[DET008] reason=the ONE sanctioned pull
 
 # The ``loop_stats`` seconds keys, each fed by one phase's host spans
 # (docs/observability.md "Loop spans and profiler capture").
-_LOOP_SECONDS = ("prepare_s", "init_s", "upload_s", "dispatch_s",
-                 "device_wait_s", "host_decision_s", "retire_wait_s",
-                 "assemble_s")
+_LOOP_SECONDS = ("prepare_s", "identity_s", "init_s", "upload_s",
+                 "dispatch_s", "device_wait_s", "host_decision_s",
+                 "retire_wait_s", "assemble_s")
+
+# The fault fingerprint of a sweep without faults, as a checkpoint stores it.
+_NO_FAULTS_SHA256 = hashlib.sha256(b"none").hexdigest()
 
 
 def _cov_reducers(mesh: Mesh):
@@ -881,7 +885,6 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
     accounting: it shifts ids only, never a corpus decision or a child
     byte.
     """
-    import hashlib
     import os
 
     from ..engine import checkpoint as ckpt
@@ -1022,15 +1025,23 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                 return None
             return faults_p[ids] if per_world_faults else faults_p
 
+        # The fault fingerprint rides every result that has faults (repro
+        # banners, bundles, the fleet merge check).
+        faults_sha256 = (hashlib.sha256(
+            np.ascontiguousarray(faults_p).tobytes()).hexdigest()
+            if faults_p is not None else _NO_FAULTS_SHA256)
         # World identity travels with the checkpoint: resuming under different
         # seeds OR fault schedules would silently attribute results (repro
-        # banners!) to inputs that never produced them.
-        faults_key = (np.ascontiguousarray(faults_p).tobytes()
-                      if faults_p is not None else b"none")
-        seeds_meta = {
-            "seeds_sha256": hashlib.sha256(seeds_p.tobytes()).hexdigest(),
-            "faults_sha256": hashlib.sha256(faults_key).hexdigest(),
-        }
+        # banners!) to inputs that never produced them. Only a checkpoint
+        # reads the seeds' hash, so only a checkpointed sweep pays for it.
+        seeds_meta = None
+        if checkpoint_path:
+            with tr.span("madsim:identity", "identity_s"):
+                seeds_meta = {
+                    "seeds_sha256": hashlib.sha256(
+                        seeds_p.tobytes()).hexdigest(),
+                    "faults_sha256": faults_sha256,
+                }
 
     resumed = False
     resume_aux: Dict[str, np.ndarray] = {}
@@ -1616,6 +1627,7 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
         return aux
 
     fused_epochs = 0                   # device refill epochs (fused path)
+    fused_setup_hit = False            # setup program came from the cache
     fused_k_bucket = 0                 # chunk window per mega-dispatch
     fused_bufs = fused_sched_buf = fused_lin_buf = None
     try:
@@ -1624,8 +1636,6 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
             # "Whole-hunt residency"): the occupancy loop lives inside
             # ONE device program; the host's job shrinks to issuing
             # mega-dispatches and mirroring telemetry scalars. ---------
-            from ..obs.lineage import lanes_buffer
-
             with tr.span("madsim:upload", "upload_s"):
                 rep_sh = NamedSharding(mesh, scalar_spec())
                 n_ids_b = _pow2_at_least(n_ids)
@@ -1636,14 +1646,21 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                 # bucket reuses ONE compiled program (the PR 3 zero-
                 # recompile contract extended to fused). Rows past n_ids
                 # are never gathered (the traced cursor clamps at the real
-                # count), so zero/repeat padding is inert.
-                lo = (seeds_p & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-                hi = (seeds_p >> np.uint64(32)).astype(np.uint32)
-                if n_ids_b > n_ids:
-                    pad = n_ids_b - n_ids
-                    lo = np.concatenate([lo, np.zeros(pad, np.uint32)])
-                    hi = np.concatenate([hi, np.zeros(pad, np.uint32)])
-                tabs = {"lo": jnp.asarray(lo), "hi": jnp.asarray(hi)}
+                # count), so zero/repeat padding is inert. The seed words
+                # go up as they are and split into lo/hi on the device,
+                # in the cached setup program that also zeroes the
+                # per-seed buffers (retiring rows land there INSIDE the
+                # loop, live rows at each mega-dispatch boundary, and the
+                # host pulls the whole thing ONCE at the end).
+                setup, fused_setup_hit = _fused_setup(
+                    eng, mesh, state, w=w_cur, n_ids_b=n_ids_b,
+                    f_rows=(f_rows if search_on else 0),
+                    lineage_on=lineage_on)
+                (lo, hi, fused_bufs, fused_sched_buf, fused_lin_buf,
+                 cursor_dev, epochs_dev) = setup(
+                    _seed_words(seeds_p, n_ids_b).reshape(-1),
+                    np.int32(cursor))
+                tabs = {"lo": lo, "hi": hi}
                 if search_on:
                     fault_mode = "search"
                 elif faults_p is None:
@@ -1655,30 +1672,12 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                         ftab = np.concatenate(
                             [ftab, ftab[:1].repeat(n_ids_b - n_ids, axis=0)],
                             axis=0)
-                    tabs["faults"] = jnp.asarray(ftab, jnp.int32)
+                    tabs["faults"] = jax.device_put(
+                        np.asarray(ftab, np.int32), rep_sh)
                 else:
                     fault_mode = "shared"
-                    tabs["faults"] = jnp.asarray(faults_p, jnp.int32)
-                tabs = jax.device_put(tabs, rep_sh)
-                # Per-seed observation buffers (+ one dump row for masked
-                # scatters): retiring rows land at retire time INSIDE the
-                # loop, live rows at each mega-dispatch boundary, and the
-                # host pulls the whole thing ONCE at the end. eval_shape
-                # keeps buffer setup compile-free.
-                obs_shapes = jax.eval_shape(eng.observe_device, state)
-                fused_bufs = jax.device_put(
-                    {k: jnp.zeros((n_ids_b + 1,) + tuple(sh.shape[1:]),
-                                  sh.dtype)
-                     for k, sh in obs_shapes.items()}, rep_sh)
-                if search_on:
-                    sb = np.full((n_ids_b + 1, f_rows, 4), -1, np.int32)
-                    sb[:, :, 1:] = 0       # canonical disabled-row padding
-                    fused_sched_buf = jax.device_put(jnp.asarray(sb), rep_sh)
-                if lineage_on:
-                    fused_lin_buf = jax.device_put(
-                        lanes_buffer(n_ids_b), rep_sh)
-                cursor_dev = jax.device_put(jnp.int32(cursor), rep_sh)
-                epochs_dev = jax.device_put(jnp.int32(0), rep_sh)
+                    tabs["faults"] = jax.device_put(
+                        np.asarray(faults_p, np.int32), rep_sh)
             runner = _fused_hunt(
                 eng, mesh, search, w=w_cur, n_ids_b=n_ids_b,
                 f_rows=(f_rows if search_on else 0),
@@ -2140,6 +2139,11 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
             # dispatch).
             "seeds_per_dispatch": round(n / max(n_disp, 1), 3),
             "epochs_on_device": int(fused_epochs),
+            # Fused setups served by the engine's cached setup program (no
+            # eval_shape, no trace), and hashes of the seed array
+            # (madsim:identity, checkpointed sweeps only).
+            "fused_setup_cache_hits": int(fused_setup_hit),
+            "identity_hashes": tr.entered["madsim:identity"],
             "dispatch_depth": int(perf["dispatch_depth"]),
             "scalar_fetches": tr.entered["madsim:wait"],
             "retire_fetches": int(perf["retire_fetches"]),
@@ -2203,7 +2207,7 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                              n_active_chunks=np.asarray(n_active_chunk,
                                                         np.int64),
                              loop_stats=loop_stats,
-                             faults_sha256=(seeds_meta["faults_sha256"]
+                             faults_sha256=(faults_sha256
                                             if faults is not None else None),
                              coverage=coverage,
                              search=search_report,
@@ -2271,6 +2275,66 @@ def _pow2_at_least(n: int) -> int:
     while b < n:
         b <<= 1
     return b
+
+
+def _seed_words(seeds, n_b: int) -> np.ndarray:
+    """The u64 seeds as an ``(n_b, 2)`` table of little-endian u32 words,
+    ``[:, 0]`` the low word and ``[:, 1]`` the high one, zero rows past
+    ``len(seeds)``: the ``& 0xFFFFFFFF`` / ``>> 32`` split without a pass
+    over the seeds. A view of the seed array (no copy) when it is
+    contiguous native u64 on a little-endian host and ``n_b`` adds no
+    rows."""
+    words = np.ascontiguousarray(seeds, dtype="<u8").view("<u4")
+    words = words.reshape(-1, 2)
+    if words.shape[0] == n_b:
+        return words
+    table = np.zeros((n_b, 2), "<u4")
+    table[:words.shape[0]] = words
+    return table
+
+
+def _fused_setup(eng: DeviceEngine, mesh: Mesh, state, *, w: int,
+                 n_ids_b: int, f_rows: int, lineage_on: bool):
+    """Compile (and cache per engine) the fused hunt's setup program;
+    returns ``(setup, cache_hit)``.
+
+    ``setup(words, cursor)`` splits the :func:`_seed_words` table, flat
+    (a ``(n, 2)`` array would pad its minor axis to the TPU's 128 lanes),
+    into the refill's ``lo``/``hi`` seed tables and builds every zeroed
+    per-seed buffer (observations, and with search the schedules and
+    lineage lanes; one trailing dump row each) and the cursor/epoch
+    scalars, all mesh-replicated, in one dispatch. The buffer shapes
+    come from one ``eval_shape`` of ``eng.observe_device`` per engine
+    and geometry, so a later hunt in the same bucket traces nothing.
+    """
+    cache = eng.__dict__.setdefault("_fused_setup_cache", {})
+    key = (mesh, w, n_ids_b, f_rows, lineage_on)
+    if key in cache:
+        return cache[key], True
+
+    from ..obs.lineage import lanes_buffer
+
+    obs_shapes = jax.eval_shape(eng.observe_device, state)
+
+    def setup(words, cursor):
+        bufs = {k: jnp.zeros((n_ids_b + 1,) + tuple(sh.shape[1:]), sh.dtype)
+                for k, sh in obs_shapes.items()}
+        sched_buf = lin_buf = None
+        if f_rows:
+            # Canonical disabled-row padding: time -1, op/a/b 0.
+            sched_buf = jnp.zeros((n_ids_b + 1, f_rows, 4),
+                                  jnp.int32).at[:, :, 0].set(-1)
+        if lineage_on:
+            lin_buf = lanes_buffer(n_ids_b)
+        lo = jax.lax.slice(words, (0,), (2 * n_ids_b,), (2,))
+        hi = jax.lax.slice(words, (1,), (2 * n_ids_b,), (2,))
+        return (lo, hi, bufs, sched_buf, lin_buf,
+                jnp.asarray(cursor, jnp.int32), jnp.int32(0))
+
+    rep = NamedSharding(mesh, scalar_spec())
+    fn = jax.jit(setup, in_shardings=rep, out_shardings=rep)
+    cache[key] = fn
+    return fn, False
 
 
 @jax.jit
@@ -2639,7 +2703,7 @@ class SweepSession:
     """A persistent sweep session: the fleet's answer to O(fresh-sweep)
     lease turnaround (docs/fleet.md "Fabric cost model").
 
-    ``sweep()`` pays a per-call host tax — seed/fault padding and
+    ``sweep()`` pays a per-call host tax — seed/fault padding, fault
     hashing, batch ``init``, compile-cache lookups, telemetry plumbing —
     that a fleet worker used to repeat for EVERY leased range. A session
     pins the (engine, mesh, chunk/superstep geometry) once and streams
@@ -2730,7 +2794,6 @@ class SweepSession:
         mesh-rounded id space with repeats of row 0, exactly as
         ``sweep()`` pads), so a grouped result's fingerprint equals its
         solo counterpart's byte for byte."""
-        import hashlib
         if faults is None:
             return None
         fp = np.asarray(faults, np.int32)

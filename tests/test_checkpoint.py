@@ -233,3 +233,96 @@ def test_sweep_resumes_from_checkpoint(tmp_path):
         assert np.array_equal(full.observations[key],
                               resumed.observations[key]), key
     assert np.array_equal(full.bug, resumed.bug)
+
+
+# ---------------------------------------------------------------------------
+# Sweep identity: what is hashed, and only when a checkpoint reads it
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def seng():
+    return DeviceEngine(RaftActor(RCFG), ECFG)
+
+
+def _record_sha256(monkeypatch):
+    """Patch ``hashlib.sha256`` to record the bytes of every call; returns
+    the record and the real constructor."""
+    import hashlib
+
+    real = hashlib.sha256
+    calls = []
+
+    def recording(data=b"", **kw):
+        calls.append(bytes(data))
+        return real(data, **kw)
+
+    monkeypatch.setattr(hashlib, "sha256", recording)
+    return calls, real
+
+
+@pytest.mark.parametrize("loop", ["serial", "pipelined", "fused",
+                                  "checkpoint"])
+def test_sweep_hashes_seeds_only_for_a_checkpoint(seng, tmp_path,
+                                                  monkeypatch, loop):
+    """Only a checkpoint reads ``seeds_sha256``, so a sweep without one
+    never hashes its seed array (no sha256 call over more than 64 bytes);
+    a checkpointed sweep hashes it once, in ``madsim:identity``, and
+    writes the same identity as before."""
+    from madsim_tpu.engine.checkpoint import read_meta
+    from madsim_tpu.parallel.sweep import sweep
+
+    path = str(tmp_path / "sweep.npz")
+    kw = {"serial": {"pipeline": False}, "pipelined": {},
+          "fused": {"fused": True},
+          "checkpoint": {"checkpoint_path": path}}[loop]
+    seeds = np.arange(100, 124, dtype=np.uint64)
+
+    def run():
+        return sweep(None, ECFG, seeds, engine=seng, chunk_steps=64,
+                     max_steps=64, **kw)
+
+    run()                      # compile outside the record
+    calls, real = _record_sha256(monkeypatch)
+    res = run()
+    if loop == "checkpoint":
+        assert calls.count(seeds.tobytes()) == 1
+        assert res.loop_stats["identity_hashes"] == 1
+        extra = read_meta(path)["extra"]
+        assert extra == {
+            "seeds_sha256": real(seeds.tobytes()).hexdigest(),
+            "faults_sha256": real(b"none").hexdigest()}
+    else:
+        assert [len(c) for c in calls if len(c) > 64] == []
+        assert res.loop_stats["identity_hashes"] == 0
+        assert res.loop_stats["identity_s"] == 0.0
+
+
+@pytest.mark.parametrize("schedule", ["shared", "per_world", "none"])
+def test_faults_sha256_is_the_padded_rows_hash(seng, tmp_path, schedule):
+    """``faults_sha256`` (on the result and in the checkpoint) is sha256
+    over the int32 rows, per-world ones padded to the mesh-rounded seed
+    count with repeats of row 0, and of ``b"none"`` without faults."""
+    import hashlib
+
+    from madsim_tpu.engine.checkpoint import read_meta
+    from madsim_tpu.parallel.mesh import seed_mesh
+    from madsim_tpu.parallel.sweep import sweep
+
+    n = 20
+    pad = (-n) % seed_mesh().devices.size
+    rows = np.array([[200_000, 0, 1, 0], [600_000, 1, 1, 0]], np.int32)
+    if schedule == "shared":
+        faults, key = rows, rows.tobytes()
+    elif schedule == "per_world":
+        faults = rows + np.arange(n, dtype=np.int32)[:, None, None] * \
+            np.array([1_000, 0, 0, 0], np.int32)
+        key = np.concatenate([faults, faults[:1].repeat(pad, axis=0)],
+                             axis=0).tobytes()
+    else:
+        faults, key = None, b"none"
+    path = str(tmp_path / "sweep.npz")
+    res = sweep(None, ECFG, np.arange(n), faults=faults, engine=seng,
+                chunk_steps=64, max_steps=64, checkpoint_path=path)
+    want = hashlib.sha256(key).hexdigest()
+    assert read_meta(path)["extra"]["faults_sha256"] == want
+    assert res.faults_sha256 == (None if faults is None else want)

@@ -318,9 +318,12 @@ def test_fused_loop_stats_schema(raft_eng):
                   "superstep_max", "chunk_steps", "chunks", "dispatches",
                   "chunks_per_dispatch", "dispatch_s", "retire_wait_s",
                   "loop_wall_s", "prepare_s", "init_s", "upload_s",
-                  "assemble_s"}
+                  "assemble_s", "identity_s", "identity_hashes",
+                  "fused_setup_cache_hits"}
     assert documented <= set(ls), sorted(ls)
     assert ls["fused"] is True and ls["pipelined"] is False
+    assert ls["identity_hashes"] == 0
+    assert ls["fused_setup_cache_hits"] in (0, 1)
     assert isinstance(ls["seeds_per_dispatch"], float)
     assert isinstance(ls["epochs_on_device"], int)
     assert ls["seeds_per_dispatch"] == pytest.approx(
@@ -333,3 +336,63 @@ def test_fused_loop_stats_schema(raft_eng):
                 "fused"} <= set(res.loop_stats)
         assert res.loop_stats["epochs_on_device"] == 0
         assert res.loop_stats["fused"] is False
+
+
+# ---------------------------------------------------------------------------
+# Setup before the program: the seed words and the cached buffers
+# ---------------------------------------------------------------------------
+
+_EDGE_SEEDS = np.array([0, 2**32 - 1, 2**32, 2**63, 2**64 - 1], np.uint64)
+
+
+@pytest.mark.parametrize("case", ["contiguous", "strided", "big_endian",
+                                  "padded"])
+def test_seed_words_match_the_mask_and_shift_split(raft_eng, case):
+    """The ``(n_b, 2)`` word table, and the lo/hi tables the fused setup
+    program splits from it on the device, equal the ``& 0xFFFFFFFF`` /
+    ``>> 32`` split, zero rows past the seeds; contiguous native seeds
+    are viewed, not copied."""
+    from madsim_tpu.parallel.mesh import seed_mesh
+
+    seeds = {"contiguous": _EDGE_SEEDS,
+             "strided": np.repeat(_EDGE_SEEDS, 2)[::2],
+             "big_endian": _EDGE_SEEDS.astype(">u8"),
+             "padded": _EDGE_SEEDS}[case]
+    n = len(seeds)
+    n_b = sweep_mod._pow2_at_least(n + 1) if case == "padded" else n
+    want = np.asarray(seeds, np.uint64)
+    lo = (want & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+    hi = (want >> np.uint64(32)).astype(np.uint32)
+
+    words = sweep_mod._seed_words(seeds, n_b)
+    assert words.shape == (n_b, 2)
+    np.testing.assert_array_equal(words[:n, 0], lo)
+    np.testing.assert_array_equal(words[:n, 1], hi)
+    np.testing.assert_array_equal(words[n:], 0)
+    if case == "contiguous":
+        assert np.shares_memory(words, seeds)
+
+    setup, _ = sweep_mod._fused_setup(
+        raft_eng, seed_mesh(), raft_eng.init(np.arange(8)), w=8,
+        n_ids_b=n_b, f_rows=0, lineage_on=False)
+    lo_d, hi_d = setup(words.reshape(-1), np.int32(8))[:2]
+    np.testing.assert_array_equal(np.asarray(lo_d)[:n], lo)
+    np.testing.assert_array_equal(np.asarray(hi_d)[:n], hi)
+    np.testing.assert_array_equal(np.asarray(lo_d)[n:], 0)
+
+
+def test_fused_setup_cache_serves_a_second_hunt_in_the_bucket(raft_eng):
+    """Two fused hunts whose seed counts share a power-of-two bucket: the
+    second reuses the engine's setup program (no ``eval_shape``, no new
+    trace), and its rows equal those of a fresh engine's hunt."""
+    kw = dict(chunk_steps=64, max_steps=2_048, fused=True, recycle=True,
+              batch_worlds=16)
+    sweep(None, raft_eng.cfg, np.arange(1_000, 1_040), engine=raft_eng,
+          **kw)
+    seeds = np.arange(2_000, 2_056)            # 40 and 56 both bucket to 64
+    second = sweep(None, raft_eng.cfg, seeds, engine=raft_eng, **kw)
+    assert second.loop_stats["fused_setup_cache_hits"] == 1
+    fresh_eng = DeviceEngine(raft_eng.actor, raft_eng.cfg)
+    fresh = sweep(None, raft_eng.cfg, seeds, engine=fresh_eng, **kw)
+    assert fresh.loop_stats["fused_setup_cache_hits"] == 0
+    assert_fused_bitwise(fresh, second)

@@ -299,7 +299,8 @@ def test_loop_stats_schema_both_paths(raft_eng, pipeline):
                   "pipelined", "superstep_max", "chunk_steps", "chunks",
                   "dispatches", "chunks_per_dispatch", "dispatch_s",
                   "retire_wait_s", "loop_wall_s", "slot_steps_skipped",
-                  "prepare_s", "init_s", "upload_s", "assemble_s"}
+                  "prepare_s", "init_s", "upload_s", "assemble_s",
+                  "identity_s", "identity_hashes", "fused_setup_cache_hits"}
     assert documented <= set(ls), sorted(ls)
     assert ls["pipelined"] is pipeline
     assert ls["fused"] is False
@@ -308,11 +309,12 @@ def test_loop_stats_schema_both_paths(raft_eng, pipeline):
         48 / ls["dispatches"], abs=1e-3)
     for key in ("device_wait_s", "host_decision_s", "dispatch_s",
                 "retire_wait_s", "loop_wall_s", "prepare_s", "init_s",
-                "upload_s", "assemble_s"):
+                "upload_s", "assemble_s", "identity_s"):
         assert isinstance(ls[key], float) and ls[key] >= 0.0, key
     for key in ("scalar_fetches", "retire_fetches", "dispatch_depth",
                 "chunks", "dispatches", "superstep_max", "chunk_steps",
-                "slot_steps_skipped"):
+                "slot_steps_skipped", "identity_hashes",
+                "fused_setup_cache_hits"):
         assert isinstance(ls[key], int) and ls[key] >= 0, key
     assert ls["chunks"] >= 1 and ls["dispatches"] >= 1
     assert ls["scalar_fetches"] >= 1
