@@ -6,12 +6,13 @@ then failing-seed replay, at the sizes users sweep. One process, which
 imports JAX once and holds the chip throughout; nothing here spawns.
 
 - Phase A, headline sweep: 3-node Raft election, 1 virtual second
-  (bench.py's headline config), 524,288 seeds. No world live, no bug, no
+  (BASELINE.json config 2), 524,288 seeds. No world live, no bug, no
   overflow; a rerun is bitwise equal; a 4,096-seed slice is bitwise
   equal between the chip and the CPU backend.
 - Phase B, chaos: 5-node Raft replication with per-world kill/restart
-  and link-clog schedules (bench.py's madraft_5node config), 100,000
-  worlds, ``chunk_steps=16``. No world live, no bug, no overflow.
+  and link-clog schedules (BASELINE.json config 5, faults from
+  ``make_fault_schedules``), 100,000 worlds, ``chunk_steps=16``. No
+  world live, no bug, no overflow; a rerun is bitwise equal.
 - Phase C, hunt and replay: a fused, recycled ``stop_on_first_bug`` hunt
   over 1,048,576 seeds of the ``buggy_double_vote`` config. It finds the
   bug; ``DeviceEngine.trace`` replays the first failing seed on the chip
@@ -133,10 +134,37 @@ def phase_a(w=HEADLINE_W, xcheck_w=XCHECK_W):
           crosscheck_s=round(xc_s, 3))
 
 
+def make_fault_schedules(n_worlds: int, n_nodes: int, t_limit_us: int,
+                         seed: int = 0):
+    """Per-world fault rows [time_us, op, a, b]: one kill+restart pair and
+    one link clog+unclog window per world, at schedule-swept times."""
+    import numpy as np
+
+    from madsim_tpu.engine.core import (
+        FAULT_KILL, FAULT_RESTART, FAULT_CLOG_LINK, FAULT_UNCLOG_LINK)
+
+    rng = np.random.default_rng(seed)
+    t_kill = rng.integers(t_limit_us // 10, t_limit_us // 2, n_worlds)
+    t_restart = t_kill + rng.integers(50_000, t_limit_us // 4, n_worlds)
+    victim = rng.integers(0, n_nodes, n_worlds)
+    t_clog = rng.integers(t_limit_us // 10, t_limit_us // 2, n_worlds)
+    t_unclog = t_clog + rng.integers(50_000, t_limit_us // 4, n_worlds)
+    a = rng.integers(0, n_nodes, n_worlds)
+    b = (a + 1 + rng.integers(0, n_nodes - 1, n_worlds)) % n_nodes
+    rows = np.stack([
+        np.stack([t_kill, np.full(n_worlds, FAULT_KILL), victim,
+                  np.zeros(n_worlds)], axis=1),
+        np.stack([t_restart, np.full(n_worlds, FAULT_RESTART), victim,
+                  np.zeros(n_worlds)], axis=1),
+        np.stack([t_clog, np.full(n_worlds, FAULT_CLOG_LINK), a, b], axis=1),
+        np.stack([t_unclog, np.full(n_worlds, FAULT_UNCLOG_LINK), a, b], axis=1),
+    ], axis=1).astype(np.int32)
+    return rows
+
+
 def phase_b(w=CHAOS_W):
     import numpy as np
 
-    from bench import make_fault_schedules
     from madsim_tpu.engine import (DeviceEngine, EngineConfig, RaftActor,
                                    RaftDeviceConfig)
     from madsim_tpu.parallel.sweep import sweep

@@ -17,8 +17,8 @@ on every backend.
 """
 # The one compile-cache rule (parallel/compile_cache.py), applied at
 # package import, before anything compiles (jax latches its cache at the
-# first compile), so every entry point — bench, chip_smoke, tools/, fleet
-# worker processes — follows it. Loaded by file path, NOT `from
+# first compile), so every entry point — benchmark/, chip_smoke, tools/,
+# fleet worker processes — follows it. Loaded by file path, NOT `from
 # .parallel import ...`: the parallel package init imports jax and the
 # engine, and the host-only import path stays jax-free.
 import os as _os

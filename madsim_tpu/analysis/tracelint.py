@@ -165,7 +165,7 @@ _ENGINE_CACHE: Dict[str, Any] = {}
 
 def _bug_engine(metrics: bool = False, blackbox: int = 0):
     """The canonical raft bug config every budget in the repo is pinned
-    to (tests/test_queue_insert.py, bench time_to_first_bug)."""
+    to (tests/test_queue_insert.py)."""
     key = f"eng_m{int(metrics)}_b{blackbox}"
     if key not in _ENGINE_CACHE:
         from ..engine import (DeviceEngine, EngineConfig, RaftActor,

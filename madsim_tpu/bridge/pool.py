@@ -425,7 +425,7 @@ def sweep_pooled(world_fn, seeds, *, jobs: int, config=None, configs=None,
     Returns ``(outcomes, traces)`` exactly like the serial
     ``_sweep_impl`` — and bit-identically to it, per seed, for every
     ``jobs``/``batch`` split. ``stats`` (optional dict) receives the
-    parent-observed per-phase wall windows for bench.py
+    parent-observed per-phase wall windows
     (``host_s``/``pack_s``/``dispatch_s``/``settle_s``/``parent_s``/
     ``rounds``/``drain_rounds``/``resets``).
     """
@@ -448,7 +448,7 @@ def sweep_pooled(world_fn, seeds, *, jobs: int, config=None, configs=None,
                      parent_s=0.0, workers=J, w=W)
 
         def _clk():
-            # Wall-clock phase windows of the pool driver (bench only).
+            # Wall-clock phase windows of the pool driver (stats only).
             return perf_counter()  # detlint: allow[DET001]
     else:
         def _clk():

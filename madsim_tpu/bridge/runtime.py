@@ -769,8 +769,7 @@ def _sweep_impl(world_fn, seeds, *, config=None, configs=None, cap=128,
         mb = kernel.metrics()
         if mb is not None:
             # Fleet aggregate of the kernel's per-slot counters
-            # (docs/observability.md; bench.py records it under
-            # configs.bridge_sweep.sim_metrics).
+            # (docs/observability.md).
             profile["sim_metrics"] = {k: int(v.sum()) for k, v in mb.items()}
             # Behavior-coverage sketch over the same block: the host-side
             # twin of the device sweep's ledger (obs/coverage.py). Bridge

@@ -7,8 +7,8 @@ contract (engine/core.py docstring) says (seed, config) ⇒ bit-exact
 trajectories, *re-runnable anywhere*. Everything in the step function is
 integer or exactly-representable f32 arithmetic, so TPU and CPU must agree
 to the last bit — any divergence is an engine bug (e.g. a reduction order
-leak or a fast-math rewrite), not noise. bench.py runs this in --smoke mode
-every round on the real accelerator.
+leak or a fast-math rewrite), not noise. chip_smoke.py runs this on the
+chip against the CPU backend (phase A).
 """
 from __future__ import annotations
 

@@ -138,7 +138,7 @@ class Worker:
 
     @staticmethod
     def _wall() -> float:
-        # Phase-timing telemetry only (bench.py fleet_sweep breakdown);
+        # Phase-timing telemetry only (``loop_stats["fleet"]``);
         # never feeds a lease or sim decision.
         from time import perf_counter
         return perf_counter()  # detlint: allow[DET001]

@@ -100,7 +100,7 @@ class RaftOptions:
     # Injected bug (same switch as engine/raft_actor.py RaftDeviceConfig):
     # grant votes ignoring the one-vote-per-term rule, so seed sweeps have a
     # real election-safety violation to find. Used by the host↔device
-    # cross-validation benchmark (bench.py time-to-first-bug).
+    # cross-validation (tests/test_crossvalidation.py).
     buggy_double_vote: bool = False
 
 

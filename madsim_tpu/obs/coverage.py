@@ -190,7 +190,7 @@ class SweepCoverage:
         return np.diff(c, prepend=0)
 
     def to_json(self) -> Dict[str, object]:
-        """Compact JSON-safe record (bench_results.json ``coverage``)."""
+        """Compact JSON-safe record (the ``observe=`` summary's block)."""
         curve = [int(x) for x in self.novelty_curve]
         return {
             "n_buckets": int(self.n_buckets),

@@ -16,8 +16,7 @@ leaf* the step updates but never reads for simulation decisions, so:
 The counters survive world recycling for free: they live in the world
 slot, the sweep's slot→seed index attributes them per seed at
 retirement, and ``SweepResult.metrics`` reports per-seed frames plus the
-fleet aggregate (``bench.py`` records the latter under
-``configs.*.sim_metrics``). The bridge kernel carries the analogous
+fleet aggregate. The bridge kernel carries the analogous
 block for host-workload sweeps (``bridge/kernel.py`` ``BridgeMetrics``).
 
 This module deliberately imports nothing from :mod:`madsim_tpu.engine`
@@ -121,7 +120,8 @@ def metrics_from_observations(obs: Dict[str, np.ndarray]
 
 def aggregate_metrics(per_seed: Dict[str, np.ndarray]) -> Dict[str, object]:
     """Fleet-aggregate frame: counters sum over the seed axis; histograms
-    stay per-bin lists. JSON-serializable (bench.py ``sim_metrics``)."""
+    stay per-bin lists. JSON-serializable (``SweepResult.metrics``'s
+    ``aggregate``)."""
     out: Dict[str, object] = {}
     for k, v in per_seed.items():
         s = np.asarray(v).sum(axis=0)

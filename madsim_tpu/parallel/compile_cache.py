@@ -9,8 +9,8 @@ later process find the entries, so it must not move between runs.
 by file path so it fires before any program compiles: jax latches the
 cache at its first compile). When jax is not imported yet it only sets
 environment variables, which jax reads at its own import, so the
-host-only import path stays jax-free and every child process — bench
-children, spawned fleet workers — inherits the same directory.
+host-only import path stays jax-free and every child process — spawned
+fleet workers among them — inherits the same directory.
 Thresholds are zeroed so every program is cached: this codebase's
 programs are few, large, and identical across processes.
 

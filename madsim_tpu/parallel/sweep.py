@@ -468,8 +468,8 @@ class SweepResult:
         default_factory=lambda: np.zeros(0, np.int64))
     # Orchestration telemetry (docs/perf.md "Pipelined orchestration" /
     # "Whole-hunt residency"): dispatch counts, superstep fan-in, and
-    # the host/device wall split of the chunk loop. Recorded into
-    # bench_results.json under configs.*.sweep_loop. Keys: pipelined,
+    # the host/device wall split of the chunk loop, also carried by the
+    # observe= stream's summary record. Keys: pipelined,
     # fused, chunks, dispatches, chunks_per_dispatch,
     # dispatches_per_seed, seeds_per_dispatch, epochs_on_device,
     # dispatch_depth, device_wait_s, host_decision_s, dispatch_s,
@@ -547,7 +547,7 @@ class SweepResult:
         array}, "aggregate": {field: int | [int]}}``. Per-seed rows are
         attributed through the same slot→seed machinery as every other
         observation, so they survive recycling/compaction; the aggregate
-        is the fleet sum (``bench.py`` records it as ``sim_metrics``)."""
+        is the fleet sum."""
         from ..obs.metrics import aggregate_metrics, metrics_from_observations
 
         per_seed = metrics_from_observations(self.observations)
@@ -2758,7 +2758,7 @@ class SweepSession:
         self.superstep_max = int(superstep_max)
         self.coverage_buckets = coverage_buckets
         #: Ranges served without paying a fresh per-lease sweep setup
-        #: (bench.py fleet_sweep reports the fleet-wide sum).
+        #: (``loop_stats["fleet"]["session_reuse_hits"]`` sums them).
         self.reuse_hits = 0
         self._runs = 0
         self._k_warm = 1          # adaptive-K carry across groups

@@ -1120,8 +1120,8 @@ class UdsEndpoint(RealEndpoint):
     `std/net/erpc.rs`, chosen by Cargo feature): here the transport is
     chosen by ``MADSIM_REAL_TRANSPORT=uds``, for same-host deployments
     that want filesystem-scoped addressing and permissions instead of the
-    shared TCP port namespace (latency is comparable to loopback TCP —
-    bench.py measures both). Addresses stay virtual
+    shared TCP port namespace (latency is comparable to loopback TCP).
+    Addresses stay virtual
     ``(ip, port)`` pairs — each maps to one socket file under
     ``MADSIM_UDS_DIR`` (default ``$TMPDIR/madsim-uds-<uid>``) so
     application code is transport-agnostic, like the reference keeping

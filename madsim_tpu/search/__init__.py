@@ -26,8 +26,8 @@ Module map (docs/search.md):
 - :mod:`~madsim_tpu.search.generate` — the jitted harvest+generate
   program (tracelint registry: ``search.generate``).
 - :mod:`~madsim_tpu.search.family` — ``GuidedPairActor``, the
-  conjunction-bug family with observable progress that ``bench.py
-  guided_hunt`` and ``make fuzz-demo`` gate on.
+  conjunction-bug family with observable progress that
+  ``make fuzz-demo`` and tests/test_search.py gate on.
 """
 import dataclasses as _dc
 from typing import Dict as _Dict
@@ -126,7 +126,7 @@ class SearchReport:
         return "\n".join(lines)
 
     def to_json(self) -> _Dict[str, object]:
-        """Compact JSON-safe record (bench_results.json ``search``)."""
+        """Compact JSON-safe record (the ``observe=`` summary's block)."""
         out = {
             "generations": int(self.generations),
             "inserted": int(self.inserted),

@@ -48,8 +48,8 @@ class SearchConfig:
     # False: the corpus never updates past the seeded template — every
     # child is a fresh random mutation of the ORIGINAL schedule. This is
     # the matched random-fuzzing baseline (same operators, same budget,
-    # no coverage feedback) that `bench.py guided_hunt` and
-    # `make fuzz-demo` compare guided search against.
+    # no coverage feedback) that `make fuzz-demo` and
+    # tests/test_search.py compare guided search against.
     guided: bool = True
     # Provenance lanes + per-operator outcome accounting (obs/lineage.py,
     # docs/search.md "Reading the lineage"): every installed child

@@ -15,7 +15,7 @@ both targets in a single mutation pass of the original template — the
 classic staircase argument for why coverage-guided search beats random
 fuzzing on conjunctive bugs (docs/search.md "when guided beats
 random"), here with an exactly measurable seeds-to-bug gap
-(``bench.py guided_hunt``, ``make fuzz-demo``).
+(``make fuzz-demo``, tests/test_search.py).
 
 The template schedule (:func:`family_schedule`) restarts only filler
 nodes: the bug is reachable EXCLUSIVELY through the search's node-
@@ -127,8 +127,8 @@ def engine_config(acfg: GuidedPairConfig = GuidedPairConfig()
                         t_limit_us=2_000_000, metrics=True)
 
 
-# The canonical guided-hunt shape shared by bench.py `guided_hunt`,
-# `make fuzz-demo` and tests/test_search.py: 12 nodes (10 fillers) and a
+# The canonical guided-hunt shape shared by `make fuzz-demo` and
+# tests/test_search.py: 12 nodes (10 fillers) and a
 # 6-row template make a single-pass double-target hit rare — measured
 # seeds-to-bug ~73 guided vs ~409 random under HUNT_SEARCH, the
 # staircase gap the acceptance gate asserts.

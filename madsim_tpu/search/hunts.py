@@ -1,5 +1,5 @@
-"""The two canonical guided hunts: shared by bench.py `guided_hunt`,
-`make fuzz-demo` (tools/fuzz_demo.py) and the acceptance gates.
+"""The canonical guided hunts: shared by `make fuzz-demo`, `make
+actorc-demo` and the acceptance gates (tests/test_search.py).
 
 Both hunts compare coverage-guided search against the MATCHED random-
 mutation baseline (``SearchConfig(guided=False)``: same operators, same
@@ -56,7 +56,7 @@ from .family import (
 
 
 class Hunt(NamedTuple):
-    """One bench/demo hunt setup: build engines with
+    """One demo/test hunt setup: build engines with
     ``DeviceEngine(actor, cfg)`` and sweep with ``template`` +
     ``search(guided=...)``."""
 

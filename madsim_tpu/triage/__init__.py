@@ -19,8 +19,8 @@ turns them into artifacts a human can act on —
   repro bundle with a ``minimization`` provenance block.
   ``triage(result)`` is the entry.
 - :mod:`.synthetic` — the known-minimal-repro fixture actor
-  (``PairRestartActor``) used by tests, ``make triage-demo``, and
-  ``bench.py minimize_bug``.
+  (``PairRestartActor``) used by tests/test_triage.py and
+  ``make triage-demo``.
 
 See docs/triage.md for the algebra, the oracle contract, and the bundle
 schema; determinism (same inputs → bitwise-identical minimized

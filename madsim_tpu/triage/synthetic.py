@@ -6,12 +6,12 @@ conjunction over fault-schedule rows, so a schedule's minimal failing
 subset is exactly {the one row restarting ``node_a``, the one row
 restarting ``node_b``} when every other row restarts filler nodes.
 
-That known answer is what makes it the triage test fixture, the
-``make triage-demo`` workload, and the ``bench.py minimize_bug``
-config: the batched ddmin loop (triage/minimize.py) must converge to
-exactly those two rows, bitwise-identically across runs and across the
-serial/pipelined sweep paths, and the 1-minimality verification has
-ground truth to be checked against.
+That known answer is what makes it the triage test fixture and the
+``make triage-demo`` workload: the batched ddmin loop
+(triage/minimize.py) must converge to exactly those two rows,
+bitwise-identically across runs and across the serial/pipelined sweep
+paths, and the 1-minimality verification has ground truth to be
+checked against.
 
 It is also registered in the replay registry (obs/cli.py, actor name
 ``pair_restart``), so minimized repro bundles emitted by the corpus

@@ -12,9 +12,11 @@ also steers ``lanes.gathers_are_cheap`` to the TPU's answer: traced
 here, the engine would otherwise see the CPU backend and compile its
 gather form.
 
-Also the CPU checks of the bring-up repairs that need no topology:
-bench.py refuses to report a device number without a TPU.
+Also the CPU checks that need no topology: chip_smoke.py refuses to run
+without a TPU, and its phase B passes its checks on the CPU at a small
+width.
 """
+import json
 import os
 import subprocess
 import sys
@@ -212,19 +214,6 @@ def test_sharded_chunk_all_reduces_over_four_chips(topo):
     assert "all-reduce" in comp.as_text()
 
 
-def test_bench_without_smoke_needs_a_tpu(tmp_path):
-    """Outside --smoke a device config that finds no TPU fails the run
-    instead of recording a null number and exiting 0."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               MADSIM_BENCH_RESULTS=str(tmp_path / "r.json"))
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--only", "3node",
-         "--worlds", "256"], env=env, cwd=tmp_path, capture_output=True,
-        text=True, timeout=300)
-    assert out.returncode != 0
-    assert "no TPU found" in out.stderr
-
-
 def test_chip_smoke_refuses_the_cpu(tmp_path):
     """chip_smoke.py exits non-zero and prints no result without a TPU,
     and outside a checkout."""
@@ -238,3 +227,15 @@ def test_chip_smoke_refuses_the_cpu(tmp_path):
     out = subprocess.run([sys.executable, str(alone)], env=env,
                          capture_output=True, text=True, timeout=300)
     assert out.returncode != 0 and out.stdout == ""
+
+
+def test_chip_smoke_phase_b_on_cpu(capsys):
+    """Phase B (5-node Raft under per-world kill/restart and link clogs
+    from ``make_fault_schedules``) passes its checks on the CPU at 256
+    worlds: ``_emit`` raises ``CheckFailed`` on any false check, and the
+    printed line carries each one."""
+    chip_smoke.phase_b(w=256)
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["phase"] == "B" and line["W"] == 256
+    for check in ("no_live_world", "no_bug", "no_overflow", "rerun_bitwise"):
+        assert line["checks"][check] is True, check

@@ -77,8 +77,8 @@ def test_postgres_select_now_observes_server_skew():
 
 def test_host_model_finds_injected_double_vote_bug():
     """Sweeping seeds on the buggy host model must trip the election-safety
-    checker at a nonzero rate (cross-validated against the device rate in
-    bench.py time_to_first_bug)."""
+    checker at a nonzero rate (the device actor's side is
+    test_device_actor_finds_injected_double_vote_bug)."""
     from madsim_tpu.models.raft import (
         RaftCluster, RaftOptions, RaftInvariantViolation)
 
@@ -129,7 +129,7 @@ def test_clean_device_actor_flags_no_bugs():
 
 def test_crosscheck_cpu_devices_bit_identical():
     """Backend crosscheck machinery on two CPU devices of the test mesh
-    (bench.py runs the real TPU-vs-CPU version every round)."""
+    (chip_smoke.py runs the real TPU-vs-CPU version on the chip)."""
     import jax
 
     from madsim_tpu.engine import (

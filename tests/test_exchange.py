@@ -360,6 +360,24 @@ def test_epoch0_matches_plain_fleet_and_seeding_changes_later_epochs(hunt):
     assert st["publishes"] == 2 and st["epochs_merged"] == 1
 
 
+def test_exchange_reaches_a_bug_no_range_reaches_alone(hunt):
+    """The fleet-level staircase: 64-seed ranges are shorter than the
+    ~73 seeds the pair bug needs, so no range of an independent fleet
+    reaches it alone, while the exchanged fleet (320 seeds, 2 workers)
+    chains corpus progress across epochs and does (39 seeds into its
+    fifth range): more bugs, in fewer seeds into a range."""
+    eng, cfg, tmpl = hunt
+    kw = dict(n_seeds=320, range_size=64, stop_on_first_bug=True)
+    independent = _fleet(eng, cfg, tmpl, **kw)
+    exchanged = _fleet(eng, cfg, tmpl, exchange=ExchangeConfig(every=1),
+                       **kw)
+    assert not independent.failing_seeds, \
+        "a 64-seed range reached the pair bug alone: the family lost " \
+        "its staircase (retune search/family.py)"
+    assert exchanged.failing_seeds, \
+        "the exchange did not chain corpus progress across epochs"
+
+
 def test_exchanged_fleet_resumes_coordinator_state_end_to_end(hunt,
                                                              tmp_path):
     """A second fleet run over a pre-populated exchange state (the

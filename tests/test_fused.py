@@ -306,8 +306,7 @@ def test_fused_zero_step_budget_runs_no_chunks(raft_eng):
 
 def test_fused_loop_stats_schema(raft_eng):
     """The documented loop_stats schema on the fused path, plus the two
-    new dispatch-economics keys on EVERY path (make smoke asserts them
-    through bench_results.json)."""
+    new dispatch-economics keys on EVERY path."""
     res = sweep(None, raft_eng.cfg, np.arange(48), engine=raft_eng,
                 chunk_steps=64, max_steps=2_048, fused=True)
     ls = res.loop_stats

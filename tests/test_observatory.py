@@ -1,6 +1,6 @@
 """Sweep observatory (docs/observability.md "The sweep observatory"):
 live telemetry stream, Prometheus snapshots, profiler capture windows,
-the `watch` CLI, and the bench_diff regression tool.
+and the `watch` CLI.
 
 The load-bearing contracts: telemetry/profiling are host-side
 observation only (observe-on and profile-on sweeps are bitwise
@@ -401,71 +401,6 @@ def test_profile_window_validation(eng_off, tmp_path):
               profile_dir=str(tmp_path / "p"), profile_window=(3, 3))
     # window is ignored entirely when no profile_dir is given.
     observatory.ProfilerWindow(None, (9, 9)).before_dispatch()
-
-
-# ---------------------------------------------------------------------------
-# tools/bench_diff.py — the regression table
-# ---------------------------------------------------------------------------
-
-def _bench_doc(seeds_per_sec, flops, distinct=8):
-    return {
-        "metric": "madraft_3node_1s_seeds_per_sec",
-        "value": seeds_per_sec, "unit": "seeds/s", "vs_baseline": 100.0,
-        "configs": {
-            "madraft_5node": {
-                "seeds_per_sec": seeds_per_sec / 10,
-                "world_utilization": 0.9,
-                "xla_cost": {"flops_per_world_step": flops},
-                "sweep_loop": {"chunks_per_dispatch": 4.0,
-                               "host_decision_s": 0.01,
-                               "loop_wall_s": 1.0},
-                "coverage": {"distinct_behaviors": distinct},
-            },
-        },
-    }
-
-
-def test_bench_diff_table_and_regression_gate(tmp_path, capsys):
-    import tools.bench_diff as bd
-
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    old.write_text(json.dumps(_bench_doc(100_000.0, 8_000.0)))
-    # Faster headline, but a flop regression past any threshold.
-    new.write_text(json.dumps(_bench_doc(120_000.0, 16_000.0)))
-    rc = bd.main([str(old), str(new)])
-    out = capsys.readouterr().out
-    assert rc == 0  # informational by default
-    assert "headline seeds/s" in out and "+20.0%" in out
-    assert "REGRESSED" in out  # flops doubled, lower-is-better
-    rc = bd.main([str(old), str(new), "--fail-on-regress", "50"])
-    assert rc == 1  # the 100% flop regression trips the gate
-    rc = bd.main([str(old), str(new), "--fail-on-regress", "150"])
-    assert rc == 0  # within tolerance
-
-
-def test_bench_diff_loads_wrapper_shapes(tmp_path):
-    import tools.bench_diff as bd
-
-    doc = _bench_doc(50_000.0, 7_000.0)
-    raw = tmp_path / "bench_results.json"
-    raw.write_text(json.dumps(doc))
-    assert bd.load_round(str(raw))["value"] == 50_000.0
-    wrapped = tmp_path / "BENCH_r09.json"
-    wrapped.write_text(json.dumps({"n": 9, "rc": 0, "parsed": doc}))
-    assert bd.load_round(str(wrapped))["value"] == 50_000.0
-    # parsed=null with the result's JSON line surviving in the tail.
-    tail = tmp_path / "BENCH_r10.json"
-    tail.write_text(json.dumps(
-        {"n": 10, "rc": 0, "parsed": None,
-         "tail": "noise\n" + json.dumps(doc) + "\n"}))
-    assert bd.load_round(str(tail))["value"] == 50_000.0
-    # Unrecoverable (head-truncated) tail → a clear error.
-    bad = tmp_path / "BENCH_r11.json"
-    bad.write_text(json.dumps({"n": 11, "parsed": None,
-                               "tail": "…cut} {also-not-json"}))
-    with pytest.raises(ValueError, match="no parsable result"):
-        bd.load_round(str(bad))
 
 
 def test_watch_renders_fused_search_cadence():

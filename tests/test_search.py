@@ -6,7 +6,8 @@ The closed-fuzzer-loop contract (docs/search.md):
   and across ``pipeline=True/False`` (the mutation lanes are counter-
   based splitmix64, the corpus fold is sequential and deterministic);
 - guided search measurably beats the matched random-mutation baseline
-  on the conjunction family (the staircase argument);
+  on the conjunction family (the staircase argument) and, in the
+  canonical hunts, on the actorc Paxos family too;
 - corpus + per-slot schedule state survives checkpoint→resume
   bit-exactly through the PR 7 aux-array channel;
 - ``search=None`` sweeps compile the exact pre-search programs (the
@@ -44,6 +45,7 @@ from madsim_tpu.search import (
     family_schedule,
 )
 from madsim_tpu.search.family import HUNT_NODES, HUNT_ROWS, hunt_search_config
+from madsim_tpu.search.hunts import pair_hunt, paxos_hunt
 
 sweep_mod = importlib.import_module("madsim_tpu.parallel.sweep")
 sweep = sweep_mod.sweep
@@ -187,8 +189,7 @@ def test_guided_beats_random_on_the_family(hunt):
     """The acceptance gate's core claim at test scale: on the
     conjunction family, guided search reaches the bug inside a budget
     the matched random-mutation baseline cannot (the full measured gap
-    — ~73 vs ~409 seeds — is `bench.py guided_hunt` / `make
-    fuzz-demo`)."""
+    — ~73 vs ~409 seeds — is `make fuzz-demo`)."""
     eng, cfg, tmpl = hunt
     g = _guided(eng, cfg, tmpl, 128, stop_on_first_bug=True)
     r = _guided(eng, cfg, tmpl, 128, guided=False, stop_on_first_bug=True)
@@ -199,6 +200,31 @@ def test_guided_beats_random_on_the_family(hunt):
     # The novelty curve actually grew: feedback is flowing.
     assert g.search.corpus_size > 1
     assert g.coverage.novelty_curve[-1] > 1
+
+
+@pytest.mark.parametrize("make", [pair_hunt, paxos_hunt],
+                         ids=["pair", "paxos"])
+def test_canonical_hunt_guided_reaches_the_bug_first(make):
+    """The canonical hunts of search/hunts.py (`make fuzz-demo`'s pair
+    family, `make actorc-demo`'s forgetful-acceptor Paxos) at a 256-seed
+    budget: guided search reaches the bug in strictly fewer seeds than
+    the matched random baseline, which may miss it (pair: 73 seeds,
+    Paxos: 191; random finds neither), and the find descends from at
+    least one mutation."""
+    h = make()
+    eng = DeviceEngine(h.actor, h.cfg)
+
+    def hunt_once(guided):
+        return sweep(None, h.cfg, np.arange(256), engine=eng,
+                     faults=h.template, stop_on_first_bug=True,
+                     search=h.search(guided), **h.sweep_kw)
+
+    g, r = hunt_once(True), hunt_once(False)
+    assert g.failing_seeds, f"guided search missed the {h.name} bug"
+    assert not r.failing_seeds or \
+        g.failing_seeds[0] < r.failing_seeds[0], \
+        (g.failing_seeds[0], r.failing_seeds[0])
+    assert g.search.lineage_depth() >= 1
 
 
 def test_guided_find_triages_to_the_two_target_restarts(hunt):

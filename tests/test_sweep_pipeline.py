@@ -330,8 +330,8 @@ def test_loop_stats_schema_both_paths(raft_eng, pipeline):
 
 
 def test_superstep_telemetry_fields(raft_eng):
-    """SweepResult.loop_stats carries the bench contract fields
-    (bench_results.json configs.*.sweep_loop, asserted by make smoke)."""
+    """SweepResult.loop_stats carries the documented telemetry fields
+    (docs/perf.md "Pipelined orchestration")."""
     res = sweep(None, raft_eng.cfg, np.arange(48), engine=raft_eng,
                 chunk_steps=64, max_steps=512)
     need = {"pipelined", "fused", "chunks", "dispatches",

@@ -34,8 +34,8 @@ BUDGET = 512
 # guided hunts are bitwise-deterministic, so these exact values hold
 # until MUTATION/GENERATION code changes — at which point the PR 11
 # retune-and-re-pin rule applies: retune search/family.py + hunts.py,
-# re-measure with `bench.py --only guided`, and re-pin here AND in the
-# ROADMAP recap. A drift WITHOUT a mutation-code change means search
+# re-measure with `make fuzz-demo`, and re-pin here, in
+# tests/test_search.py AND in the ROADMAP recap. A drift WITHOUT a mutation-code change means search
 # semantics regressed silently — that is what this gate exists to catch
 # (PR 12 satellite: exchange/fleet work must not move these).
 PIN_PAIR_GUIDED = 73    # guided seeds-to-bug, pair family
