@@ -23,6 +23,7 @@ Semantics carried over from the reference host engine:
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
@@ -334,18 +335,36 @@ class DeviceEngine:
             raise ValueError("actor.num_kinds must be <= 64")
         self.actor = actor
         self.cfg = cfg
-        self._step_one = self._build_step()
+        self._set_step(self._build_step())
+        # Built once: jit's own cache keys on the fault-array shape, so
+        # repeated init() calls (and every sweep) reuse the compilation
+        # instead of paying a fresh trace per call.
+        self._init_batched = jax.jit(jax.vmap(self._init_one))
+        # refill's select donates the old state (the merged batch aliases
+        # it in place); the fresh batch is NOT donated — the select can
+        # only alias one source, and donating both just trips XLA's
+        # "donated buffer not usable" warning for the loser.
+        self._refill_select = jax.jit(tree_select_worlds,
+                                      donate_argnums=(2,))
+
+    #: Whether the run loops trace the step with its fault path. Only
+    #: the twin :meth:`_form` builds has False.
+    fault_path = True
+
+    def _set_step(self, step_one) -> None:
+        """Install the per-world step and the run loops built on it."""
+        self._step_one = step_one
         # The batched step the run loops iterate: a plain vmap of the
         # per-world step, or — with cfg.pallas — the same step fused
         # into one pl.pallas_call (engine/pallas_step.py) so every lane
         # update shares one VMEM residency. Bitwise identical by
         # construction: the kernel body IS the vmapped step.
-        if cfg.pallas:
+        if self.cfg.pallas:
             from .pallas_step import make_pallas_step
 
-            self._batched_step = make_pallas_step(self._step_one, cfg)
+            self._batched_step = make_pallas_step(step_one, self.cfg)
         else:
-            self._batched_step = jax.vmap(self._step_one)
+            self._batched_step = jax.vmap(step_one)
         self.step = jax.jit(self._batched_step)
         # The run loops DONATE their input state: XLA aliases the output
         # onto the argument buffers and updates the 200-400 MB world state
@@ -357,16 +376,40 @@ class DeviceEngine:
                                   donate_argnums=0)
         self._run = jax.jit(self._run_impl, static_argnums=1,
                             donate_argnums=0)
-        # Built once: jit's own cache keys on the fault-array shape, so
-        # repeated init() calls (and every sweep) reuse the compilation
-        # instead of paying a fresh trace per call.
-        self._init_batched = jax.jit(jax.vmap(self._init_one))
-        # refill's select donates the old state (the merged batch aliases
-        # it in place); the fresh batch is NOT donated — the select can
-        # only alias one source, and donating both just trips XLA's
-        # "donated buffer not usable" warning for the loser.
-        self._refill_select = jax.jit(tree_select_worlds,
-                                      donate_argnums=(2,))
+
+    def _form(self, fault_path: bool) -> "DeviceEngine":
+        """This engine, or (``fault_path=False``) its twin whose step is
+        traced without the fault path, for batches in which no world
+        holds a fault event.
+
+        Only fault events write ``alive``, ``gen``, ``paused``,
+        ``clog_node`` and ``clog_link``; without one every node stays
+        alive and unpaused at generation 0 and no link is clogged. So
+        the twin's step leaves out ``apply_fault`` and its selects, the
+        pause mask of the pop, the stale and dead checks and the clog
+        and generation lookups of the push, and reaches the same state
+        bit for bit. Message loss stays: it is world data, not a fault.
+        The twin refuses fault rows (:meth:`init`, :meth:`refill`,
+        :meth:`refill_traced`), so it never holds a world its step
+        would get wrong. It shares everything else with this engine;
+        the sweep keys its compiled programs on ``fault_path``."""
+        if fault_path:
+            return self
+        twin = self.__dict__.get("_fault_free")
+        if twin is None:
+            twin = copy.copy(self)
+            twin.fault_path = False
+            twin._set_step(self._build_step(fault_path=False))
+            self.__dict__["_fault_free"] = twin
+        return twin
+
+    def _admit_fault_rows(self, n_rows: int) -> None:
+        """Refuse ``n_rows`` fault rows a world on the fault-free twin."""
+        if n_rows and not self.fault_path:
+            raise ValueError(
+                f"this engine's step is traced without its fault path and "
+                f"cannot take fault rows ({n_rows} a world): run batches "
+                f"with faults on the engine itself")
 
     # ------------------------------------------------------------------
     # Initialization
@@ -433,6 +476,7 @@ class DeviceEngine:
                 raise ValueError("net-config fault rows carry their params "
                                  f"in the payload: payload_words must be "
                                  f">= {need_words} (packed={self.cfg.packed})")
+        self._admit_fault_rows(faults.shape[-2])
 
         if configs is None:
             configs = np.array([self.cfg.latency_min_us,
@@ -636,6 +680,7 @@ class DeviceEngine:
         a blocking device→host sync in the middle of the sweep loop.
         Callers own the validity contract (see :meth:`refill`).
         """
+        self._admit_fault_rows(faults.shape[-2])
         seeds = np.asarray(seeds, dtype=np.uint64)
         w = seeds.shape[0]
         lo = (seeds & np.uint64(0xFFFFFFFF)).astype(np.uint32)
@@ -655,7 +700,10 @@ class DeviceEngine:
     # ------------------------------------------------------------------
     # The per-world step
     # ------------------------------------------------------------------
-    def _build_step(self) -> Callable[[WorldState], WorldState]:
+    def _build_step(self, fault_path: bool = True
+                    ) -> Callable[[WorldState], WorldState]:
+        """The per-world step; ``fault_path=False`` traces it without the
+        fault path, for worlds that hold no fault event (:meth:`_form`)."""
         cfg = self.cfg
         actor = self.actor
         num_kinds = int(actor.num_kinds)  # kind_hist width (metrics)
@@ -738,17 +786,23 @@ class DeviceEngine:
             lat = _u32_to_range(even, ws.lat_min, ws.lat_max)      # (M,)
             u = _u32_to_unit_f32(odd)                              # (M,)
             dst = jnp.clip(ob.dst, 0, cfg.n_nodes - 1)             # (M,)
-            clogged = take_small(ws.clog_node, src) \
-                | take_small(ws.clog_node, dst) \
-                | take_small(take_small(ws.clog_link, src), dst)   # (M,)
-            dropped = (~ob.is_timer) & (clogged | (u < loss))
+            if fault_path:
+                clogged = take_small(ws.clog_node, src) \
+                    | take_small(ws.clog_node, dst) \
+                    | take_small(take_small(ws.clog_link, src), dst)  # (M,)
+                dropped = (~ob.is_timer) & (clogged | (u < loss))
+            else:
+                dropped = (~ob.is_timer) & (u < loss)
             # Saturating schedule time: now + delay can wrap int32 when
             # t_limit_us or an actor delay is near 2^31. Both operands
             # are <= INF_TIME, so min-before-add cannot overflow.
             delay = jnp.maximum(jnp.where(ob.is_timer, ob.delay_us, lat), 0)
             t = ws.now + jnp.minimum(delay, INF_TIME - ws.now)
             flags = jnp.where(ob.is_timer, FLAG_TIMER, 0).astype(jnp.int32)
-            gen_dst = widen(take_small(ws.gen, dst))  # wide in flight
+            if fault_path:
+                gen_dst = widen(take_small(ws.gen, dst))  # wide in flight
+            else:
+                gen_dst = jnp.zeros((m,), jnp.int32)
             # Gated on the world's (pre-step) active flag: frozen worlds
             # write nothing into the queue, which is what lets the step's
             # tail skip the whole-state frozen-world restore select.
@@ -821,10 +875,12 @@ class DeviceEngine:
             # The named scopes (madsim/pop, fault, handle, push) tag the
             # ops' metadata for the device trace; they change no op.
             with jax.named_scope("madsim/pop"):
-                q, ev, found, slot = pop_indexed(
-                    ws.queue,
-                    eligible_mask(ws.queue, ws.paused, cfg.n_nodes)
-                    & ws.active)
+                if fault_path:
+                    eligible = (eligible_mask(ws.queue, ws.paused,
+                                              cfg.n_nodes) & ws.active)
+                else:
+                    eligible = ws.active
+                q, ev, found, slot = pop_indexed(ws.queue, eligible)
                 now = jnp.where(found, jnp.maximum(ws.now, ev.time), ws.now)
                 in_time = now < jnp.int32(cfg.t_limit_us)
                 ws1 = ws._replace(queue=q, now=now, steps=ws.steps + 1,
@@ -832,28 +888,43 @@ class DeviceEngine:
                                   - found.astype(ws.qdepth.dtype))
 
                 dst = jnp.clip(ev.dst, 0, cfg.n_nodes - 1)
-                is_fault = (ev.flags & FLAG_FAULT) != 0
+                if fault_path:
+                    is_fault = (ev.flags & FLAG_FAULT) != 0
                 is_timer = (ev.flags & FLAG_TIMER) != 0
-                # Generations compare modulo the packed width
-                # (queue.GEN_MASK).
-                stale = is_timer & (ev.gen != (widen(take_small(ws1.gen, dst))
-                                               & GEN_MASK))
-                dead = ~take_small(ws1.alive, dst)
+                if fault_path:
+                    # Generations compare modulo the packed width
+                    # (queue.GEN_MASK).
+                    stale = is_timer & (
+                        ev.gen != (widen(take_small(ws1.gen, dst))
+                                   & GEN_MASK))
+                    dead = ~take_small(ws1.alive, dst)
+                else:
+                    # No fault event: every node alive at generation 0.
+                    # Constants, not a read of the flag: reading it (and
+                    # the src select it feeds) made this step ~7% slower
+                    # on a v5e, though XLA's cost model priced it lower.
+                    is_fault = stale = dead = np.False_
                 deliver = found & in_time & ~is_fault & ~stale & ~dead
                 do_fault = found & in_time & is_fault
 
-            with jax.named_scope("madsim/fault"):
-                fault_ws, fault_ob = apply_fault(ws1, ev)
+            if fault_path:
+                with jax.named_scope("madsim/fault"):
+                    fault_ws, fault_ob = apply_fault(ws1, ev)
             with jax.named_scope("madsim/handle"):
                 astate2, act_ob, rng2, hbug = actor.handle(
                     cfg, ws1.astate, ev, now, ws1.rng)
             act_ws = ws1._replace(astate=astate2, rng=rng2)
 
-            ws2 = tree_select(do_fault, fault_ws,
-                              tree_select(deliver, act_ws, ws1))
-            ob = tree_select(do_fault, fault_ob,
-                             tree_select(deliver, act_ob, Outbox.empty(cfg)))
-            src = jnp.where(do_fault, jnp.clip(ev.src, 0, cfg.n_nodes - 1), dst)
+            ws2 = tree_select(deliver, act_ws, ws1)
+            if fault_path:
+                ws2 = tree_select(do_fault, fault_ws, ws2)
+            ob = tree_select(deliver, act_ob, Outbox.empty(cfg))
+            if fault_path:
+                ob = tree_select(do_fault, fault_ob, ob)
+                src = jnp.where(do_fault,
+                                jnp.clip(ev.src, 0, cfg.n_nodes - 1), dst)
+            else:
+                src = dst
             with jax.named_scope("madsim/push"):
                 ws3 = push_outbox(ws2, src, ob, ws.queue, (slot, found))
 
@@ -1218,6 +1289,7 @@ class DeviceEngine:
         worlds bit-identical to :meth:`refill`'s, because both run the
         same ``vmap``'d ``_init_one`` (jit does not change values).
         """
+        self._admit_fault_rows(faults.shape[-2])
         w = state.active.shape[0]
         lat_min = jnp.full((w,), int(self.cfg.latency_min_us), jnp.int32)
         lat_max = jnp.full((w,), int(self.cfg.latency_max_us), jnp.int32)

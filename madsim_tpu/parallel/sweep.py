@@ -79,7 +79,8 @@ def _cov_reducers(mesh: Mesh):
 
 def sharded_engine(eng: DeviceEngine, mesh: Mesh, chunk_steps: int = 512,
                    donate: bool = False,
-                   coverage: Optional[int] = None):
+                   coverage: Optional[int] = None,
+                   fault_path: bool = True):
     """Compile a chunk runner: state → (state, any_bug, n_active,
     shard_steps).
 
@@ -107,21 +108,26 @@ def sharded_engine(eng: DeviceEngine, mesh: Mesh, chunk_steps: int = 512,
     itself is untouched, so trajectories stay bitwise identical and with
     ``coverage=None`` this compiles the exact pre-coverage program).
 
-    Runners are cached per (mesh, chunk_steps, donate, coverage) on the
-    engine, so repeated sweeps reuse the compiled program instead of
-    paying a fresh XLA compile for an identical closure.
+    ``fault_path=False`` traces the step without its fault path
+    (``DeviceEngine._form``): only for batches that hold no fault event.
+
+    Runners are cached per (mesh, chunk_steps, donate, coverage,
+    fault_path) on the engine, so repeated sweeps reuse the compiled
+    program instead of paying a fresh XLA compile for an identical
+    closure.
     """
     cache = eng.__dict__.setdefault("_sharded_runner_cache", {})
-    key = (mesh, chunk_steps, donate, coverage)
+    key = (mesh, chunk_steps, donate, coverage, fault_path)
     if key in cache:
         return cache[key]
+    form = eng._form(fault_path)
     spec = world_spec(mesh)
     axes = tuple(mesh.axis_names)
     sp = scalar_spec()
 
     def run(state: WorldState):
         steps0 = state.steps
-        state = eng._run_steps_impl(state, chunk_steps)
+        state = form._run_steps_impl(state, chunk_steps)
         any_bug = jax.lax.psum(
             jnp.any(state.bug).astype(jnp.int32), axes) > 0
         n_active = jax.lax.psum(
@@ -160,7 +166,8 @@ def sharded_engine(eng: DeviceEngine, mesh: Mesh, chunk_steps: int = 512,
 def sharded_superstep(eng: DeviceEngine, mesh: Mesh, chunk_steps: int,
                       k_max: int, donate: bool = False,
                       min_one: bool = False,
-                      coverage: Optional[int] = None):
+                      coverage: Optional[int] = None,
+                      fault_path: bool = True):
     """Compile a superstep runner:
     ``(state, stop_threshold, stop_on_bug, k_chunks) → (state, any_bug,
     n_active, k_done, hist, shard_steps)`` (``shard_steps`` as in
@@ -196,11 +203,14 @@ def sharded_superstep(eng: DeviceEngine, mesh: Mesh, chunk_steps: int,
     superstep (entry condition already false) folds nothing, which is
     what keeps the ledger — like everything else — bitwise identical
     between the dispatch-ahead and serial loops.
+
+    ``fault_path`` as in :func:`sharded_engine`, and part of the key.
     """
     cache = eng.__dict__.setdefault("_sharded_superstep_cache", {})
-    key = (mesh, chunk_steps, k_max, donate, min_one, coverage)
+    key = (mesh, chunk_steps, k_max, donate, min_one, coverage, fault_path)
     if key in cache:
         return cache[key]
+    form = eng._form(fault_path)
     spec = world_spec(mesh)
     axes = tuple(mesh.axis_names)
     sp = scalar_spec()
@@ -208,7 +218,7 @@ def sharded_superstep(eng: DeviceEngine, mesh: Mesh, chunk_steps: int,
 
     if coverage is None:
         def sstep(state: WorldState, stop_threshold, stop_on_bug, k_chunks):
-            return eng._superstep_impl(
+            return form._superstep_impl(
                 state, stop_threshold, stop_on_bug, k_chunks,
                 chunk_steps=chunk_steps, k_max=k_max,
                 reduce_sum=rsum, min_one=min_one)
@@ -228,7 +238,7 @@ def sharded_superstep(eng: DeviceEngine, mesh: Mesh, chunk_steps: int,
                 return fold_retired(h, f, s.metrics, mask, idx, rsum, rmin)
 
             (state, any_bug, n_active, k_done, hist, (hits, first), ch,
-             ran) = eng._superstep_impl(
+             ran) = form._superstep_impl(
                 state, stop_threshold, stop_on_bug, k_chunks,
                 chunk_steps=chunk_steps, k_max=k_max,
                 reduce_sum=rsum, min_one=min_one,
@@ -1019,6 +1029,14 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                     f"faults must be (F, 4) or (n_seeds, F, 4); got "
                     f"{faults_p.ndim}-D shape {faults_p.shape}")
 
+        # The step runs its fault path only where a world can hold a
+        # fault event: fault rows at init or at a refill (guided
+        # children are fault rows). ``form`` inits and refills, and
+        # refuses fault rows without it.
+        fault_path = search_on or (faults_p is not None
+                                   and faults_p.shape[-2] > 0)
+        form = eng._form(fault_path)
+
         def batch_faults(ids: np.ndarray):
             """Fault rows for the worlds holding the given seed ids."""
             if faults_p is None:
@@ -1080,7 +1098,7 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
             state = shard_worlds(state, mesh)
             resumed = True
         else:
-            state = shard_worlds(eng.init(
+            state = shard_worlds(form.init(
                 seeds_p[:w0], faults=batch_faults(np.arange(w0))), mesh)
 
     writer = (_AsyncCheckpointer(eng, checkpoint_path, seeds_meta)
@@ -1536,14 +1554,14 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                         state, slot_sched, idx, corpus, jnp.int32(n_act),
                         new_ids)
             state = shard_worlds(
-                eng.refill(state, mask, seeds_p[fill_ids],
-                           faults=children), mesh)
+                form.refill(state, mask, seeds_p[fill_ids],
+                            faults=children), mesh)
             slot_sched = jnp.where(
                 jnp.asarray(mask)[:, None, None], children, slot_sched)
         else:
             state = shard_worlds(
-                eng.refill(state, mask, seeds_p[fill_ids],
-                           faults=batch_faults(fill_ids)), mesh)
+                form.refill(state, mask, seeds_p[fill_ids],
+                            faults=batch_faults(fill_ids)), mesh)
         idx = jnp.where(jnp.asarray(np.arange(w_cur) >= n_act),
                         jnp.asarray(repl), idx)
         return obs_t, idx_t, tail_len, sched_t, stats_t, lin_t, op_t
@@ -1684,7 +1702,7 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                 chunk_steps=chunk_steps, k_bucket=fused_k_bucket,
                 cov_k=(cov_k if cov_on else None),
                 lineage_on=lineage_on, fault_mode=fault_mode,
-                recycle=recycle)
+                recycle=recycle, fault_path=fault_path)
             stop = False
             first = True
             # "first" forces one dispatch even when max_steps <= 0: a
@@ -1822,7 +1840,8 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
                 runner = sharded_superstep(
                     eng, mesh, chunk_steps, superstep_max, donate,
                     min_one=epoch_fresh,
-                    coverage=cov_k if cov_on else None)
+                    coverage=cov_k if cov_on else None,
+                    fault_path=fault_path)
                 epoch_fresh = False
                 prof.before_dispatch()
                 with tr.span("madsim:superstep", "dispatch_s"):
@@ -1945,7 +1964,8 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
         else:
             # -- serial per-chunk reference loop ---------------------------
             runner = sharded_engine(eng, mesh, chunk_steps, donate=donate,
-                                    coverage=cov_k if cov_on else None)
+                                    coverage=cov_k if cov_on else None,
+                                    fault_path=fault_path)
             while steps < max_steps:
                 prof.before_dispatch()
                 with tr.span("madsim:chunk", "dispatch_s"):
@@ -2144,6 +2164,9 @@ def sweep(actor: Any, cfg: EngineConfig, seeds, faults: Optional[np.ndarray] = N
             # (madsim:identity, checkpointed sweeps only).
             "fused_setup_cache_hits": int(fused_setup_hit),
             "identity_hashes": tr.entered["madsim:identity"],
+            # 1 when the step ran its fault path, 0 when no world could
+            # hold a fault event (DeviceEngine._form).
+            "fault_path": int(fault_path),
             "dispatch_depth": int(perf["dispatch_depth"]),
             "scalar_fetches": tr.entered["madsim:wait"],
             "retire_fetches": int(perf["retire_fetches"]),
@@ -2462,7 +2485,7 @@ _FUSED_K_CAP = 4096
 def _fused_hunt(eng: DeviceEngine, mesh: Mesh, scfg, *, w: int,
                 n_ids_b: int, f_rows: int, chunk_steps: int,
                 k_bucket: int, cov_k: Optional[int], lineage_on: bool,
-                fault_mode: str, recycle: bool):
+                fault_mode: str, recycle: bool, fault_path: bool = True):
     """Compile (and cache per engine) the whole-hunt fused program.
 
     One plain-``jit`` dispatch runs the ENTIRE occupancy loop the serial
@@ -2499,12 +2522,14 @@ def _fused_hunt(eng: DeviceEngine, mesh: Mesh, scfg, *, w: int,
     replicated table), ``shared`` (broadcast the template) or ``none``.
     The phases' ops carry ``jax.named_scope`` names (``madsim/compact``
     and kin, docs/observability.md) for the device trace.
+    ``fault_path`` as in :func:`sharded_engine`.
     """
     cache = eng.__dict__.setdefault("_fused_hunt_cache", {})
     key = (mesh, w, n_ids_b, f_rows, chunk_steps, k_bucket, cov_k,
-           scfg, lineage_on, fault_mode, recycle)
+           scfg, lineage_on, fault_mode, recycle, fault_path)
     if key in cache:
         return cache[key]
+    form = eng._form(fault_path)
 
     from ..obs.coverage import distinct_count, fold_retired_local
 
@@ -2586,8 +2611,8 @@ def _fused_hunt(eng: DeviceEngine, mesh: Mesh, scfg, *, w: int,
         # (4) Re-key the refilled slots: the traced twin of
         # DeviceEngine.refill (same vmapped _init_one, same select).
         with jax.named_scope("madsim/rekey"):
-            s = eng.refill_traced(s, fill, tabs["lo"][fill_ids],
-                                  tabs["hi"][fill_ids], f_new)
+            s = form.refill_traced(s, fill, tabs["lo"][fill_ids],
+                                   tabs["hi"][fill_ids], f_new)
             ex["idx"] = jnp.where(rows_r >= n_act, repl, idx)
             ex["cursor"] = ex["cursor"] + take
             ex["epochs"] = ex["epochs"] + jnp.int32(1)
@@ -2653,7 +2678,7 @@ def _fused_hunt(eng: DeviceEngine, mesh: Mesh, scfg, *, w: int,
             return s, ex, stop
 
         state, ex, any_bug, n_active, k_done, hist = \
-            eng._fused_superstep_impl(
+            form._fused_superstep_impl(
                 state, ex, stop_on_bug, k_chunks,
                 chunk_steps=chunk_steps, k_max=k_bucket,
                 post_chunk=post_chunk, entry_stop=entry_stop)
@@ -2891,17 +2916,21 @@ class SweepSession:
                          faults_init[:1].repeat(w - n_tot, axis=0)], axis=0)
 
         # -- install: recycle the standing slots, else fresh init ---------
+        # Every slot is installed anew, so the group's own fault rows
+        # decide the step's form, as in sweep().
+        fault_path = faults_init is not None and faults_init.shape[-2] > 0
+        form = eng._form(fault_path)
         with tr.span("madsim:init", "init_s"):
             reused = self._slot_state is not None and self._slot_w == w
             if reused:
                 prev_state, self._slot_state = self._slot_state, None
                 state = shard_worlds(
-                    eng.refill(prev_state, np.ones(w, bool), seeds_c,
-                               faults=faults_init), mesh)
+                    form.refill(prev_state, np.ones(w, bool), seeds_c,
+                                faults=faults_init), mesh)
             else:
                 self._slot_state = None
                 state = shard_worlds(
-                    eng.init(seeds_c, faults=faults_init), mesh)
+                    form.init(seeds_c, faults=faults_init), mesh)
         first = self._runs == 0
         self._runs += 1
         self.reuse_hits += len(parts) - (1 if first else 0)
@@ -2928,7 +2957,7 @@ class SweepSession:
                 k = 1
             runner = sharded_superstep(
                 eng, mesh, chunk_steps, superstep_max, donate=True,
-                min_one=epoch_fresh, coverage=None)
+                min_one=epoch_fresh, coverage=None, fault_path=fault_path)
             epoch_fresh = False
             with tr.span("madsim:superstep", "dispatch_s"):
                 state, any_bug, n_active, k_done, hist, shard_steps = runner(
@@ -3042,6 +3071,7 @@ class SweepSession:
                     chunks / max(tr.entered["madsim:superstep"], 1), 3),
                 "dispatch_depth": dispatch_depth,
                 "scalar_fetches": tr.entered["madsim:wait"],
+                "fault_path": int(fault_path),
                 "loop_wall_s": round(tr.clock() - t_loop0, 6),
             }
 

@@ -36,6 +36,11 @@ HBM_BYTES = 16 * 2**30     # one v5e chip
 # The gather form this replaced was ~5.5 MB per world.
 V5E_STEP_FLOPS_PER_WORLD = 7_163
 V5E_STEP_BYTES_PER_WORLD = 17_609
+# The same step traced without its fault path (DeviceEngine._form), the
+# form a sweep without fault rows runs: sized at 4,990 flops and 13,564
+# bytes per world, with the same 1.15 headroom.
+V5E_FREE_STEP_FLOPS_PER_WORLD = 5_738
+V5E_FREE_STEP_BYTES_PER_WORLD = 15_598
 
 
 @pytest.fixture(scope="module")
@@ -103,6 +108,33 @@ def test_headline_step_fits_one_chip(topo):
     w = chip_smoke.HEADLINE_W
     assert ca["flops"] / w <= V5E_STEP_FLOPS_PER_WORLD, ca["flops"] / w
     assert ca["bytes accessed"] / w <= V5E_STEP_BYTES_PER_WORLD, \
+        ca["bytes accessed"] / w
+
+
+def test_headline_step_without_fault_path_fits_one_chip(topo):
+    """Phase A's step as a sweep without fault rows runs it, traced
+    without its fault path: it compiles for one v5e with no gather,
+    scatter or ``madsim/fault`` op, fits the chip, and its cost model
+    stays under the fault-free step budget, so the saving cannot quietly
+    come back."""
+    import jax
+
+    from madsim_tpu.parallel.mesh import world_sharding
+
+    eng = chip_smoke.headline_engine()._form(False)
+    mesh = _mesh(topo.devices[:1])
+    state = _state_shapes(eng, chip_smoke.HEADLINE_W, world_sharding(mesh))
+    comp = jax.jit(eng._batched_step, donate_argnums=0).lower(
+        state).compile()
+    ma = comp.memory_analysis()
+    assert 0 < ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
+    hlo = comp.as_text()
+    assert " gather(" not in hlo and " scatter(" not in hlo
+    assert "madsim/fault" not in hlo and "madsim/pop" in hlo
+    ca = comp.cost_analysis()
+    w = chip_smoke.HEADLINE_W
+    assert ca["flops"] / w <= V5E_FREE_STEP_FLOPS_PER_WORLD, ca["flops"] / w
+    assert ca["bytes accessed"] / w <= V5E_FREE_STEP_BYTES_PER_WORLD, \
         ca["bytes accessed"] / w
 
 
